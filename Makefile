@@ -60,7 +60,8 @@ bench-serve:     ## serving layer: AOT cells, micro-batch vs naive, latency sim
 	$(PY) -m benchmarks.bench_serve --json BENCH_serve.json
 
 bench-dist:      ## hierarchical vs flat vs compressed reduce (8 virtual devices)
-	$(PY) -m benchmarks.bench_dist --json BENCH_dist.json
+	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+	    $(PY) -m benchmarks.bench_dist --json BENCH_dist.json
 
 bench-ft:        ## Fig. 15/16 FT overhead (incl. one-pass FT vs unprotected)
 	$(PY) -m benchmarks.bench_ft_overhead

@@ -15,9 +15,11 @@ bandwidth win the hierarchy exists for — the derived column carries the
 ratios so ``check_regression`` can gate the hierarchical rung
 (``dist_hier_vs_flat``) against the committed ``BENCH_dist.json``.
 
-Standalone module (like bench_serve): it must own process start-up —
-the 8 virtual devices exist only if ``XLA_FLAGS`` is set before jax
-initializes, so it is NOT in ``benchmarks.run``'s in-process module list.
+Standalone module (like bench_serve): the 8 virtual CPU devices exist only
+if ``XLA_FLAGS`` is set before jax initializes, so it is NOT in
+``benchmarks.run``'s in-process module list. The module pins no platform
+itself; ``make bench-dist`` sets ``JAX_PLATFORMS=cpu`` and the device
+count, so on a machine with a chip it never quietly runs on the CPU.
 
 CLI:
   --smoke        tiny shapes (CI wiring)
@@ -27,19 +29,13 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 
-# must precede the first jax import: device count locks at backend init
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-import jax                                              # noqa: E402
-import jax.numpy as jnp                                 # noqa: E402
-import numpy as np                                      # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from benchmarks.common import row, time_call            # noqa: E402
+from benchmarks.common import row, time_call
 
 M, K, F = 8192, 64, 128
 SMOKE_M, SMOKE_K, SMOKE_F = 2048, 16, 64
@@ -73,10 +69,9 @@ def run(smoke: bool = False) -> list[str]:
 def _collect(smoke: bool = False) -> tuple[list[str], dict]:
     from repro.dist.reduce import ReducePlan
     from repro.dist.sharding import mesh2d
-    if len(jax.devices()) < 8:    # env was pinned before we loaded
-        raise SystemExit("bench_dist needs 8 virtual devices; run as "
-                         "`python -m benchmarks.bench_dist` in a fresh "
-                         "process")
+    if len(jax.devices()) < 8:
+        raise SystemExit("bench_dist needs 8 devices; run `make bench-dist` "
+                         "(8 virtual CPU devices)")
     m, k, f = (SMOKE_M, SMOKE_K, SMOKE_F) if smoke else (M, K, F)
     iters = 5 if smoke else 11
     rng = np.random.default_rng(0)

@@ -9,6 +9,9 @@ from __future__ import annotations
 
 import sys
 import traceback
+from pathlib import Path
+
+from repro.launch.compile_cache import use_compile_cache
 
 MODULES = [
     "benchmarks.bench_stepwise",       # Fig 7
@@ -24,6 +27,7 @@ MODULES = [
 
 
 def main() -> None:
+    use_compile_cache(Path(__file__).resolve().parent.parent)
     print("name,us_per_call,derived")
     failed = 0
     for modname in MODULES:

@@ -285,7 +285,8 @@ class BatchedKMeans:
             keys = self._problem_keys(x.shape[0])
         if self.init == "kmeans++-fused":
             from repro.kernels.kmeanspp_init import init_kmeanspp_fused
-            return init_kmeanspp_fused(keys, x, self.n_clusters)
+            return init_kmeanspp_fused(keys, x, self.n_clusters,
+                                       autotune=self.autotune)
         fn = init_kmeanspp if self.init == "kmeans++" else init_random
         return jax.vmap(fn, in_axes=(0, 0, None))(keys, x, self.n_clusters)
 

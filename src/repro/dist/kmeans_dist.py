@@ -52,7 +52,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist.reduce import ReducePlan, hop_axes, reduce_update
@@ -242,10 +241,10 @@ class DistributedKMeans:
         else:
             in_specs.append(P(None, None, None))
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local_step, mesh=self.mesh,
             in_specs=tuple(in_specs), out_specs=tuple(out_specs),
-            check_rep=False))
+            check_vma=False))
 
     # -- problem-axis mode: shard over B, no psum on the hot path -----------
 
@@ -276,13 +275,13 @@ class DistributedKMeans:
             return c, am, inertia, done, jax.lax.psum(det, daxes), live
 
         row = _axes_spec(self._paxes)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local_chunk, mesh=self.mesh,
             in_specs=(P(row, None, None), P(row, None, None), P(row, None),
                       P(row), P(row), P(row, None), P()),
             out_specs=(P(row, None, None), P(row, None), P(row), P(row),
                        P(), P(None, row)),
-            check_rep=False))
+            check_vma=False))
 
     def _fit_problems(self, xs: jax.Array, centroids: jax.Array,
                       max_iters: int, start_iteration: int,
@@ -374,13 +373,13 @@ class DistributedKMeans:
 
         pspec = _axes_spec(self._paxes)
         rspec = _axes_spec(self._raxes)
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local_step, mesh=self.mesh,
             in_specs=(P(pspec, rspec, None), P(pspec, None, None),
                       P(pspec, rspec), P(pspec), P(pspec)),
             out_specs=(P(pspec, None, None), P(pspec, rspec), P(pspec),
                        P(pspec), P()),
-            check_rep=False))
+            check_vma=False))
 
     def _fit_combined(self, xs: jax.Array, centroids: jax.Array,
                       max_iters: int, start_iteration: int,

@@ -18,8 +18,9 @@ ICI_LINK_BW: float = 50e9       # bytes/s per link
 ICI_LINKS: int = 4              # v5e: 4 ICI links per chip (2D torus x2)
 HBM_BYTES: int = 16 * 2**30     # 16 GiB
 VMEM_BYTES: int = 128 * 2**20
-# Usable VMEM per core for kernel working sets: half of the physical
-# 128 MiB, leaving room for Mosaic's own double-buffering scratch.
+# VMEM per core for kernel working sets: the budget the autotuner checks
+# and the scoped-VMEM limit every kernel asks Mosaic for
+# (``kernels/_mosaic.py``), three quarters of the physical 128 MiB.
 VMEM_BUDGET: int = 96 * 2**20
 # Fixed host-side cost of one kernel launch (runtime dispatch + grid
 # setup), independent of the grid. It is invisible next to a multi-ms fit

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params
 
 
 def _kernel(x_ref, a_ref, sums_ref, counts_ref, sums2_ref, counts2_ref,
@@ -102,8 +102,7 @@ def centroid_update_dmr(x: jax.Array, assign: jax.Array, k: int,
             jax.ShapeDtypeStruct((1, k), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=compiler_params("arbitrary"),
         interpret=interpret,
     )(x, assign[:, None].astype(jnp.int32))
     return sums, counts[0], bad[0, 0]

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params, mxu_dot
 
 # Initial value of the running minimum: +float32 max, so the first observed
 # distance always wins the compare. (Historically misnamed NEG_LIMIT; kept
@@ -100,9 +100,7 @@ def _kernel(x_ref, c_ref, cn_ref, mind_ref, argmin_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # MXU tile product, f32 accumulation.
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(x_ref[...], c_ref[...], (1, 1))
 
     @pl.when(f_idx == nf - 1)
     def _epilogue():
@@ -123,9 +121,7 @@ def _kernel_smallk(x_ref, c_ref, cn_ref, mind_ref, argmin_ref, acc_ref):
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(x_ref[...], c_ref[...], (1, 1))
 
     @pl.when(f_idx == nf - 1)
     def _epilogue():
@@ -186,8 +182,7 @@ def distance_argmin(
             ],
             out_shape=out_shape,
             scratch_shapes=scratch,
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=compiler_params("parallel", "arbitrary"),
             interpret=interpret,
         )
         return kernel(x, c, cn)
@@ -204,8 +199,7 @@ def distance_argmin(
         out_specs=out_specs_3d(),
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )
     return kernel(x, c, cn)

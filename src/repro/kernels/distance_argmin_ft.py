@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params, mxu_dot
 
 from repro.kernels.distance_argmin import (MIN_INIT, fold_min,
                                            tile_min_argmin)
@@ -64,6 +64,130 @@ INJ_LEN = 8
 INJ_SLOTS = 1
 
 
+_INT_MAX = jnp.iinfo(jnp.int32).max
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _first_argmax(v, axis):
+    """First index of the maximum of a (1, n) / (n, 1) vector along
+    ``axis``, as a (1, 1) vector: jnp.argmax's tie rule, built from the
+    min/max reductions Mosaic lowers."""
+    mx = jnp.max(v, axis=axis, keepdims=True)
+    return jnp.min(jnp.where(v == mx, _iota(v.shape, axis), _INT_MAX),
+                   axis=axis, keepdims=True)
+
+
+def _pick(v, axis, i):
+    """Entry ``i`` of a (1, n) / (n, 1) vector along ``axis``, as a (1, 1)
+    vector. A masked reduction: Mosaic lowers no dynamic vector slice."""
+    return jnp.max(jnp.where(_iota(v.shape, axis) == i, v, -jnp.inf),
+                   axis=axis, keepdims=True)
+
+
+def accumulate_checksums(x, c, col1_ref, col2_ref, row1_ref, row2_ref):
+    """Add one feature step's expected checksums of D = X C^T, from the
+    VMEM-resident tiles (paper lines 15-24). Checksums run in f32
+    regardless of the input dtype; e1 = ones, e2 = [1..b] at tile-local
+    indices."""
+    bm, bk = x.shape[0], c.shape[0]
+    xf = x.astype(jnp.float32)
+    cf = c.astype(jnp.float32)
+    w_m = (_iota((bm, 1), 0) + 1).astype(jnp.float32)        # e2 rows
+    w_kc = (_iota((bk, 1), 0) + 1).astype(jnp.float32)       # e2 cols
+    e1x = jnp.sum(xf, axis=0, keepdims=True)                 # (1, bf)
+    e2x = jnp.sum(w_m * xf, axis=0, keepdims=True)           # (1, bf)
+    ce1 = jnp.sum(cf, axis=0, keepdims=True)                 # (1, bf)
+    ce2 = jnp.sum(w_kc * cf, axis=0, keepdims=True)          # (1, bf)
+    col1_ref[...] += mxu_dot(e1x, cf, (1, 1))                # (1, bk)
+    col2_ref[...] += mxu_dot(e2x, cf, (1, 1))                # (1, bk)
+    row1_ref[...] += mxu_dot(xf, ce1, (1, 1))                # (bm, 1)
+    row2_ref[...] += mxu_dot(xf, ce2, (1, 1))                # (bm, 1)
+
+
+def _f32_from_bits(bits, shape):
+    """An SMEM int32 scalar reinterpreted as f32, broadcast to ``shape``.
+    Mosaic bitcasts vectors only, so the broadcast comes first."""
+    return jax.lax.bitcast_convert_type(jnp.full(shape, bits, jnp.int32),
+                                        jnp.float32)
+
+
+def inject_distance(acc_ref, inj_ref, m_idx, c_idx, f_idx):
+    """Simulated SEU in the accumulator (a compute-unit error): add the
+    descriptor's delta at (row, col) of tile (m_tile, c_tile) at feature
+    step f_tile. Slots [0..6] of the descriptor, in both FT kernels."""
+    hit = jnp.logical_and(
+        inj_ref[0] > 0,
+        jnp.logical_and(
+            jnp.logical_and(m_idx == inj_ref[1], c_idx == inj_ref[2]),
+            f_idx == inj_ref[3]))
+
+    @pl.when(hit)
+    def _inject():
+        shape = acc_ref.shape
+        mask = jnp.logical_and(_iota(shape, 0) == inj_ref[4],
+                               _iota(shape, 1) == inj_ref[5])
+        delta = _f32_from_bits(inj_ref[6], shape)
+        acc_ref[...] += jnp.where(mask, delta, 0.0)
+
+
+def verify_and_correct(acc, col1, col2, row1, row2, factor):
+    """Verification interval of one (bm, bk) accumulator tile: detect ->
+    locate -> correct. Returns (the corrected tile, detected (1, 1) bool).
+
+    ``factor`` is the dtype-aware threshold factor (a trace-time constant:
+    the grid is static). The magnitude scale comes from the *expected*
+    checksums — the invariant side, computed from clean inputs — never
+    from the possibly-corrupted accumulator: a corrupted-side scale lets a
+    large delta inflate its own threshold past itself whenever the factor
+    exceeds 1 (bf16 at wide tiles), self-masking exactly the errors worth
+    catching.
+    """
+    bm, bk = acc.shape
+    w_m = (_iota((bm, 1), 0) + 1).astype(jnp.float32)
+    w_k = (_iota((1, bk), 1) + 1).astype(jnp.float32)
+    res_col1 = jnp.sum(acc, axis=0, keepdims=True) - col1           # (1, bk)
+    res_col2 = jnp.sum(w_m * acc, axis=0, keepdims=True) - col2
+    res_row1 = jnp.sum(acc, axis=1, keepdims=True) - row1           # (bm, 1)
+    res_row2 = jnp.sum(w_k * acc, axis=1, keepdims=True) - row2
+
+    scale = jnp.maximum(
+        jnp.maximum(jnp.max(jnp.abs(col1), keepdims=True),
+                    jnp.max(jnp.abs(row1), keepdims=True)), 1.0)    # (1, 1)
+    thr = jnp.float32(factor) * scale
+    abs_col1, abs_row1 = jnp.abs(res_col1), jnp.abs(res_row1)
+    detected = jnp.logical_or(jnp.max(abs_col1, keepdims=True) > thr,
+                              jnp.max(abs_row1, keepdims=True) > thr)
+
+    # Locate: argmax |column residual| gives j and delta; e2/e1 ratio of
+    # the row residuals gives i (and vice versa as fallback).
+    j = _first_argmax(abs_col1, 1)
+    delta_col = _pick(res_col1, 1, j)
+    i_direct = _first_argmax(abs_row1, 0)
+    safe = jnp.where(delta_col == 0.0, 1.0, delta_col)
+    i_ratio = (jnp.round(_pick(res_col2, 1, j) / safe) - 1.0).astype(jnp.int32)
+    use_ratio = jnp.abs(delta_col) > thr
+    i = jnp.clip(jnp.where(use_ratio, i_ratio, i_direct), 0, bm - 1)
+    delta_row = _pick(res_row1, 0, i)
+    delta = jnp.where(jnp.abs(delta_col) > jnp.abs(delta_row),
+                      delta_col, delta_row)
+    safe_r = jnp.where(delta_row == 0.0, 1.0, delta_row)
+    j_ratio = (jnp.round(_pick(res_row2, 0, i) / safe_r) - 1.0
+               ).astype(jnp.int32)
+    j = jnp.where(use_ratio, j, jnp.clip(j_ratio, 0, bk - 1))
+
+    # Mosaic broadcasts a (1, 1) vector along one tile axis at a time, so
+    # the correction is built as a (bm, 1) column, then spread over lanes.
+    # Subtracting 0.0 leaves every other element (and an undetected tile)
+    # bit-unchanged.
+    on_row = jnp.logical_and(_iota((bm, 1), 0) == i, detected)       # (bm, 1)
+    on_col = _iota((1, bk), 1) == j                                  # (1, bk)
+    corr = jnp.where(on_col, jnp.where(on_row, delta, 0.0), 0.0)     # (bm, bk)
+    return acc - corr, detected
+
+
 def _kernel(inj_ref, x_ref, c_ref, cn_ref,
             mind_ref, argmin_ref, det_ref,
             acc_ref, col1_ref, col2_ref, row1_ref, row2_ref):
@@ -71,7 +195,7 @@ def _kernel(inj_ref, x_ref, c_ref, cn_ref,
     c_idx = pl.program_id(1)
     f_idx = pl.program_id(2)
     nf = pl.num_programs(2)
-    bm, bk = acc_ref.shape
+    bk = acc_ref.shape[1]
     bf = x_ref.shape[1]
 
     @pl.when(jnp.logical_and(c_idx == 0, f_idx == 0))
@@ -93,96 +217,18 @@ def _kernel(inj_ref, x_ref, c_ref, cn_ref,
     c = c_ref[...]
 
     # --- main MXU product (native dtype in, f32 accumulate) -----------------
-    acc_ref[...] += jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-
-    # --- expected checksums, from VMEM-resident tiles (paper lines 15-24) ---
-    # Checksums run in f32 regardless of the input dtype: products of
-    # 2-byte values are exactly representable in f32, so the residual of a
-    # clean bf16/fp16 tile stays at f32 rounding level and the f32-eps
-    # threshold below applies unchanged.
-    xf = x.astype(jnp.float32)
-    cf = c.astype(jnp.float32)
-    w_m = jax.lax.broadcasted_iota(jnp.float32, (bm, 1), 0) + 1.0   # e2 rows
-    w_k = jax.lax.broadcasted_iota(jnp.float32, (1, bk), 1) + 1.0   # e2 cols
-    e1x = jnp.sum(xf, axis=0, keepdims=True)                 # (1, bf)
-    e2x = jnp.sum(w_m * xf, axis=0, keepdims=True)           # (1, bf)
-    ce1 = jnp.sum(cf, axis=0, keepdims=True)                 # (1, bf)
-    ce2 = jnp.sum(w_k.reshape(bk, 1) * cf, axis=0, keepdims=True)
-    dot_t = lambda a, b: jax.lax.dot_general(                # a (1|bm, bf) x b (bk|1, bf)^T
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    col1_ref[...] += dot_t(e1x, cf)                          # (1, bk)
-    col2_ref[...] += dot_t(e2x, cf)                          # (1, bk)
-    row1_ref[...] += dot_t(xf, ce1)                          # (bm, 1)
-    row2_ref[...] += dot_t(xf, ce2)                          # (bm, 1)
-
-    # --- simulated SEU in the accumulator (compute-unit error) --------------
-    hit = jnp.logical_and(
-        inj_ref[0] > 0,
-        jnp.logical_and(
-            jnp.logical_and(m_idx == inj_ref[1], c_idx == inj_ref[2]),
-            f_idx == inj_ref[3]))
-
-    @pl.when(hit)
-    def _inject():
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1)
-        mask = jnp.logical_and(rows == inj_ref[4], cols == inj_ref[5])
-        delta = jax.lax.bitcast_convert_type(inj_ref[6], jnp.float32)
-        acc_ref[...] += jnp.where(mask, delta, 0.0)
+    acc_ref[...] += mxu_dot(x, c, (1, 1))
+    accumulate_checksums(x, c, col1_ref, col2_ref, row1_ref, row2_ref)
+    inject_distance(acc_ref, inj_ref, m_idx, c_idx, f_idx)
 
     # --- verification interval: detect -> locate -> correct -> reduce -------
     @pl.when(f_idx == nf - 1)
     def _verify_and_reduce():
-        acc = acc_ref[...]
-        obs_col1 = jnp.sum(acc, axis=0, keepdims=True)            # (1, bk)
-        obs_col2 = jnp.sum(w_m * acc, axis=0, keepdims=True)
-        obs_row1 = jnp.sum(acc, axis=1, keepdims=True)            # (bm, 1)
-        obs_row2 = jnp.sum(w_k * acc, axis=1, keepdims=True)
-
-        res_col1 = obs_col1 - col1_ref[...]
-        res_col2 = obs_col2 - col2_ref[...]
-        res_row1 = obs_row1 - row1_ref[...]
-        res_row2 = obs_row2 - row2_ref[...]
-
-        # grid is static -> the factor is a trace-time constant; the eps
-        # inside tracks the input dtype's rounding of the main accumulator
-        # (bf16/fp16 tiles), not bare f32 eps. The magnitude scale comes
-        # from the *expected* checksums — the invariant side, computed from
-        # clean inputs — never from the possibly-corrupted accumulator: a
-        # corrupted-side scale lets a large delta inflate its own threshold
-        # past itself whenever the factor exceeds 1 (bf16 at wide tiles),
-        # self-masking exactly the errors worth catching.
-        scale = jnp.maximum(jnp.maximum(jnp.max(jnp.abs(col1_ref[...])),
-                                        jnp.max(jnp.abs(row1_ref[...]))), 1.0)
-        thr = jnp.float32(threshold_factor(nf * bf, x_ref.dtype)) * scale
-
-        detected = jnp.logical_or(jnp.max(jnp.abs(res_col1)) > thr,
-                                  jnp.max(jnp.abs(res_row1)) > thr)
-
-        # Locate: argmax |column residual| gives j and delta; e2/e1 ratio of
-        # the row residuals gives i (and vice versa as fallback).
-        j = jnp.argmax(jnp.abs(res_col1[0, :])).astype(jnp.int32)
-        delta_col = res_col1[0, j]
-        i_direct = jnp.argmax(jnp.abs(res_row1[:, 0])).astype(jnp.int32)
-        safe = jnp.where(delta_col == 0.0, 1.0, delta_col)
-        i_ratio = (jnp.round(res_col2[0, j] / safe) - 1.0).astype(jnp.int32)
-        use_ratio = jnp.abs(delta_col) > thr
-        i = jnp.clip(jnp.where(use_ratio, i_ratio, i_direct), 0, bm - 1)
-        delta_row = res_row1[i, 0]
-        delta = jnp.where(jnp.abs(delta_col) > jnp.abs(delta_row),
-                          delta_col, delta_row)
-        safe_r = jnp.where(delta_row == 0.0, 1.0, delta_row)
-        j_ratio = (jnp.round(res_row2[i, 0] / safe_r) - 1.0).astype(jnp.int32)
-        j = jnp.where(use_ratio, j, jnp.clip(j_ratio, 0, bk - 1))
-
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1)
-        corrected = acc - jnp.where(
-            jnp.logical_and(rows == i, cols == j), delta, 0.0)
-        acc = jnp.where(detected, corrected, acc)
+        acc, detected = verify_and_correct(
+            acc_ref[...], col1_ref[...], col2_ref[...], row1_ref[...],
+            row2_ref[...], threshold_factor(nf * bf, x_ref.dtype))
         acc_ref[...] = acc
-        det_ref[...] += detected.astype(jnp.int32)
+        det_ref[0] += detected.astype(jnp.int32)
 
         # --- fused epilogue on the corrected tile ---------------------------
         local_min, local_arg = tile_min_argmin(acc, cn_ref[...], c_idx * bk)
@@ -236,12 +282,14 @@ def distance_argmin_ft(
         out_specs=[
             pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
             pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, t: (i, 0)),
+            # unit axis: the (1, 1, 1) block equals the array's last two
+            # dims, as Mosaic's block rule requires; squeezed after the call
+            pl.BlockSpec((1, 1, 1), lambda i, j, t: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
             jax.ShapeDtypeStruct((m, 1), jnp.int32),
-            jax.ShapeDtypeStruct((m // block_m, 1), jnp.int32),
+            jax.ShapeDtypeStruct((m // block_m, 1, 1), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_m, block_k), jnp.float32),
@@ -250,8 +298,8 @@ def distance_argmin_ft(
             pltpu.VMEM((block_m, 1), jnp.float32),
             pltpu.VMEM((block_m, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )
-    return kernel(inj, x, c, cn)
+    mind, am, det = kernel(inj, x, c, cn)
+    return mind, am, det[:, 0]

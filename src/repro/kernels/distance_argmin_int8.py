@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params
 from repro.kernels.distance_argmin import MIN_INIT, fold_min, tile_min_argmin
 
 
@@ -176,8 +176,7 @@ def distance_argmin_int8(
             ],
             out_shape=out_shape,
             scratch_shapes=scratch,
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=compiler_params("parallel", "arbitrary"),
             interpret=interpret,
         )
         return kernel(x, c, sx, sc, cn)
@@ -199,8 +198,7 @@ def distance_argmin_int8(
         ],
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )
     return kernel(x, c, sx, sc, cn)

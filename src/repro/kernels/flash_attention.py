@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params
 
 NEG = -1e30
 
@@ -115,8 +115,7 @@ def flash_attention(q, k, v, q_positions, kv_positions, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(q_positions.astype(jnp.int32)[:, None],
       kv_positions.astype(jnp.int32)[None, :],

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params, mxu_dot
 
 DEFAULT_BLOCK_N = 512
 # off-TPU the tile size only shapes the two-level CDF, not a launch grid
@@ -77,18 +77,16 @@ def _round_kernel(x_ref, xn_ref, c_ref, d2_ref, d2o_ref, ts_ref):
     c_ref  : (1, 1, fp)  f32  the centroid chosen last round
     d2_ref : (1, bn, 1)  f32  incoming d² (0 in padded rows)
     d2o_ref: (1, bn, 1)  f32  updated d² (output)
-    ts_ref : (1, 1)      f32  tile sum of the updated d² (output)
+    ts_ref : (1, 1, 1, 1) f32 tile sum of the updated d² (output)
     """
     xt = x_ref[0]                                    # (bn, fp)
     ct = c_ref[0]                                    # (1, fp)
-    cross = jax.lax.dot_general(
-        xt, ct, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (bn, 1)
+    cross = mxu_dot(xt, ct, (1, 1))                 # (bn, 1)
     cn = jnp.sum(ct * ct)
     nd = jnp.maximum(xn_ref[0] - 2.0 * cross + cn, 0.0)
     d2 = jnp.minimum(d2_ref[0], nd)
     d2o_ref[0] = d2
-    ts_ref[...] = jnp.sum(d2, axis=0, keepdims=True).reshape(1, 1)
+    ts_ref[0, 0] = jnp.sum(d2, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -118,17 +116,18 @@ def kmeanspp_round(x: jax.Array, xn: jax.Array, c: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((1, block_n, 1), lambda bb, i: (bb, i, 0)),
-            pl.BlockSpec((1, 1), lambda bb, i: (bb, i)),
+            # unit axes: the block equals the array's last two dims, as
+            # Mosaic's block rule requires; squeezed after the call
+            pl.BlockSpec((1, 1, 1, 1), lambda bb, i: (bb, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, np_, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, t), jnp.float32),
+            jax.ShapeDtypeStruct((b, t, 1, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=compiler_params("parallel", "parallel"),
         interpret=interpret,
     )(x, xn[..., None], c, d2[..., None])
-    return d2n[..., 0], ts
+    return d2n[..., 0], ts[..., 0, 0]
 
 
 def _round_twin(x: jax.Array, xn: jax.Array, c: jax.Array, d2: jax.Array,
@@ -221,7 +220,8 @@ def _init_impl(keys: jax.Array, x: jax.Array, *, k: int, block_n: int,
 def init_kmeanspp_fused(keys: jax.Array, x: jax.Array, k: int, *,
                         params=None, block_n: int = None,
                         use_kernel: bool = None,
-                        interpret: bool = None) -> jax.Array:
+                        interpret: bool = None,
+                        autotune=None) -> jax.Array:
     """Fused k-means++ seeding for B stacked problems.
 
     keys (B, 2) per-problem PRNG keys, x (B, N, F) stacked samples.
@@ -231,7 +231,8 @@ def init_kmeanspp_fused(keys: jax.Array, x: jax.Array, k: int, *,
     and per seed they choose the same indices (the parity contract
     ``tests/test_seeding.py`` pins). ``block_n``/``params`` override the
     tile size (``params.block_m`` wins the autotune ``"init"``-kind
-    lookup); ``interpret`` only affects the kernel path.
+    lookup, made in ``autotune`` — default: the process cache);
+    ``interpret`` only affects the kernel path.
     """
     from repro.kernels.ops import on_tpu
     b, n, f = x.shape
@@ -243,8 +244,10 @@ def init_kmeanspp_fused(keys: jax.Array, x: jax.Array, k: int, *,
         if params is not None:
             block_n = params.block_m
         elif use_kernel:
-            from repro.api.cache import default_cache
-            _, p = default_cache().lookup(n, k, f, kind="init")
+            if autotune is None:
+                from repro.api.cache import default_cache
+                autotune = default_cache()
+            _, p = autotune.lookup(n, k, f, kind="init")
             block_n = p.block_m
         else:
             # twin path: no launch grid to amortize off-TPU, so the tile

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params, mxu_dot
 from repro.kernels.distance_argmin import MIN_INIT, fold_min, tile_min_argmin
 
 # SMEM metadata layout: [true_m] — rows >= true_m are padding and must not
@@ -108,7 +108,7 @@ def _kernel(meta_ref, x_ref, c_ref, cn_ref,
     mind_ref  : (bm, 1)     running minimum of d_ij  (output, revisited)
     argmin_ref: (bm, 1)     running argmin           (output, revisited)
     sums_ref  : (1, kp, fp) per-row-tile partial cluster sums (output)
-    counts_ref: (1, kp)     per-row-tile partial cluster counts (output)
+    counts_ref: (1, 1, kp)  per-row-tile partial cluster counts (output)
     acc_ref   : (bm, bk)    VMEM scratch accumulator for X C^T
     xbuf_ref  : (bm, fp)    VMEM stash of the row tile's feature chunks
     sem_ref   : (2,)        DMA semaphores for the double-buffered stash
@@ -139,9 +139,7 @@ def _kernel(meta_ref, x_ref, c_ref, cn_ref,
         _stash_dma_start(x_ref, xbuf_ref, sem_ref, f_idx, bf)
 
     # MXU tile product, f32 accumulation.
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(x_ref[...], c_ref[...], (1, 1))
 
     @pl.when(f_idx == nf - 1)
     def _min_epilogue():
@@ -164,16 +162,14 @@ def _emit_update(meta_ref, argmin_ref, sums_ref, counts_ref, xbuf_ref,
     """Shared one-hot update epilogue: final argmin -> per-cluster partial
     sums/counts for this row tile. The one-hot matrix is exact (0/1) in the
     stash dtype, so a 2-byte stash loses nothing; accumulation is f32."""
-    kp = counts_ref.shape[1]
+    kp = counts_ref.shape[-1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0) + m_idx * bm
     valid = (rows < meta_ref[0]).astype(jnp.float32)           # (bm, 1)
     clusters = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
     onehot = (argmin_ref[...] == clusters).astype(jnp.float32) * valid
-    counts_ref[...] = jnp.sum(onehot, axis=0, keepdims=True)   # (1, kp)
-    sums_ref[...] = jax.lax.dot_general(
-        onehot.astype(xbuf_ref.dtype), xbuf_ref[...],
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[None]              # (1, kp, fp)
+    counts_ref[0] = jnp.sum(onehot, axis=0, keepdims=True)     # (1, kp)
+    sums_ref[...] = mxu_dot(onehot.astype(xbuf_ref.dtype), xbuf_ref[...],
+                            (0, 0))[None]                      # (1, kp, fp)
 
 
 def _kernel_smallk(meta_ref, x_ref, c_ref, cn_ref,
@@ -199,9 +195,7 @@ def _kernel_smallk(meta_ref, x_ref, c_ref, cn_ref,
     # every step issues its async stash (overlapping its own MXU product).
     _stash_dma_start(x_ref, xbuf_ref, sem_ref, f_idx, bf)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(x_ref[...], c_ref[...], (1, 1))
 
     @pl.when(f_idx == nf - 1)
     def _epilogue():
@@ -231,7 +225,7 @@ def _kernel_batched(meta_ref, x_ref, c_ref, cn_ref,
     mind_ref  : (1, bm, 1)        min distance (output, single visit)
     argmin_ref: (1, bm, 1)        argmin       (output, single visit)
     sums_ref  : (1, 1, kp, fp)    per-row-tile partial cluster sums
-    counts_ref: (1, 1, kp)        per-row-tile partial cluster counts
+    counts_ref: (1, 1, 1, kp)     per-row-tile partial cluster counts
     acc_ref   : (bm, kp)          per-problem VMEM scratch accumulator
     xbuf_ref  : (bm, fp)          VMEM stash of the row tile's chunks
     sem_ref   : (2,)              DMA semaphores for the async stash
@@ -251,9 +245,7 @@ def _kernel_batched(meta_ref, x_ref, c_ref, cn_ref,
     # copy overlaps this step's MXU product.
     _stash_dma_start(x_ref.at[0], xbuf_ref, sem_ref, f_idx, bf)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[0], c_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(x_ref[0], c_ref[0], (1, 1))
 
     @pl.when(f_idx == nf - 1)
     def _epilogue():
@@ -266,11 +258,9 @@ def _kernel_batched(meta_ref, x_ref, c_ref, cn_ref,
         valid = (rows < meta_ref[0]).astype(jnp.float32)
         clusters = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
         onehot = (local_arg == clusters).astype(jnp.float32) * valid
-        counts_ref[0, 0] = jnp.sum(onehot, axis=0)
-        sums_ref[0, 0] = jax.lax.dot_general(
-            onehot.astype(xbuf_ref.dtype), xbuf_ref[...],
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        counts_ref[0, 0] = jnp.sum(onehot, axis=0, keepdims=True)
+        sums_ref[0, 0] = mxu_dot(onehot.astype(xbuf_ref.dtype),
+                                 xbuf_ref[...], (0, 0))
 
 
 @functools.partial(
@@ -308,7 +298,8 @@ def lloyd_step_batched(
         jax.ShapeDtypeStruct((bsz, m, 1), jnp.float32),
         jax.ShapeDtypeStruct((bsz, m, 1), jnp.int32),
         jax.ShapeDtypeStruct((bsz, num_m, k, f), jnp.float32),
-        jax.ShapeDtypeStruct((bsz, num_m, k), jnp.float32),
+        # unit axis before K: see ``lloyd_step``
+        jax.ShapeDtypeStruct((bsz, num_m, 1, k), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((block_m, k), jnp.float32),
@@ -328,15 +319,15 @@ def lloyd_step_batched(
             pl.BlockSpec((1, block_m, 1), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, block_m, 1), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, 1, k, f), lambda b, i, t: (b, i, 0, 0)),
-            pl.BlockSpec((1, 1, k), lambda b, i, t: (b, i, 0)),
+            pl.BlockSpec((1, 1, 1, k), lambda b, i, t: (b, i, 0, 0)),
         ],
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )
-    return kernel(meta, x, c, cn)
+    mind, am, sums, counts = kernel(meta, x, c, cn)
+    return mind, am, sums, counts[:, :, 0]
 
 
 @functools.partial(
@@ -373,7 +364,10 @@ def lloyd_step(
         jax.ShapeDtypeStruct((m, 1), jnp.float32),
         jax.ShapeDtypeStruct((m, 1), jnp.int32),
         jax.ShapeDtypeStruct((num_m, k, f), jnp.float32),
-        jax.ShapeDtypeStruct((num_m, k), jnp.float32),
+        # A unit axis before K: Mosaic wants a block's last two dims to be
+        # (8, 128)-aligned or equal to the array's, so one row of counts per
+        # row tile is a (1, 1, k) block, squeezed away after the call.
+        jax.ShapeDtypeStruct((num_m, 1, k), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((block_m, block_k), jnp.float32),
@@ -397,36 +391,35 @@ def lloyd_step(
                 pl.BlockSpec((block_m, 1), lambda i, t: (i, 0)),
                 pl.BlockSpec((block_m, 1), lambda i, t: (i, 0)),
                 pl.BlockSpec((1, k, f), lambda i, t: (i, 0, 0)),
-                pl.BlockSpec((1, k), lambda i, t: (i, 0)),
+                pl.BlockSpec((1, 1, k), lambda i, t: (i, 0, 0)),
             ],
             out_shape=out_shape,
             scratch_shapes=scratch,
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=compiler_params("parallel", "arbitrary"),
             interpret=interpret,
         )
-        return kernel(meta, x, c, cn)
-
-    assert variant == "generic", f"unknown kernel variant {variant!r}"
-    kernel = pl.pallas_call(
-        _kernel,
-        grid=(m // block_m, k // block_k, f // block_f),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_m, block_f), lambda i, j, t: (i, t)),
-            pl.BlockSpec((block_k, block_f), lambda i, j, t: (j, t)),
-            pl.BlockSpec((1, block_k), lambda i, j, t: (0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1, k, f), lambda i, j, t: (i, 0, 0)),
-            pl.BlockSpec((1, k), lambda i, j, t: (i, 0)),
-        ],
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )
-    return kernel(meta, x, c, cn)
+    else:
+        assert variant == "generic", f"unknown kernel variant {variant!r}"
+        kernel = pl.pallas_call(
+            _kernel,
+            grid=(m // block_m, k // block_k, f // block_f),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((block_m, block_f), lambda i, j, t: (i, t)),
+                pl.BlockSpec((block_k, block_f), lambda i, j, t: (j, t)),
+                pl.BlockSpec((1, block_k), lambda i, j, t: (0, j)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
+                pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
+                pl.BlockSpec((1, k, f), lambda i, j, t: (i, 0, 0)),
+                pl.BlockSpec((1, 1, k), lambda i, j, t: (i, 0, 0)),
+            ],
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            compiler_params=compiler_params(
+                "parallel", "arbitrary", "arbitrary"),
+            interpret=interpret,
+        )
+    mind, am, sums, counts = kernel(meta, x, c, cn)
+    return mind, am, sums, counts[:, 0]

@@ -54,9 +54,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params, mxu_dot
 from repro.kernels.distance_argmin import MIN_INIT, fold_min, tile_min_argmin
-from repro.kernels.distance_argmin_ft import threshold_factor
+from repro.kernels.distance_argmin_ft import (_f32_from_bits,
+                                              accumulate_checksums,
+                                              inject_distance,
+                                              threshold_factor,
+                                              verify_and_correct)
 from repro.kernels.lloyd_step import (STASH_SLOTS, _emit_update,
                                       _stash_dma_start, _stash_dma_wait_last)
 
@@ -117,11 +121,11 @@ def _kernel(meta_ref, inj_ref, x_ref, c_ref, cn_ref,
     cn_ref    : (1, bk)     centroid squared norms (+inf for padded slots)
     mind_ref  : (bm, 1)     running minimum of d_ij  (output, revisited)
     argmin_ref: (bm, 1)     running argmin           (output, revisited)
-    det_ref   : (1, 1)      corrected distance-GEMM errors in this row tile
+    det_ref   : (1, 1, 1)   corrected distance-GEMM errors in this row tile
     sums_ref  : (1, kp, fp) per-row-tile partial cluster sums (output)
-    counts_ref: (1, kp)     per-row-tile partial cluster counts (output)
+    counts_ref: (1, 1, kp)  per-row-tile partial cluster counts (output)
     ucheck_ref: (1, 2, fp)  expected e1/e2 column checksums of the sums
-    ccheck_ref: (1, 2)      expected e1/e2 checksums of the counts
+    ccheck_ref: (1, 1, 2)   expected e1/e2 checksums of the counts
     acc/colN/rowN          : ABFT scratch as in ``distance_argmin_ft``
     xbuf_ref  : (bm, fp)    VMEM stash of the row tile's feature chunks
     sem_ref   : (2,)        DMA semaphores for the double-buffered stash
@@ -160,89 +164,18 @@ def _kernel(meta_ref, inj_ref, x_ref, c_ref, cn_ref,
     c = c_ref[...]
 
     # --- main MXU product (native dtype in, f32 accumulate) -----------------
-    acc_ref[...] += jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-
-    # --- expected checksums, from VMEM-resident tiles (paper lines 15-24) ---
-    xf = x.astype(jnp.float32)
-    cf = c.astype(jnp.float32)
-    w_m = jax.lax.broadcasted_iota(jnp.float32, (bm, 1), 0) + 1.0   # e2 rows
-    w_k = jax.lax.broadcasted_iota(jnp.float32, (1, bk), 1) + 1.0   # e2 cols
-    e1x = jnp.sum(xf, axis=0, keepdims=True)                 # (1, bf)
-    e2x = jnp.sum(w_m * xf, axis=0, keepdims=True)           # (1, bf)
-    ce1 = jnp.sum(cf, axis=0, keepdims=True)                 # (1, bf)
-    ce2 = jnp.sum(w_k.reshape(bk, 1) * cf, axis=0, keepdims=True)
-    dot_t = lambda a, b: jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    col1_ref[...] += dot_t(e1x, cf)                          # (1, bk)
-    col2_ref[...] += dot_t(e2x, cf)                          # (1, bk)
-    row1_ref[...] += dot_t(xf, ce1)                          # (bm, 1)
-    row2_ref[...] += dot_t(xf, ce2)                          # (bm, 1)
-
-    # --- simulated SEU in the distance accumulator --------------------------
-    hit = jnp.logical_and(
-        inj_ref[0] > 0,
-        jnp.logical_and(
-            jnp.logical_and(m_idx == inj_ref[1], c_idx == inj_ref[2]),
-            f_idx == inj_ref[3]))
-
-    @pl.when(hit)
-    def _inject():
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1)
-        mask = jnp.logical_and(rows == inj_ref[4], cols == inj_ref[5])
-        delta = jax.lax.bitcast_convert_type(inj_ref[6], jnp.float32)
-        acc_ref[...] += jnp.where(mask, delta, 0.0)
+    acc_ref[...] += mxu_dot(x, c, (1, 1))
+    accumulate_checksums(x, c, col1_ref, col2_ref, row1_ref, row2_ref)
+    inject_distance(acc_ref, inj_ref, m_idx, c_idx, f_idx)
 
     # --- verification interval: detect -> locate -> correct -> reduce -------
     @pl.when(f_idx == nf - 1)
     def _verify_and_reduce():
-        acc = acc_ref[...]
-        obs_col1 = jnp.sum(acc, axis=0, keepdims=True)            # (1, bk)
-        obs_col2 = jnp.sum(w_m * acc, axis=0, keepdims=True)
-        obs_row1 = jnp.sum(acc, axis=1, keepdims=True)            # (bm, 1)
-        obs_row2 = jnp.sum(w_k * acc, axis=1, keepdims=True)
-
-        res_col1 = obs_col1 - col1_ref[...]
-        res_col2 = obs_col2 - col2_ref[...]
-        res_row1 = obs_row1 - row1_ref[...]
-        res_row2 = obs_row2 - row2_ref[...]
-
-        # static grid -> trace-time constant factor; dtype-aware eps. The
-        # magnitude scale comes from the *expected* checksums (the clean
-        # invariant side), never the possibly-corrupted accumulator —
-        # a corrupted-side scale would let a large delta inflate its own
-        # threshold past itself (self-masking) once the factor exceeds 1.
-        scale = jnp.maximum(jnp.maximum(jnp.max(jnp.abs(col1_ref[...])),
-                                        jnp.max(jnp.abs(row1_ref[...]))), 1.0)
-        thr = jnp.float32(threshold_factor(nf * bf, x_ref.dtype)) * scale
-
-        detected = jnp.logical_or(jnp.max(jnp.abs(res_col1)) > thr,
-                                  jnp.max(jnp.abs(res_row1)) > thr)
-
-        # Locate: argmax |column residual| gives j and delta; e2/e1 ratio of
-        # the row residuals gives i (and vice versa as fallback).
-        j = jnp.argmax(jnp.abs(res_col1[0, :])).astype(jnp.int32)
-        delta_col = res_col1[0, j]
-        i_direct = jnp.argmax(jnp.abs(res_row1[:, 0])).astype(jnp.int32)
-        safe = jnp.where(delta_col == 0.0, 1.0, delta_col)
-        i_ratio = (jnp.round(res_col2[0, j] / safe) - 1.0).astype(jnp.int32)
-        use_ratio = jnp.abs(delta_col) > thr
-        i = jnp.clip(jnp.where(use_ratio, i_ratio, i_direct), 0, bm - 1)
-        delta_row = res_row1[i, 0]
-        delta = jnp.where(jnp.abs(delta_col) > jnp.abs(delta_row),
-                          delta_col, delta_row)
-        safe_r = jnp.where(delta_row == 0.0, 1.0, delta_row)
-        j_ratio = (jnp.round(res_row2[i, 0] / safe_r) - 1.0).astype(jnp.int32)
-        j = jnp.where(use_ratio, j, jnp.clip(j_ratio, 0, bk - 1))
-
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1)
-        corrected = acc - jnp.where(
-            jnp.logical_and(rows == i, cols == j), delta, 0.0)
-        acc = jnp.where(detected, corrected, acc)
+        acc, detected = verify_and_correct(
+            acc_ref[...], col1_ref[...], col2_ref[...], row1_ref[...],
+            row2_ref[...], threshold_factor(nf * bf, x_ref.dtype))
         acc_ref[...] = acc
-        det_ref[...] += detected.astype(jnp.int32)
+        det_ref[0] += detected.astype(jnp.int32)
 
         # --- fused min/argmin epilogue on the corrected tile ----------------
         local_min, local_arg = tile_min_argmin(acc, cn_ref[...], c_idx * bk)
@@ -251,7 +184,7 @@ def _kernel(meta_ref, inj_ref, x_ref, c_ref, cn_ref,
     # --- protected update epilogue: argmin for this row tile is final -------
     @pl.when(jnp.logical_and(c_idx == nk - 1, f_idx == nf - 1))
     def _update_epilogue():
-        kp = counts_ref.shape[1]
+        kp = counts_ref.shape[-1]
         fp = xbuf_ref.shape[1]
         _stash_dma_wait_last(x_ref, xbuf_ref, sem_ref, nf, bf)
         # the one-hot product itself is the unprotected kernel's epilogue,
@@ -267,11 +200,9 @@ def _kernel(meta_ref, inj_ref, x_ref, c_ref, cn_ref,
         # vectors and the stashed tiles — never from the product itself
         amp1 = valid * (argmin_ref[...] + 1).astype(jnp.float32)   # (bm, 1)
         enc = jnp.concatenate([valid, amp1], axis=1)               # (bm, 2)
-        ucheck_ref[...] = jax.lax.dot_general(
-            enc, xbuf_ref[...].astype(jnp.float32),
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[None]              # (1,2,fp)
-        ccheck_ref[...] = jnp.sum(enc, axis=0, keepdims=True)      # (1, 2)
+        ucheck_ref[...] = mxu_dot(
+            enc, xbuf_ref[...].astype(jnp.float32), (0, 0))[None]  # (1,2,fp)
+        ccheck_ref[0] = jnp.sum(enc, axis=0, keepdims=True)        # (1, 2)
 
         # simulated SEU in the one-hot update product — applied after the
         # invariant side is recorded (inputs are ECC's job, per §II-A)
@@ -282,7 +213,7 @@ def _kernel(meta_ref, inj_ref, x_ref, c_ref, cn_ref,
             krows = jax.lax.broadcasted_iota(jnp.int32, (kp, fp), 0)
             fcols = jax.lax.broadcasted_iota(jnp.int32, (kp, fp), 1)
             mask = jnp.logical_and(krows == inj_ref[9], fcols == inj_ref[10])
-            udelta = jax.lax.bitcast_convert_type(inj_ref[11], jnp.float32)
+            udelta = _f32_from_bits(inj_ref[11], (kp, fp))
             sums_ref[...] += jnp.where(mask, udelta, 0.0)[None]
 
 
@@ -330,20 +261,23 @@ def lloyd_step_ft(
         out_specs=[
             pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
             pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, t: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j, t: (i, 0, 0)),
             pl.BlockSpec((1, k, f), lambda i, j, t: (i, 0, 0)),
-            pl.BlockSpec((1, k), lambda i, j, t: (i, 0)),
+            pl.BlockSpec((1, 1, k), lambda i, j, t: (i, 0, 0)),
             pl.BlockSpec((1, 2, f), lambda i, j, t: (i, 0, 0)),
-            pl.BlockSpec((1, 2), lambda i, j, t: (i, 0)),
+            pl.BlockSpec((1, 1, 2), lambda i, j, t: (i, 0, 0)),
         ],
+        # The per-row-tile det/counts/ccheck rows carry a unit axis so each
+        # block equals the array's last two dims (Mosaic's block rule); it
+        # is squeezed away after the call.
         out_shape=[
             jax.ShapeDtypeStruct((m, 1), jnp.float32),
             jax.ShapeDtypeStruct((m, 1), jnp.int32),
-            jax.ShapeDtypeStruct((num_m, 1), jnp.int32),
+            jax.ShapeDtypeStruct((num_m, 1, 1), jnp.int32),
             jax.ShapeDtypeStruct((num_m, k, f), jnp.float32),
-            jax.ShapeDtypeStruct((num_m, k), jnp.float32),
+            jax.ShapeDtypeStruct((num_m, 1, k), jnp.float32),
             jax.ShapeDtypeStruct((num_m, 2, f), jnp.float32),
-            jax.ShapeDtypeStruct((num_m, 2), jnp.float32),
+            jax.ShapeDtypeStruct((num_m, 1, 2), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_m, block_k), jnp.float32),
@@ -354,8 +288,51 @@ def lloyd_step_ft(
             pltpu.VMEM((block_m, f), x.dtype),   # stash in the input dtype
             pltpu.SemaphoreType.DMA((STASH_SLOTS,)),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )
-    return kernel(meta, inj, x, c, cn)
+    mind, am, det, sums, counts, ucheck, ccheck = kernel(meta, inj, x, c, cn)
+    return mind, am, det[:, 0], sums, counts[:, 0], ucheck, ccheck[:, 0]
+
+
+def _kernel_update_tile(meta_ref, am_ref, x_ref, sums_ref, counts_ref):
+    """The update epilogue alone, on one row tile (row 0 of the tile is
+    row 0 here; ``meta`` holds the tile's count of true rows)."""
+    _emit_update(meta_ref, am_ref, sums_ref, counts_ref, x_ref, 0,
+                 am_ref.shape[0])
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def recompute_update_tile(x: jax.Array, am: jax.Array, meta: jax.Array, *,
+                          k: int, interpret: bool = False
+                          ) -> tuple[jax.Array, jax.Array]:
+    """Recompute one row tile's partial sums and counts with the kernel's
+    own update epilogue — the repair of a tile whose checksums failed.
+
+    x (bm, F) the tile's padded samples, am (bm, 1) int32 its corrected
+    assignment, meta (1,) int32 = [true rows in the tile]; ``k`` is the
+    padded cluster count. Running ``_emit_update`` itself, rather than an
+    XLA re-derivation of it, is what makes the repaired tile bit-identical
+    to an uncorrupted one on every backend. Returns (sums (1, K, F),
+    counts (1, K)), shaped like one row tile's partial blocks.
+    """
+    bm, f = x.shape
+    sums, counts = pl.pallas_call(
+        _kernel_update_tile,
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((bm, 1), lambda: (0, 0)),
+            pl.BlockSpec((bm, f), lambda: (0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, k, f), lambda: (0, 0, 0)),
+            pl.BlockSpec((1, 1, k), lambda: (0, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((1, k, f), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1, k), jnp.float32),
+        ],
+        compiler_params=compiler_params(),
+        interpret=interpret,
+    )(meta, am, x)
+    return sums, counts[:, 0]

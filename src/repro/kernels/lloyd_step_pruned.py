@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params, mxu_dot
 from repro.kernels.distance_argmin import MIN_INIT, fold_min, tile_min_argmin
 from repro.kernels.lloyd_step import (STASH_SLOTS, _emit_update,
                                       _stash_dma_start, _stash_dma_wait_last)
@@ -84,13 +84,13 @@ def _kernel_pruned(meta_ref, x_ref, c_ref, cn_ref, xn_ref, skip_ref,
     c_ref     : (bk, bf)    centroid tile
     cn_ref    : (1, bk)     centroid squared norms (+inf for padded slots)
     xn_ref    : (bm, 1)     row squared norms (0 for padded rows)
-    skip_ref  : (1, 1)      i32 — 1 iff this (row tile, centroid tile)
+    skip_ref  : (1, 1, 1, 1) i32 — 1 iff this (row tile, centroid tile)
                             cell is pruned this iteration
     mind_ref  : (bm, 1)     running minimum of d_ij  (output, revisited)
     argmin_ref: (bm, 1)     running argmin           (output, revisited)
     sums_ref  : (1, kp, fp) per-row-tile partial cluster sums (output)
-    counts_ref: (1, kp)     per-row-tile partial cluster counts (output)
-    tmin_ref  : (1, 1)      refreshed Euclidean group bound (output)
+    counts_ref: (1, 1, kp)  per-row-tile partial cluster counts (output)
+    tmin_ref  : (1, 1, 1, 1) refreshed Euclidean group bound (output)
     acc_ref   : (bm, bk)    VMEM scratch accumulator for X C^T
     xbuf_ref  : (bm, fp)    VMEM stash of the row tile's feature chunks
     sem_ref   : (2,)        DMA semaphores for the double-buffered stash
@@ -102,7 +102,7 @@ def _kernel_pruned(meta_ref, x_ref, c_ref, cn_ref, xn_ref, skip_ref,
     nf = pl.num_programs(2)
     bm = acc_ref.shape[0]
     bf = x_ref.shape[1]
-    live = skip_ref[0, 0] == 0
+    live = skip_ref[0, 0, 0, 0] == 0
 
     @pl.when(jnp.logical_and(c_idx == 0, f_idx == 0))
     def _init_outputs():
@@ -127,16 +127,14 @@ def _kernel_pruned(meta_ref, x_ref, c_ref, cn_ref, xn_ref, skip_ref,
     # The entire point: no MXU product for pruned tiles.
     @pl.when(live)
     def _accumulate():
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] += mxu_dot(x_ref[...], c_ref[...], (1, 1))
 
     @pl.when(jnp.logical_and(live, f_idx == nf - 1))
     def _min_epilogue():
         local_min, local_arg = tile_min_argmin(
             acc_ref[...], cn_ref[...], c_idx * acc_ref.shape[1])
         fold_min(mind_ref, argmin_ref, local_min, local_arg)
-        tmin_ref[...] = _tile_bound(meta_ref, xn_ref, local_min, m_idx, bm)
+        tmin_ref[0, 0] = _tile_bound(meta_ref, xn_ref, local_min, m_idx, bm)
 
     # The update epilogue is unconditional: a skipped last tile still
     # finalizes the row tile's argmin (skipping only omits losing folds).
@@ -168,16 +166,14 @@ def _kernel_smallk_pruned(meta_ref, x_ref, c_ref, cn_ref, xn_ref, skip_ref,
 
     _stash_dma_start(x_ref, xbuf_ref, sem_ref, f_idx, bf)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(x_ref[...], c_ref[...], (1, 1))
 
     @pl.when(f_idx == nf - 1)
     def _epilogue():
         local_min, local_arg = tile_min_argmin(acc_ref[...], cn_ref[...], 0)
         mind_ref[...] = local_min       # single visit: direct write
         argmin_ref[...] = local_arg
-        tmin_ref[...] = _tile_bound(meta_ref, xn_ref, local_min, m_idx, bm)
+        tmin_ref[0, 0] = _tile_bound(meta_ref, xn_ref, local_min, m_idx, bm)
         _stash_dma_wait_last(x_ref, xbuf_ref, sem_ref, nf, bf)
         _emit_update(meta_ref, argmin_ref, sums_ref, counts_ref, xbuf_ref,
                      m_idx, bm)
@@ -221,9 +217,12 @@ def lloyd_step_pruned(
     out_shape = [
         jax.ShapeDtypeStruct((m, 1), jnp.float32),
         jax.ShapeDtypeStruct((m, 1), jnp.int32),
+        # Per-tile rows and scalars carry unit axes so every block equals
+        # the array's last two dims, as Mosaic's block rule requires; they
+        # are squeezed away after the call.
         jax.ShapeDtypeStruct((num_m, k, f), jnp.float32),
-        jax.ShapeDtypeStruct((num_m, k), jnp.float32),
-        jax.ShapeDtypeStruct((num_m, num_k), jnp.float32),
+        jax.ShapeDtypeStruct((num_m, 1, k), jnp.float32),
+        jax.ShapeDtypeStruct((num_m, num_k, 1, 1), jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((block_m, block_k), jnp.float32),
@@ -245,48 +244,48 @@ def lloyd_step_pruned(
                 pl.BlockSpec((block_k, block_f), lambda i, t: (0, t)),
                 pl.BlockSpec((1, block_k), lambda i, t: (0, 0)),
                 pl.BlockSpec((block_m, 1), lambda i, t: (i, 0)),
-                pl.BlockSpec((1, 1), lambda i, t: (i, 0)),
+                pl.BlockSpec((1, 1, 1, 1), lambda i, t: (i, 0, 0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((block_m, 1), lambda i, t: (i, 0)),
                 pl.BlockSpec((block_m, 1), lambda i, t: (i, 0)),
                 pl.BlockSpec((1, k, f), lambda i, t: (i, 0, 0)),
-                pl.BlockSpec((1, k), lambda i, t: (i, 0)),
-                pl.BlockSpec((1, 1), lambda i, t: (i, 0)),
+                pl.BlockSpec((1, 1, k), lambda i, t: (i, 0, 0)),
+                pl.BlockSpec((1, 1, 1, 1), lambda i, t: (i, 0, 0, 0)),
             ],
             out_shape=out_shape,
             scratch_shapes=scratch,
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+            compiler_params=compiler_params("parallel", "arbitrary"),
             interpret=interpret,
         )
-        return kernel(meta, x, c, cn, xn, skip)
-
-    assert variant == "generic", f"unknown kernel variant {variant!r}"
-    assert skip.shape == (num_m, num_k), (
-        f"skip shape {skip.shape} != {(num_m, num_k)}")
-    kernel = pl.pallas_call(
-        _kernel_pruned,
-        grid=(m // block_m, k // block_k, f // block_f),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_m, block_f), lambda i, j, t: (i, t)),
-            pl.BlockSpec((block_k, block_f), lambda i, j, t: (j, t)),
-            pl.BlockSpec((1, block_k), lambda i, j, t: (0, j)),
-            pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, t: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1, k, f), lambda i, j, t: (i, 0, 0)),
-            pl.BlockSpec((1, k), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, t: (i, j)),
-        ],
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )
-    return kernel(meta, x, c, cn, xn, skip)
+    else:
+        assert variant == "generic", f"unknown kernel variant {variant!r}"
+        assert skip.shape == (num_m, num_k), (
+            f"skip shape {skip.shape} != {(num_m, num_k)}")
+        kernel = pl.pallas_call(
+            _kernel_pruned,
+            grid=(m // block_m, k // block_k, f // block_f),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((block_m, block_f), lambda i, j, t: (i, t)),
+                pl.BlockSpec((block_k, block_f), lambda i, j, t: (j, t)),
+                pl.BlockSpec((1, block_k), lambda i, j, t: (0, j)),
+                pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
+                pl.BlockSpec((1, 1, 1, 1), lambda i, j, t: (i, j, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
+                pl.BlockSpec((block_m, 1), lambda i, j, t: (i, 0)),
+                pl.BlockSpec((1, k, f), lambda i, j, t: (i, 0, 0)),
+                pl.BlockSpec((1, 1, k), lambda i, j, t: (i, 0, 0)),
+                pl.BlockSpec((1, 1, 1, 1), lambda i, j, t: (i, j, 0, 0)),
+            ],
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            compiler_params=compiler_params(
+                "parallel", "arbitrary", "arbitrary"),
+            interpret=interpret,
+        )
+    mind, am, sums, counts, tmin = kernel(meta, x, c, cn, xn,
+                                          skip[:, :, None, None])
+    return mind, am, sums, counts[:, 0], tmin[:, :, 0, 0]

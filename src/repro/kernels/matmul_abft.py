@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels._mosaic import compiler_params
 
 from repro.kernels.distance_argmin_ft import (INJ_LEN, make_injection,  # noqa: F401 — re-export
                                               no_injection,
@@ -164,8 +164,7 @@ def matmul_abft(
             pltpu.VMEM((block_m, 1), jnp.float32),
             pltpu.VMEM((block_m, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=compiler_params("parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
     )
     return kernel(inj, x, y)

@@ -770,14 +770,14 @@ def fused_lloyd_batched(
 
 def _verify_update_partials(plan: Any, am: jax.Array, sums_p: jax.Array,
                             counts_p: jax.Array, ucheck: jax.Array,
-                            ccheck: jax.Array, params: KernelParams
-                            ) -> tuple:
+                            ccheck: jax.Array, params: KernelParams,
+                            interpret: bool) -> tuple:
     """Verification interval of the fused update epilogue (paper Fig. 6
     applied to the one-hot product). Compares the observed e1/e2 column
     checksums of each row tile's partial sums/counts against the expected
     ones the kernel computed from its argmin/valid vectors, and recomputes
     a mismatched tile from the data plan and the (corrected) assignment.
-    The recompute replays the kernel's own one-hot arithmetic on the same
+    The recompute runs the kernel's own update epilogue on the same
     operands, so a recovered run is bit-identical to a clean one. Under
     the §II-A SEU model at most one tile can mismatch per step; every
     mismatch is counted, the worst tile is repaired.
@@ -814,16 +814,10 @@ def _verify_update_partials(plan: Any, am: jax.Array, sums_p: jax.Array,
         i = jnp.argmax(bad)
         x_tile = jax.lax.dynamic_slice(plan.xp, (i * bm, 0), (bm, fp))
         am_tile = jax.lax.dynamic_slice(am, (i * bm, 0), (bm, 1))
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0) + i * bm
-        valid = (rows < plan.m).astype(jnp.float32)
-        clusters = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
-        onehot = (am_tile == clusters).astype(jnp.float32) * valid
-        new_counts = jnp.sum(onehot, axis=0, keepdims=True)
-        new_sums = jax.lax.dot_general(
-            onehot.astype(x_tile.dtype), x_tile, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return (jax.lax.dynamic_update_slice(sums_p, new_sums[None],
-                                             (i, 0, 0)),
+        rows_in_tile = (plan.m - i * bm)[None].astype(jnp.int32)
+        new_sums, new_counts = _llft.recompute_update_tile(
+            x_tile, am_tile, rows_in_tile, k=kp, interpret=interpret)
+        return (jax.lax.dynamic_update_slice(sums_p, new_sums, (i, 0, 0)),
                 jax.lax.dynamic_update_slice(counts_p, new_counts, (i, 0)))
 
     sums_p, counts_p = jax.lax.cond(
@@ -862,7 +856,7 @@ def fused_lloyd_ft(
         plan.xp, cp, cn, meta, inj, block_m=params.block_m,
         block_k=params.block_k, block_f=params.block_f, interpret=interpret)
     sums_p, counts_p, det_up = _verify_update_partials(
-        plan, am, sums_p, counts_p, ucheck, ccheck, params)
+        plan, am, sums_p, counts_p, ucheck, ccheck, params, interpret)
     sums = _tree_sum(sums_p)[:k, :plan.f]
     counts = _tree_sum(counts_p)[:k]
     return (am[:m, 0], mind[:m, 0] + plan.xn, sums, counts,
@@ -1031,8 +1025,10 @@ def _plan_buffers(eqn: Any) -> tuple[tuple[BufferPlan, ...],
 
     def buf(role: str, aval: Any, shape: Any) -> BufferPlan:
         memory = "smem" if "smem" in str(aval).lower() else "vmem"
+        # JAX 0.9 block shapes hold ``Blocked`` entries, not ints
         return BufferPlan(role=role, memory=memory,
-                          block_shape=tuple(int(d) for d in shape),
+                          block_shape=tuple(int(getattr(d, "block_size", d))
+                                            for d in shape),
                           dtype=jnp.dtype(aval.dtype).name)
 
     maps = list(gm.block_mappings)

@@ -97,9 +97,12 @@ def centroid_update(x: jax.Array, assign: jax.Array, k: int) -> tuple[jax.Array,
     is sums / max(counts, 1); callers handle empty clusters. Accumulation
     is f32 for every input dtype (bf16 counts would lose exactness past
     256 members) — the same contract as the one-pass kernel's epilogue.
+    The product is pinned to full precision: XLA's default on TPU rounds
+    f32 operands to bf16.
     """
     onehot = jax.nn.one_hot(assign, k, dtype=x.dtype)   # (M, K)
     sums = jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     counts = jnp.sum(onehot.astype(jnp.float32), axis=0)
     return sums, counts

@@ -129,7 +129,6 @@ def attend(q, k, v, *, q_positions, kv_positions, causal: bool = True,
     collective schedule deterministic: none in attention itself, small
     psums for the k/v gradients only.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = shd.active_mesh()
@@ -152,14 +151,14 @@ def attend(q, k, v, *, q_positions, kv_positions, causal: bool = True,
         return _attend_local(q, k, v, q_positions=qpos, kv_positions=kvpos,
                              causal=causal, window=window, chunk=local_chunk)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(brow, "model", None, None),
                   P(brow, None, None, None),
                   P(brow, None, None, None),
                   P("model"), P(None)),
         out_specs=P(brow, "model", None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, q_positions, kv_positions)
 
 

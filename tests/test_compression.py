@@ -109,7 +109,6 @@ class TestCompressedPsum:
         residual bounded by half a quantization step. A size-1 axis makes
         the reduce an identity transport: red == deq(q(g))."""
         mesh = jax.make_mesh((1,), ("data",))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         g = jnp.asarray(_values(7, 1, 200, 5.0))
 
@@ -117,10 +116,10 @@ class TestCompressedPsum:
             red, res = compressed_psum(gl[0], "data")
             return red[None], res[None]
 
-        red, res = jax.jit(shard_map(
+        red, res = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=P("data", None),
             out_specs=(P("data", None), P("data", None)),
-            check_rep=False))(g)
+            check_vma=False))(g)
         assert red.shape == g.shape and red.dtype == jnp.float32
         assert res.shape == g.shape and res.dtype == jnp.float32
         q, scale = quantize(g[0])

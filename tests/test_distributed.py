@@ -128,11 +128,11 @@ class TestDistributedKMeans:
     def test_compressed_psum_error_feedback(self):
         out = run_with_devices("""
         import jax, jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.dist.compression import compressed_psum, quantize, dequantize
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 1024))
 
         def f(gl):
@@ -140,10 +140,10 @@ class TestDistributedKMeans:
             red, res = compressed_psum(gl, "data")
             return red[None], res[None]
 
-        red, res = jax.jit(shard_map(
+        red, res = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=P("data", None),
             out_specs=(P("data", None), P("data", None)),
-            check_rep=False))(g)
+            check_vma=False))(g)
         exact = jnp.sum(g, axis=0)
         err = float(jnp.max(jnp.abs(red[0] - exact)) /
                     jnp.max(jnp.abs(exact)))
@@ -165,8 +165,11 @@ class TestDistributedKMeans:
         from repro.train.optimizer import TrainConfig
         from repro.data.synthetic import TokenPipeline
 
+        from jax.sharding import AxisType
+
         cfg = get_config("internlm2-1.8b", smoke=True)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         shape = ShapeConfig("tiny", 32, 8, "train")
         # 4-step smoke: no warmup, lr high enough that descent beats noise
         tcfg = TrainConfig(learning_rate=1e-3, warmup_steps=0,

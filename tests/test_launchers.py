@@ -149,3 +149,37 @@ class TestAutotuneTable:
         _, pb = b.lookup(1024, 64, 64)    # falls back to the model winner
         assert [pa.block_m, pa.block_k, pa.block_f] == [64, 128, 128]
         assert (pb.block_m, pb.block_k, pb.block_f) != (0, 0, 0)
+
+
+class TestChipEntryPoints:
+    def test_compile_cache_follows_the_environment(self, monkeypatch,
+                                                   tmp_path):
+        import jax
+        from repro.launch.compile_cache import use_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+        assert use_compile_cache(tmp_path) == str(tmp_path / "c")
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_compile_cache_defaults_to_the_checkout(self, monkeypatch,
+                                                    tmp_path):
+        import jax
+        from repro.launch.compile_cache import use_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            path = use_compile_cache(tmp_path)
+            assert path == str(tmp_path.resolve() / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_chip_smoke_refuses_to_run_without_a_tpu(self):
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        out = subprocess.run([sys.executable,
+                              os.path.join(REPO, "chip_smoke.py")],
+                             capture_output=True, text=True, env=env,
+                             timeout=300)
+        assert out.returncode != 0
+        assert "needs a TPU" in out.stderr
+        assert '"ok"' not in out.stdout

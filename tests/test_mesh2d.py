@@ -132,7 +132,6 @@ class TestCompressedHop:
         the time-averaged reduction converges to the exact psum (err at
         T=32 is an order of magnitude under err at T=1)."""
         out = _run("""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist.compression import compressed_psum
 
@@ -144,11 +143,11 @@ class TestCompressedHop:
             red, res_n = compressed_psum(gl[0] + res[0], "host")
             return red[None], res_n[None]
 
-        step = jax.jit(shard_map(
+        step = jax.jit(jax.shard_map(
             hop, mesh=mesh,
             in_specs=(P("host", None), P("host", None)),
             out_specs=(P("host", None), P("host", None)),
-            check_rep=False))
+            check_vma=False))
         exact = jnp.sum(g, axis=0)
         res = jnp.zeros_like(g)
         total = jnp.zeros_like(exact)

@@ -1,0 +1,141 @@
+"""Compile rehearsal for the TPU v5e: the main-path kernels, lowered by
+Mosaic for a described (not attached) chip.
+
+Interpret mode runs a kernel body in Python and enforces none of Mosaic's
+rules — block shapes whose last two dims are neither (8, 128)-aligned nor
+equal to the array's, vector ops without a lowering, working sets over the
+scoped-VMEM limit. Each test here compiles one ``ops`` wrapper with
+``interpret=False`` at the chip smoke's widths (SIFT1M: N=1,048,576,
+F=128; the batched stack B=16, N=65,536, K=256) with the tiles the
+autotuner picks, and asserts the kernel reached the compiled program
+(``tpu_custom_call``). A compile that passes is not a chip run:
+``chip_smoke.py`` is.
+
+The topology is described inside a module fixture — never at import —
+because only one process at a time may load the TPU library, and every
+pytest-xdist worker imports every test file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.api.cache import AutotuneCache
+from repro.kernels import ops
+from repro.kernels.kmeanspp_init import init_kmeanspp_fused
+
+N, F = 1_048_576, 128
+B, BN, BK = 16, 65_536, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip; keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _tuned(kind, m, k, f, *, dtype=jnp.float32, batch=1):
+    """The autotuner's pick, from an in-memory table (no file is read)."""
+    variant, p = AutotuneCache(None).lookup(m, k, f, kind=kind, dtype=dtype,
+                                            batch=batch)
+    return variant, ops.clamp_params(m, k, f, p, dtype=dtype)
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _sds(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("k,variant", [(1024, "generic"), (128, "smallk")])
+def test_fused_assign(one_chip, k, variant):
+    _, p = _tuned("assign", N, k, F)
+    assert ops.resolve_variant(k, p) == variant
+    txt = _compiled_text(
+        lambda x, c: ops.fused_assign(x, c, p, variant=variant,
+                                      interpret=False),
+        _sds(one_chip, (N, F)), _sds(one_chip, (k, F)))
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("k,variant", [(1024, "generic"), (128, "smallk")])
+def test_fused_lloyd(one_chip, k, variant):
+    _, p = _tuned("lloyd", N, k, F)
+    assert ops.resolve_variant(k, p) == variant
+    txt = _compiled_text(
+        lambda x, c: ops.fused_lloyd(x, c, p, variant=variant,
+                                     interpret=False),
+        _sds(one_chip, (N, F)), _sds(one_chip, (k, F)))
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("k,variant", [(1024, "generic"), (128, "smallk")])
+def test_fused_lloyd_pruned(one_chip, k, variant):
+    _, p = _tuned("pruned", N, k, F)
+    assert ops.resolve_variant(k, p) == variant
+    txt = _compiled_text(
+        lambda x, c: ops.fused_lloyd_pruned(x, c, p, variant=variant,
+                                            interpret=False),
+        _sds(one_chip, (N, F)), _sds(one_chip, (k, F)))
+    assert "tpu_custom_call" in txt
+
+
+def test_fused_lloyd_ft(one_chip):
+    _, p = _tuned("lloyd_ft", N, 1024, F)
+    txt = _compiled_text(
+        lambda x, c: ops.fused_lloyd_ft(x, c, p, interpret=False),
+        _sds(one_chip, (N, F)), _sds(one_chip, (1024, F)))
+    assert "tpu_custom_call" in txt
+
+
+def test_fused_assign_ft(one_chip):
+    _, p = _tuned("assign", N, 1024, F)
+    txt = _compiled_text(
+        lambda x, c: ops.fused_assign_ft(x, c, p, interpret=False),
+        _sds(one_chip, (N, F)), _sds(one_chip, (1024, F)))
+    assert "tpu_custom_call" in txt
+
+
+def test_fused_assign_int8(one_chip):
+    _, p = _tuned("int8", N, 1024, F, dtype=jnp.int8)
+    txt = _compiled_text(
+        lambda x, c: ops.fused_assign_int8(x, c, p, interpret=False),
+        _sds(one_chip, (N, F)), _sds(one_chip, (1024, F)))
+    assert "tpu_custom_call" in txt
+
+
+def test_fused_lloyd_batched(one_chip):
+    _, p = _tuned("batched", BN, BK, F, batch=B)
+    txt = _compiled_text(
+        lambda x, c: ops.fused_lloyd_batched(x, c, p, interpret=False),
+        _sds(one_chip, (B, BN, F)), _sds(one_chip, (B, BK, F)))
+    assert "tpu_custom_call" in txt
+
+
+def test_kmeanspp_round(one_chip):
+    _, p = _tuned("init", BN, BK, F)
+    txt = _compiled_text(
+        lambda keys, x: init_kmeanspp_fused(keys, x, BK, params=p,
+                                            use_kernel=True,
+                                            interpret=False),
+        _sds(one_chip, (B, 2), jnp.uint32), _sds(one_chip, (B, BN, F)))
+    assert "tpu_custom_call" in txt
