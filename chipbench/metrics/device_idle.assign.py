@@ -1,0 +1,7 @@
+"""``device_idle.assign``: the share of the traced window in which no
+operation ran on the device (1 - busy / window), in a serving cell."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

@@ -1,0 +1,8 @@
+"""``device_idle.fit``: the share of the traced window in which no
+operation ran on the device (1 - busy / window), in an unbatched
+fit cell."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

@@ -1,0 +1,7 @@
+"""``lloyd_step_batched_roofline``: the lloyd_step_batched kernel's share of its roofline (see
+``chipbench/roofline.py`` and ``chipbench/costs/lloyd_step_batched.py``)."""
+from chipbench import roofline
+
+
+def read(ctx):
+    return roofline.share(ctx, "lloyd_step_batched")
