@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.cache import AutotuneCache, default_cache
 from repro.api.policy import FaultPolicy, InjectionCampaign
 from repro.api.registry import AssignmentBackend
@@ -224,7 +225,6 @@ class KMeans:
                 + "; the flag is ignored here",
                 DeprecationWarning, stacklevel=2)
         self._step_cache: dict[tuple, Callable[..., Any]] = {}
-        self._n_host_syncs: int = 0   # fit-loop host reads (observability)
         # streaming state (partial_fit)
         self._counts: Optional[jax.Array] = None
 
@@ -422,19 +422,23 @@ class KMeans:
                 def body(carry: tuple, t: jax.Array) -> tuple:
                     centroids, am, inertia, done, det, bounds = carry
 
+                    @obs.scope("step")
                     def live(_: None) -> tuple:
                         xa = plan if takes_plan else plan.x
-                        out = backend(xa, self._cast(centroids),
-                                      params=params if takes_params
-                                      else None, bounds=bounds)
-                        am_b, md, det_i, new_c, counts = self._apply_update(
-                            out, plan.x, centroids)
+                        with obs.scope("assign"):
+                            out = backend(xa, self._cast(centroids),
+                                          params=params if takes_params
+                                          else None, bounds=bounds)
+                        with obs.scope("update"):
+                            am_b, md, det_i, new_c, counts = \
+                                self._apply_update(out, plan.x, centroids)
                         new_bounds, pfrac = out[5], out[6]
                         inertia_i = jnp.sum(md)
                         shift = jnp.sqrt(jnp.sum((new_c - centroids) ** 2))
-                        new_c = reseed_empty(
-                            jax.random.fold_in(key, it0 + t),
-                            plan.x, new_c, counts, md)
+                        with obs.scope("reseed"):
+                            new_c = reseed_empty(
+                                jax.random.fold_in(key, it0 + t),
+                                plan.x, new_c, counts, md)
                         return (new_c, am_b, inertia_i, shift,
                                 det + det_i.astype(jnp.int32),
                                 new_bounds, pfrac)
@@ -469,17 +473,21 @@ class KMeans:
                 centroids, am, inertia, done, det = carry
                 inj, t = xs
 
+                @obs.scope("step")
                 def live(_: None) -> tuple:
                     xa = plan if takes_plan else plan.x
-                    out = backend(xa, self._cast(centroids),
-                                  params=params if takes_params else None,
-                                  inj=inj if takes_inj else None)
-                    am_b, md, det_i, new_c, counts = self._apply_update(
-                        out, plan.x, centroids)
+                    with obs.scope("assign"):
+                        out = backend(xa, self._cast(centroids),
+                                      params=params if takes_params else None,
+                                      inj=inj if takes_inj else None)
+                    with obs.scope("update"):
+                        am_b, md, det_i, new_c, counts = self._apply_update(
+                            out, plan.x, centroids)
                     inertia_i = jnp.sum(md)
                     shift = jnp.sqrt(jnp.sum((new_c - centroids) ** 2))
-                    new_c = reseed_empty(jax.random.fold_in(key, it0 + t),
-                                         plan.x, new_c, counts, md)
+                    with obs.scope("reseed"):
+                        new_c = reseed_empty(jax.random.fold_in(key, it0 + t),
+                                             plan.x, new_c, counts, md)
                     return (new_c, am_b, inertia_i, shift,
                             det + det_i.astype(jnp.int32))
 
@@ -560,7 +568,8 @@ class KMeans:
         centroids = jnp.asarray(centroids, jnp.float32)
         if self.batch_size is not None:
             return self._fit_minibatch(x, centroids, on_iteration)
-        return self._fit_fullbatch(x, centroids, key, on_iteration)
+        with obs.span("fit"):
+            return self._fit_fullbatch(x, centroids, key, on_iteration)
 
     def _fit_fullbatch(self, x: jax.Array, centroids: jax.Array,
                        key: jax.Array, on_iteration: Optional[Callable]
@@ -574,20 +583,21 @@ class KMeans:
         # plan is built in the compute dtype so the per-iteration cost of a
         # bf16/fp16 fit is zero casts of X — only the (K, F) centroids are
         # cast per step.
-        if self._backend.supports_int8:
-            # quantize + pad once per fit; QuantPlan.x keeps the original
-            # samples, so the two-pass centroid update and empty-cluster
-            # reseeding stay full precision
-            plan: Any = ops.plan_data_int8(self._cast(x), params)
-        else:
-            plan = ops.plan_data(self._cast(x), params)
-        # bounds-carrying backends start every fit from a fresh (all-
-        # compute) state: a warm start / from_state restore never inherits
-        # bounds, so a centroid hot-swap can't leave stale Hamerly bounds
-        supports_bounds = self._backend.supports_bounds
-        bounds = self._backend.bounds_init(
-            m, self.n_clusters, f, params, dtype=self.compute_dtype) \
-            if supports_bounds else None
+        with obs.span("plan"):
+            if self._backend.supports_int8:
+                # quantize + pad once per fit; QuantPlan.x keeps the original
+                # samples, so the two-pass centroid update and empty-cluster
+                # reseeding stay full precision
+                plan: Any = ops.plan_data_int8(self._cast(x), params)
+            else:
+                plan = ops.plan_data(self._cast(x), params)
+            # bounds-carrying backends start every fit from a fresh (all-
+            # compute) state: a warm start / from_state restore never inherits
+            # bounds, so a centroid hot-swap can't leave stale Hamerly bounds
+            supports_bounds = self._backend.supports_bounds
+            bounds = self._backend.bounds_init(
+                m, self.n_clusters, f, params, dtype=self.compute_dtype) \
+                if supports_bounds else None
         self.prune_history_ = []
 
         am = jnp.zeros((m,), jnp.int32)
@@ -595,38 +605,39 @@ class KMeans:
         inertia = jnp.float32(jnp.inf)
         inertia_host = float("inf")
         it0 = 0
-        self._n_host_syncs = 0
         while it0 < self.max_iter:
             n_steps = min(self.sync_every, self.max_iter - it0)
-            chunk = self._chunk_fn(params, n_steps)
+            # the chunk's last operand: the bounds state, or its campaign
             if supports_bounds:
-                centroids, am, inertia, det, done_d, hist, bounds = chunk(
-                    plan, centroids, am, det, inertia, key,
-                    jnp.int32(it0), bounds)
-            else:
-                if takes_inj:
-                    # pre-draw the chunk's campaign schedule: same host
-                    # RNG consumption order as the per-iteration loop had
-                    inj_stack = jnp.stack([
+                carried: Any = bounds
+            elif takes_inj:
+                # pre-draw the chunk's campaign schedule: same host RNG
+                # consumption order as the per-iteration loop had
+                with obs.span("campaign"):
+                    carried = jnp.stack([
                         self._draw_injection(inj_rng, m, f, params)
                         for _ in range(n_steps)])
-                else:
-                    inj_stack = jnp.zeros((n_steps, 1), jnp.int32)
-                centroids, am, inertia, det, done_d, hist = chunk(
-                    plan, centroids, am, det, inertia, key,
-                    jnp.int32(it0), inj_stack)
+            else:
+                carried = jnp.zeros((n_steps, 1), jnp.int32)
+            with obs.span("dispatch"):
+                out = self._chunk_fn(params, n_steps)(
+                    plan, centroids, am, det, inertia, key, jnp.int32(it0),
+                    carried)
+            centroids, am, inertia, det, done_d, hist = out[:6]
+            if supports_bounds:
+                bounds = out[6]
             # the chunk boundary: the only device->host sync of the window.
             # The (n_steps, K, F) centroid history crosses only when a
             # callback will actually read it.
             cs_d, in_d, sh_d, act_d = hist[:4]
             pf_d = hist[4] if supports_bounds else None
-            if on_iteration is None:
-                done, in_h, sh_h, act_h, pf_h = _host_read(
-                    (done_d, in_d, sh_d, act_d, pf_d))
-            else:
-                done, cs_h, in_h, sh_h, act_h, pf_h = _host_read(
-                    (done_d, cs_d, in_d, sh_d, act_d, pf_d))
-            self._n_host_syncs += 1
+            with obs.span("sync"):
+                if on_iteration is None:
+                    done, in_h, sh_h, act_h, pf_h = _host_read(
+                        (done_d, in_d, sh_d, act_d, pf_d))
+                else:
+                    done, cs_h, in_h, sh_h, act_h, pf_h = _host_read(
+                        (done_d, cs_d, in_d, sh_d, act_d, pf_d))
             executed = int(act_h.sum())
             if on_iteration is not None:
                 for t in range(executed):
@@ -643,8 +654,8 @@ class KMeans:
 
         self.cluster_centers_ = centroids
         self.n_iter_ = max(1, it0)
-        self.detected_errors_ = int(_host_read(det))
-        self._n_host_syncs += 1
+        with obs.span("sync"):
+            self.detected_errors_ = int(_host_read(det))
         self._counts = None
         self.labels_ = am
         self.inertia_ = inertia_host

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.cache import AutotuneCache, default_cache
 from repro.api.estimator import _host_read
 from repro.api.registry import (AssignmentBackend, BackendCapabilityError,
@@ -63,17 +64,21 @@ def make_batched_chunk(backend, params, cast, tol: float, n_steps: int):
         # draws donors from the unpadded samples
         x = plan.x if takes_params else plan
 
+        @obs.scope("step")
         def body(carry, t):
             c, am, inertia, done, det = carry
-            out = backend(plan, cast(c),
-                          params=params if takes_params else None)
+            with obs.scope("assign"):
+                out = backend(plan, cast(c),
+                              params=params if takes_params else None)
             am_n, md, det_i, sums, counts = out
             inertia_n = jnp.sum(md, axis=1)                    # (B,)
-            new_c = jax.vmap(means_from_sums)(sums, counts, c)
+            with obs.scope("update"):
+                new_c = jax.vmap(means_from_sums)(sums, counts, c)
             shift = jnp.sqrt(jnp.sum((new_c - c) ** 2, axis=(1, 2)))
-            rk = jax.vmap(
-                lambda kb: jax.random.fold_in(kb, it0 + t))(keys)
-            new_c = jax.vmap(reseed_empty)(rk, x, new_c, counts, md)
+            with obs.scope("reseed"):
+                rk = jax.vmap(
+                    lambda kb: jax.random.fold_in(kb, it0 + t))(keys)
+                new_c = jax.vmap(reseed_empty)(rk, x, new_c, counts, md)
             live = jnp.logical_not(done)                       # (B,)
             new_c = jnp.where(live[:, None, None], new_c, c)
             am_o = jnp.where(live[:, None], am_n, am)
@@ -336,10 +341,20 @@ class BatchedKMeans:
             keys, subs = split[:, 0], split[:, 1]
             centroids = self.init_centroids(x, subs)
         centroids = jnp.asarray(centroids, jnp.float32)
+        with obs.span("fit"):
+            return self._fit_chunks(x, centroids, keys)
+
+    def _fit_chunks(self, x: jax.Array, centroids: jax.Array,
+                    keys: jax.Array) -> "BatchedKMeans":
+        """The device-resident Lloyd loop of :meth:`fit`, from seeded
+        centroids: the per-fit plan, then one ``sync_every`` chunk and one
+        host read per round."""
+        bsz, n, f = x.shape
         params = self._resolve_params(bsz, n, f)
-        # per-fit batch plan: pad + row-norm the whole (B, N, F) block once
-        plan = ops.plan_data_batched(self._cast(x), params) \
-            if self._backend.takes_params else self._cast(x)
+        with obs.span("plan"):
+            # per-fit batch plan: pad + row-norm the whole (B, N, F) block once
+            plan = ops.plan_data_batched(self._cast(x), params) \
+                if self._backend.takes_params else self._cast(x)
 
         am = jnp.zeros((bsz, n), jnp.int32)
         inertia = jnp.full((bsz,), jnp.inf, jnp.float32)
@@ -349,11 +364,13 @@ class BatchedKMeans:
         it0 = 0
         while it0 < self.max_iter:
             n_steps = min(self.sync_every, self.max_iter - it0)
-            chunk = self._chunk_fn(params, n_steps)
-            (centroids, am, inertia, done, det), live_hist = chunk(
-                plan, centroids, am, inertia, done, det, keys,
-                jnp.int32(it0))
-            done_h, live_h = _host_read((done, live_hist))
+            with obs.span("dispatch"):
+                chunk = self._chunk_fn(params, n_steps)
+                (centroids, am, inertia, done, det), live_hist = chunk(
+                    plan, centroids, am, inertia, done, det, keys,
+                    jnp.int32(it0))
+            with obs.span("sync"):
+                done_h, live_h = _host_read((done, live_hist))
             iters += live_h.sum(axis=0).astype(np.int64)
             it0 += n_steps
             if bool(done_h.all()):
@@ -361,7 +378,8 @@ class BatchedKMeans:
 
         self.cluster_centers_ = centroids
         self.labels_ = am
-        inertia_h, det_h = _host_read((inertia, det))
+        with obs.span("sync"):
+            inertia_h, det_h = _host_read((inertia, det))
         self.inertia_ = np.asarray(inertia_h, np.float64)
         self.n_iter_ = np.maximum(iters, 1)
         self.detected_errors_ = int(det_h)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.extend import core as jex_core
 
+from repro import obs
 from repro.dist.compression import quantize_rows as _quantize_rows
 from repro.kernels import distance_argmin as _da
 from repro.kernels import distance_argmin_ft as _daft
@@ -510,11 +511,12 @@ def fused_assign_int8(
 def _tree_sum(a: jax.Array) -> jax.Array:
     """Balanced pairwise reduction over axis 0 (log2 depth, better fp
     behaviour than a linear fold for many partial blocks)."""
-    while a.shape[0] > 1:
-        half = a.shape[0] // 2
-        rest = a[2 * half:]
-        a = jnp.concatenate([a[:half] + a[half:2 * half], rest], axis=0)
-    return a[0]
+    with obs.scope("partials"):
+        while a.shape[0] > 1:
+            half = a.shape[0] // 2
+            rest = a[2 * half:]
+            a = jnp.concatenate([a[:half] + a[half:2 * half], rest], axis=0)
+        return a[0]
 
 
 def fused_lloyd(
