@@ -161,15 +161,39 @@ def _emit_update(meta_ref, argmin_ref, sums_ref, counts_ref, xbuf_ref,
                  m_idx, bm):
     """Shared one-hot update epilogue: final argmin -> per-cluster partial
     sums/counts for this row tile. The one-hot matrix is exact (0/1) in the
-    stash dtype, so a 2-byte stash loses nothing; accumulation is f32."""
+    stash dtype, so a 2-byte stash loses nothing; accumulation is f32.
+
+    An f32 stash is split exactly into three bf16 slices (hi + mid + lo ==
+    x for normal f32) and the product takes one bf16 MXU pass per slice.
+    ``Precision.HIGHEST`` would take six, three of them against the
+    one-hot's bf16 mid and lo parts, which are zero."""
     kp = counts_ref.shape[-1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0) + m_idx * bm
     valid = (rows < meta_ref[0]).astype(jnp.float32)           # (bm, 1)
     clusters = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
     onehot = (argmin_ref[...] == clusters).astype(jnp.float32) * valid
     counts_ref[0] = jnp.sum(onehot, axis=0, keepdims=True)     # (1, kp)
-    sums_ref[...] = mxu_dot(onehot.astype(xbuf_ref.dtype), xbuf_ref[...],
-                            (0, 0))[None]                      # (1, kp, fp)
+    x = xbuf_ref[...]
+    if x.dtype != jnp.float32:
+        sums = mxu_dot(onehot.astype(x.dtype), x, (0, 0))
+    else:
+        onehot = onehot.astype(jnp.bfloat16)
+        hi, mid, lo = (mxu_dot(onehot, s, (0, 0)) for s in _bf16_slices(x))
+        sums = (hi + mid) + lo
+    sums_ref[...] = sums[None]                                 # (1, kp, fp)
+
+
+def _bf16_slices(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Split f32 ``x`` into bf16 ``(hi, mid, lo)`` with hi + mid + lo == x.
+
+    Each slice holds the next 8 significant bits of what the slices before
+    it left over, and each residual is exact in f32, so the three slices
+    carry all 24 bits of a normal f32."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
 
 
 def _kernel_smallk(meta_ref, x_ref, c_ref, cn_ref,
@@ -253,14 +277,8 @@ def _kernel_batched(meta_ref, x_ref, c_ref, cn_ref,
         mind_ref[0] = local_min      # single visit: direct write
         argmin_ref[0] = local_arg
         _stash_dma_wait_last(x_ref.at[0], xbuf_ref, sem_ref, nf, bf)
-        kp = counts_ref.shape[-1]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0) + m_idx * bm
-        valid = (rows < meta_ref[0]).astype(jnp.float32)
-        clusters = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
-        onehot = (local_arg == clusters).astype(jnp.float32) * valid
-        counts_ref[0, 0] = jnp.sum(onehot, axis=0, keepdims=True)
-        sums_ref[0, 0] = mxu_dot(onehot.astype(xbuf_ref.dtype),
-                                 xbuf_ref[...], (0, 0))
+        _emit_update(meta_ref, argmin_ref.at[0], sums_ref.at[0],
+                     counts_ref.at[0], xbuf_ref, m_idx, bm)
 
 
 @functools.partial(

@@ -197,7 +197,9 @@ def _kernel(meta_ref, inj_ref, x_ref, c_ref, cn_ref,
         valid = (rows < meta_ref[0]).astype(jnp.float32)           # (bm, 1)
 
         # expected checksums of the one-hot product, from the argmin/valid
-        # vectors and the stashed tiles — never from the product itself
+        # vectors and the stashed tiles — never from the product itself.
+        # They stay at full f32 precision (not the product's bf16 slices):
+        # the encoder reaches kp + 1, which bf16 does not hold exactly
         amp1 = valid * (argmin_ref[...] + 1).astype(jnp.float32)   # (bm, 1)
         enc = jnp.concatenate([valid, amp1], axis=1)               # (bm, 2)
         ucheck_ref[...] = mxu_dot(

@@ -817,8 +817,13 @@ def _verify_update_partials(plan: Any, am: jax.Array, sums_p: jax.Array,
         x_tile = jax.lax.dynamic_slice(plan.xp, (i * bm, 0), (bm, fp))
         am_tile = jax.lax.dynamic_slice(am, (i * bm, 0), (bm, 1))
         rows_in_tile = (plan.m - i * bm)[None].astype(jnp.int32)
-        new_sums, new_counts = _llft.recompute_update_tile(
-            x_tile, am_tile, rows_in_tile, k=kp, interpret=interpret)
+        # The barrier keeps XLA from fusing the writes below into the
+        # kernel call: such a fusion runs under XLA's default scoped-VMEM
+        # limit (16 MiB), not the kernel's own, and the update epilogue of
+        # a (1024, 4096) one-hot tile does not fit it.
+        new_sums, new_counts = jax.lax.optimization_barrier(
+            _llft.recompute_update_tile(
+                x_tile, am_tile, rows_in_tile, k=kp, interpret=interpret))
         return (jax.lax.dynamic_update_slice(sums_p, new_sums, (i, 0, 0)),
                 jax.lax.dynamic_update_slice(counts_p, new_counts, (i, 0)))
 

@@ -19,7 +19,7 @@ from repro.core.autotune import feasible, model_score, select_params
 from repro.core.fault import (draw_step_injection, no_step_injection,
                               planned_injections)
 from repro.data.blobs import make_blobs
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.kernels.lloyd_step_ft import INJ_LEN, make_injection, no_injection
 from repro.kernels.ops import KernelParams
 
@@ -59,6 +59,37 @@ class TestFusedLloydFtParity:
         a2 = ops.fused_lloyd_ft(x, c, params, interpret=True)
         for got, want in zip(a1, a2):
             np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+class TestUpdateEpilogueExactness:
+    """The f32 update epilogue splits X into three bf16 slices, hi + mid +
+    lo == x, and sums the three one-hot products. With every row its own
+    cluster each sum holds one row, so it must equal that row bit for bit:
+    a split into two slices, or a dropped slice, loses its low bits."""
+
+    @pytest.mark.parametrize("lloyd", [ops.fused_lloyd, ops.fused_lloyd_ft])
+    @pytest.mark.parametrize("m,f", [(256, 128), (300, 33)])
+    def test_singleton_sums_equal_rows_bit_for_bit(self, lloyd, m, f):
+        x = jax.random.normal(jax.random.PRNGKey(11), (m, f), jnp.float32)
+        hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+        mid = (x - hi).astype(jnp.bfloat16).astype(jnp.float32)
+        # the rows use the full 24-bit mantissa: two slices fall short
+        assert float(jnp.mean((hi + mid != x).astype(jnp.float32))) > 0.9
+        out = lloyd(x, x, interpret=True)           # C = X, K = M
+        np.testing.assert_array_equal(np.asarray(out[0]), np.arange(m))
+        np.testing.assert_array_equal(np.asarray(out[2]), np.asarray(x))
+        np.testing.assert_array_equal(np.asarray(out[3]), np.ones(m))
+
+    @pytest.mark.parametrize("lloyd", [ops.fused_lloyd, ops.fused_lloyd_ft])
+    def test_sums_match_reference(self, lloyd):
+        kx, kc = jax.random.split(jax.random.PRNGKey(12))
+        # positive rows: no cancellation, so rtol bounds every sum
+        x = jax.random.uniform(kx, (512, 128), jnp.float32, 0.5, 1.5)
+        c = jax.random.uniform(kc, (64, 128), jnp.float32, 0.5, 1.5)
+        out = lloyd(x, c, interpret=True)
+        rsums, rcounts = ref.centroid_update(x, out[0], 64)
+        np.testing.assert_allclose(out[2], rsums, rtol=1e-6)
+        np.testing.assert_array_equal(np.asarray(out[3]), np.asarray(rcounts))
 
 
 class TestInjectionCorrection:
@@ -137,18 +168,24 @@ class TestDtypeThresholds:
         assert checksum.rounding_eps(jnp.float32) \
             == float(jnp.finfo(jnp.float32).eps)
 
-    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16,
+                                       jnp.float32])
     @pytest.mark.parametrize("seed", [0, 1, 2, 3])
     def test_clean_low_precision_zero_detections(self, dtype, seed):
         """False-positive regression (the dtype-threshold footgun): clean
         bf16/fp16 data must never trip the detector, in either the
         distance ABFT or the update-epilogue checksums, over a seeded
-        grid of shapes."""
-        for m, k, f in [(256, 16, 64), (300, 7, 33), (513, 129, 257)]:
+        grid of shapes. Clean f32 must not either: its one-hot product is
+        taken in three bf16 slices while the expected checksums stay at
+        full precision. The last shape has the IVF4096 fit's row tile
+        (1024 rows), the update checksums' contraction length."""
+        for m, k, f, p in [(256, 16, 64, None), (300, 7, 33, None),
+                           (513, 129, 257, None),
+                           (2048, 600, 128, KernelParams(1024, 512, 128))]:
             x, c = _data(m, k, f, seed=seed, dtype=dtype)
-            _, _, det = ops.fused_assign_ft(x, c, interpret=True)
+            _, _, det = ops.fused_assign_ft(x, c, p, interpret=True)
             assert int(det) == 0, (m, k, f, "assign_ft")
-            _, _, _, _, det = ops.fused_lloyd_ft(x, c, interpret=True)
+            _, _, _, _, det = ops.fused_lloyd_ft(x, c, p, interpret=True)
             assert int(det) == 0, (m, k, f, "lloyd_ft")
 
     def test_update_thresholds_are_per_checksum_pair(self):

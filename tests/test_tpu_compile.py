@@ -107,6 +107,16 @@ def test_fused_lloyd_ft(one_chip):
     assert "tpu_custom_call" in txt
 
 
+def test_fused_lloyd_ft_ivf4096(one_chip):
+    """The IVF4096 fit's K: the update epilogue's one-hot tile is
+    (1024, 4096), and the tile recompute must still fit its VMEM."""
+    _, p = _tuned("lloyd_ft", 1_000_000, 4096, F)
+    txt = _compiled_text(
+        lambda x, c: ops.fused_lloyd_ft(x, c, p, interpret=False),
+        _sds(one_chip, (1_000_000, F)), _sds(one_chip, (4096, F)))
+    assert "tpu_custom_call" in txt
+
+
 def test_fused_assign_ft(one_chip):
     _, p = _tuned("assign", N, 1024, F)
     txt = _compiled_text(
