@@ -49,9 +49,11 @@ class AssignmentBackend:
     fuses_update:    one-pass Lloyd backend — returns the extended 5-tuple
                      ``(assign, min_dist, detected, sums, counts)`` so the
                      driver skips the separate centroid-update pass over X.
-    supports_batch:  many-problem backend — ``x`` is a (B, N, F) stack and
-                     ``c`` a (B, K, F) per-problem centroid stack; every
-                     output gains the leading problem axis. Single-problem
+    supports_batch:  many-problem backend — ``x`` is a (B, N, F) stack or
+                     a :class:`~repro.kernels.ops.BatchPlan` (whose
+                     problems may differ in row count) and ``c`` a
+                     (B, K, F) per-problem centroid stack; every output
+                     gains the leading problem axis. Single-problem
                      drivers must not route (M, F) data here and batched
                      drivers (``repro.batch``) require the flag.
     supports_bounds: stateful pruned backend — accepts an iteration-carried

@@ -7,8 +7,11 @@ One :class:`BatchedKMeans` fits B independent clustering problems at once:
     labels = bkm.predict(x)     # (B, N) per-problem labels
     state = bkm.get_state()     # serializable fitted state
 
+    bkm.fit(rows, lengths=n)    # rows (sum N, F): B ragged problems packed
+    bkm.labels_                 # (sum N,) labels, packed the same way
+
 The whole fit is one kernel launch per iteration (the batched one-pass
-Lloyd kernel maps problems to the outermost grid dimension) and one
+Lloyd kernel runs over every problem's row tiles) and one
 ``lax.scan`` per ``sync_every``-iteration chunk: per-problem convergence
 masks freeze finished problems in place, so early convergers stop updating
 without desynchronizing the batch, and per-problem results are
@@ -49,21 +52,16 @@ def make_batched_chunk(backend, params, cast, tol: float, n_steps: int):
     frozen: early convergers stop *changing* without desynchronizing the
     batch (one problem's convergence can never alter another's
     arithmetic). The returned callable maps
-    ``(plan, centroids, am0, inertia0, done0, det0, keys, it0)`` to
+    ``(plan, centroids, am0, inertia0, done0, det0)`` to
     ``((centroids, am, inertia, done, det), live_hist)`` where ``plan`` is
-    a :class:`~repro.kernels.ops.BatchPlan` for ``takes_params`` backends
-    and the cast (B, N, F) stack otherwise, and ``live_hist`` has shape
+    the :class:`~repro.kernels.ops.BatchPlan` of the B problems (labels
+    (B, n_max), 0 past each problem's rows) and ``live_hist`` has shape
     ``(n_steps, B)``.
     """
-    from repro.core.kmeans import means_from_sums, reseed_empty
+    from repro.core.kmeans import means_from_sums
     takes_params = backend.takes_params
 
-    def chunk(plan, centroids, am0, inertia0, done0, det0, keys, it0):
-        # the BatchPlan feeds the kernel directly (takes_params); the
-        # XLA analogue gets the cast stack itself; reseeding always
-        # draws donors from the unpadded samples
-        x = plan.x if takes_params else plan
-
+    def chunk(plan, centroids, am0, inertia0, done0, det0):
         @obs.scope("step")
         def body(carry, t):
             c, am, inertia, done, det = carry
@@ -76,9 +74,7 @@ def make_batched_chunk(backend, params, cast, tol: float, n_steps: int):
                 new_c = jax.vmap(means_from_sums)(sums, counts, c)
             shift = jnp.sqrt(jnp.sum((new_c - c) ** 2, axis=(1, 2)))
             with obs.scope("reseed"):
-                rk = jax.vmap(
-                    lambda kb: jax.random.fold_in(kb, it0 + t))(keys)
-                new_c = jax.vmap(reseed_empty)(rk, x, new_c, counts, md)
+                new_c = reseed_empty_batched(plan, new_c, counts, md)
             live = jnp.logical_not(done)                       # (B,)
             new_c = jnp.where(live[:, None, None], new_c, c)
             am_o = jnp.where(live[:, None], am_n, am)
@@ -94,16 +90,40 @@ def make_batched_chunk(backend, params, cast, tol: float, n_steps: int):
     return chunk
 
 
+def reseed_empty_batched(plan, centroids: jax.Array, counts: jax.Array,
+                         md: jax.Array) -> jax.Array:
+    """:func:`~repro.core.kmeans.reseed_empty` for each problem of a
+    :class:`~repro.kernels.ops.BatchPlan`, its donors drawn from its own
+    rows only: the ``r``-th empty cluster takes the ``r``-th farthest row
+    (``top_k`` orders ties by row, as the stable ``argsort`` does). It
+    runs only in a step that left some cluster empty."""
+    def reseed(c):
+        far = jnp.where(plan.valid(), md, -jnp.inf)
+        _, order = jax.lax.top_k(far, min(c.shape[1], plan.n_max))
+        rank = jnp.cumsum(counts == 0, axis=1) - 1      # among the empties
+        n = jnp.minimum(jnp.asarray(plan.lengths, jnp.int32),
+                        order.shape[1])[:, None]
+        donors = jnp.take_along_axis(order, jnp.clip(rank, 0, n - 1), axis=1)
+        rows = jnp.asarray(plan.offsets, jnp.int32)[:, None] + donors
+        return jnp.where((counts == 0)[..., None],
+                         plan.xp[rows][..., :plan.f].astype(c.dtype), c)
+
+    return jax.lax.cond(jnp.any(counts == 0), reseed, lambda c: c,
+                        centroids)
+
+
 class BatchedKMeans:
-    """K-means over B stacked independent problems, one launch per step.
+    """K-means over B independent problems, one launch per step.
 
     Fits ``x`` of shape ``(B, N, F)`` — B problems, each with N samples of
-    F features — against per-problem centroid stacks ``(B, K, F)``. The
-    paper's template framework (§III-B) adapts one kernel to many shapes;
-    this estimator adapts one *launch* to many problems: the batched
-    one-pass Lloyd kernel threads the problem axis through the outermost
-    grid dimension, so B small problems cost one dispatch instead of B
-    (the regime where per-problem launches waste the MXU).
+    F features — or, with ``lengths``, B problems of different row counts
+    packed back to back as ``(sum N, F)`` rows, against per-problem
+    centroid stacks ``(B, K, F)``. The paper's template framework
+    (§III-B) adapts one kernel to many shapes; this estimator adapts one
+    *launch* to many problems: the batched one-pass Lloyd kernel runs over
+    every problem's row tiles, a tile map naming each tile's problem, so
+    B small problems cost one dispatch instead of B (the regime where
+    per-problem launches waste the MXU).
 
     Parameters
     ----------
@@ -146,16 +166,16 @@ class BatchedKMeans:
         :class:`repro.api.KMeans`).
     random_state : int, default=0
         Base seed. Problem ``b`` uses key ``PRNGKey(random_state + b)``
-        for init and empty-cluster reseeding, so a batched fit is
+        for init (reseeding draws nothing), so a batched fit is
         bit-identical to B single-problem fits seeded ``random_state + b``.
 
     Attributes
     ----------
     cluster_centers_ : jax.Array, shape (B, K, F), float32
         Fitted per-problem centroids.
-    labels_ : jax.Array, shape (B, N), int32
+    labels_ : jax.Array, shape (B, N) or (sum N,), int32
         Assignment of each sample at the final executed iteration of its
-        problem.
+        problem; packed like ``x`` after a ragged fit.
     inertia_ : numpy.ndarray, shape (B,), float
         Per-problem sum of squared distances at that iteration.
     n_iter_ : numpy.ndarray, shape (B,), int
@@ -163,6 +183,11 @@ class BatchedKMeans:
     detected_errors_ : int
         Detected-SDC total (always 0 for the unprotected batched backends;
         the slot keeps the surface uniform with :class:`repro.api.KMeans`).
+    rows_valid_, rows_padded_, row_tiles_ : int
+        What each kernel launch of the last fit processed: the problems'
+        rows, the zero rows that pad them to whole row tiles (and a
+        stacked block to the tile grid), and the row tiles. Counters, read
+        only; the XLA analogue pads nothing and has no tiles (0, 0).
 
     See Also
     --------
@@ -176,6 +201,19 @@ class BatchedKMeans:
     kernel has no FT template, so there is no ``fault`` parameter here.
     Protect giant single problems with ``KMeans(fault=...)``; batched
     traffic is (for now) unprotected by construction.
+
+    Every fit pads each problem to whole row tiles of the kernel; padded
+    rows never enter labels, sums, counts, inertia or reseeding. Problems
+    of one length launch ``lloyd_step_batched``, problems of different
+    lengths the same kernel as ``lloyd_step_ragged``, so a ragged fit of
+    equal lengths is the stacked fit, bit for bit. ``predict`` and
+    ``score`` take stacked data only, and ``DistributedKMeans`` shards
+    stacked problems only.
+
+    The lengths are static: the pack, the chunk's scan and the label
+    packing compile once per distinct ``lengths`` tuple, so a caller
+    whose batches bring new lengths every time pays those compiles in
+    every fit.
 
     Examples
     --------
@@ -229,6 +267,9 @@ class BatchedKMeans:
         self.inertia_: Optional[np.ndarray] = None
         self.n_iter_: Optional[np.ndarray] = None
         self.detected_errors_: int = 0
+        self.rows_valid_: int = 0
+        self.rows_padded_: int = 0
+        self.row_tiles_: int = 0
 
     # ------------------------------------------------------------------
     # internals
@@ -282,9 +323,13 @@ class BatchedKMeans:
                                 dtype=self.compute_dtype)
 
     def init_centroids(self, x: jax.Array,
-                       keys: Optional[jax.Array] = None) -> jax.Array:
+                       keys: Optional[jax.Array] = None, *,
+                       lengths=None) -> jax.Array:
         """Per-problem seeding: (B, K, F) from the stacked (B, N, F) data,
-        every problem drawing from its own key."""
+        or from ragged rows ``x`` (sum N, F) with ``lengths``, every
+        problem drawing from its own key and its own rows."""
+        if lengths is not None:
+            return self._init_ragged(x, ops.ragged_lengths(x, lengths), keys)
         from repro.core.kmeans import init_kmeanspp, init_random
         if keys is None:
             keys = self._problem_keys(x.shape[0])
@@ -294,6 +339,23 @@ class BatchedKMeans:
                                        autotune=self.autotune)
         fn = init_kmeanspp if self.init == "kmeans++" else init_random
         return jax.vmap(fn, in_axes=(0, 0, None))(keys, x, self.n_clusters)
+
+    def _init_ragged(self, x: jax.Array, lengths: tuple[int, ...],
+                     keys: Optional[jax.Array]) -> jax.Array:
+        """Seed ragged problems a length at a time: the problems of one
+        length are gathered into a stack (only those rows) and seeded as
+        a stacked batch is, so equal lengths seed as the stacked fit."""
+        if keys is None:
+            keys = self._problem_keys(len(lengths))
+        starts = np.cumsum((0,) + lengths[:-1])
+        lens = np.asarray(lengths)  # analysis: allow=host-sync (a tuple)
+        out = jnp.zeros((len(lengths), self.n_clusters, x.shape[1]),
+                        jnp.float32)
+        for n in sorted(set(lengths)):
+            idx = np.flatnonzero(lens == n)
+            rows = starts[idx][:, None] + np.arange(n)[None, :]
+            out = out.at[idx].set(self.init_centroids(x[rows], keys[idx]))
+        return out
 
     def _chunk_fn(self, params, n_steps: int):
         """jit'd device-resident chunk of up to ``n_steps`` batched Lloyd
@@ -311,15 +373,21 @@ class BatchedKMeans:
     # estimator API
     # ------------------------------------------------------------------
 
-    def fit(self, x: jax.Array, *,
+    def fit(self, x: jax.Array, *, lengths=None,
             centroids: Optional[jax.Array] = None) -> "BatchedKMeans":
         """Run batched Lloyd iterations to per-problem convergence.
 
         Parameters
         ----------
-        x : jax.Array, shape (B, N, F)
-            B stacked problems. Stacking implies every problem shares
-            (N, K, F); pad ragged problems to a common N before stacking.
+        x : jax.Array, shape (B, N, F) or (sum N, F)
+            B stacked problems sharing (N, K, F); or, with ``lengths``,
+            the rows of B problems of any row counts packed back to back
+            (problem 0's rows, then problem 1's, ...).
+        lengths : array of int, shape (B,), optional
+            Each packed problem's row count; ``sum(lengths)`` must equal
+            the rows of ``x``. Every problem is clustered on its own rows
+            only: its init draws from them, reseeding takes donors from
+            them, and inertia and counts cover them alone.
         centroids : jax.Array, shape (B, K, F), optional
             Warm-start stack; default is per-problem ``init`` seeding.
 
@@ -327,36 +395,58 @@ class BatchedKMeans:
         -------
         self : BatchedKMeans
             With ``cluster_centers_``, ``labels_``, ``inertia_``,
-            ``n_iter_`` populated (all carrying the leading B axis).
+            ``n_iter_`` populated (all carrying the leading B axis, but
+            ``labels_`` of a ragged fit, which is packed like ``x``).
         """
         x = jnp.asarray(x)
-        if x.ndim != 3:
-            raise ValueError(f"BatchedKMeans.fit wants stacked (B, N, F) "
-                             f"problems, got shape {x.shape}; use "
-                             f"repro.api.KMeans for one problem")
-        bsz, n, f = x.shape
-        keys = self._problem_keys(bsz)
+        if lengths is None:
+            if x.ndim != 3:
+                raise ValueError(
+                    f"BatchedKMeans.fit wants stacked (B, N, F) problems, "
+                    f"got shape {x.shape}; pass lengths= for ragged "
+                    f"problems packed as (sum N, F) rows, or use "
+                    f"repro.api.KMeans for one problem")
+            bsz = x.shape[0]
+        else:
+            lengths = ops.ragged_lengths(x, lengths)
+            bsz = len(lengths)
         if centroids is None:
-            split = jax.vmap(jax.random.split)(keys)       # (B, 2, 2)
-            keys, subs = split[:, 0], split[:, 1]
-            centroids = self.init_centroids(x, subs)
+            split = jax.vmap(jax.random.split)(self._problem_keys(bsz))
+            centroids = self.init_centroids(x, split[:, 1], lengths=lengths)
         centroids = jnp.asarray(centroids, jnp.float32)
         with obs.span("fit"):
-            return self._fit_chunks(x, centroids, keys)
+            return self._fit_chunks(x, centroids, lengths)
+
+    def _plan(self, x: jax.Array, lengths: Optional[tuple[int, ...]]):
+        """The per-fit :class:`~repro.kernels.ops.BatchPlan` and its
+        tiles, once per fit, under the span ``kmeans.plan`` for a stack
+        and ``kmeans.pack`` for ragged rows. Ragged problems of different
+        lengths take :func:`~repro.kernels.ops.ragged_params` tiles unless
+        ``params`` were given."""
+        bsz, n, f = x.shape if lengths is None else (
+            len(lengths), max(lengths), x.shape[1])
+        params = self._resolve_params(bsz, n, f)
+        if (params is not None and self.params is None
+                and len(set(lengths or (n,))) > 1):
+            params = ops.ragged_params(params, n, self.n_clusters, f,
+                                       self.compute_dtype)
+        with obs.span("plan" if lengths is None else "pack"):
+            # every problem padded to whole row tiles, once per fit
+            plan = ops.plan_data_batched(self._cast(x), params, lengths)
+        self.rows_valid_, self.rows_padded_ = (plan.rows_valid,
+                                               plan.rows_padded)
+        self.row_tiles_ = int(plan.tile_prob.shape[0])
+        return plan, params
 
     def _fit_chunks(self, x: jax.Array, centroids: jax.Array,
-                    keys: jax.Array) -> "BatchedKMeans":
+                    lengths=None) -> "BatchedKMeans":
         """The device-resident Lloyd loop of :meth:`fit`, from seeded
         centroids: the per-fit plan, then one ``sync_every`` chunk and one
         host read per round."""
-        bsz, n, f = x.shape
-        params = self._resolve_params(bsz, n, f)
-        with obs.span("plan"):
-            # per-fit batch plan: pad + row-norm the whole (B, N, F) block once
-            plan = ops.plan_data_batched(self._cast(x), params) \
-                if self._backend.takes_params else self._cast(x)
+        plan, params = self._plan(x, lengths)
+        bsz = plan.b
 
-        am = jnp.zeros((bsz, n), jnp.int32)
+        am = jnp.zeros((bsz, plan.n_max), jnp.int32)
         inertia = jnp.full((bsz,), jnp.inf, jnp.float32)
         done = jnp.zeros((bsz,), jnp.bool_)
         det = jnp.zeros((), jnp.int32)
@@ -367,8 +457,7 @@ class BatchedKMeans:
             with obs.span("dispatch"):
                 chunk = self._chunk_fn(params, n_steps)
                 (centroids, am, inertia, done, det), live_hist = chunk(
-                    plan, centroids, am, inertia, done, det, keys,
-                    jnp.int32(it0))
+                    plan, centroids, am, inertia, done, det)
             with obs.span("sync"):
                 done_h, live_h = _host_read((done, live_hist))
             iters += live_h.sum(axis=0).astype(np.int64)
@@ -377,7 +466,7 @@ class BatchedKMeans:
                 break
 
         self.cluster_centers_ = centroids
-        self.labels_ = am
+        self.labels_ = am if lengths is None else plan.packed(am)
         with obs.span("sync"):
             inertia_h, det_h = _host_read((inertia, det))
         self.inertia_ = np.asarray(inertia_h, np.float64)
@@ -404,31 +493,38 @@ class BatchedKMeans:
             self._step_cache[key] = fn
         return self._step_cache[key](x, self.cluster_centers_)
 
+    def _check_stack(self, x: jax.Array) -> jax.Array:
+        self._check_fitted()
+        x = jnp.asarray(x)
+        if x.ndim == 2:
+            raise ValueError(
+                f"predict and score take stacked (B, N, F) problems; "
+                f"ragged rows packed as {x.shape} are not supported")
+        if x.ndim != 3 or x.shape[0] != self.cluster_centers_.shape[0]:
+            raise ValueError(
+                f"predict wants (B, N, F) with B={self.cluster_centers_.shape[0]} "
+                f"fitted problems, got shape {x.shape}")
+        return x
+
     def predict(self, x: jax.Array) -> jax.Array:
         """Per-problem nearest-centroid labels for new stacked data.
 
         Parameters
         ----------
         x : jax.Array, shape (B, N', F)
-            New samples; B must match the fitted problem count.
+            New samples; B must match the fitted problem count. Ragged
+            (packed) rows are not supported.
 
         Returns
         -------
         labels : jax.Array, shape (B, N'), int32
         """
-        self._check_fitted()
-        x = jnp.asarray(x)
-        if x.ndim != 3 or x.shape[0] != self.cluster_centers_.shape[0]:
-            raise ValueError(
-                f"predict wants (B, N, F) with B={self.cluster_centers_.shape[0]} "
-                f"fitted problems, got shape {x.shape}")
-        return self._assign(x)[0]
+        return self._assign(self._check_stack(x))[0]
 
     def score(self, x: jax.Array) -> np.ndarray:
         """Per-problem negative inertia on ``x`` (sklearn sign convention:
         higher is better). Returns shape (B,)."""
-        self._check_fitted()
-        _, md = self._assign(jnp.asarray(x))
+        _, md = self._assign(self._check_stack(x))
         return -np.asarray(_host_read(jnp.sum(md, axis=1)), np.float64)
 
     # ------------------------------------------------------------------

@@ -37,10 +37,11 @@ Each implementation maps (x (M, F), c (K, F)) ->
   lloyd_ft_xla XLA analogue of the one-pass FT backend (non-TPU fast path;
                detection + correction at the XLA level, no in-kernel
                injection surface).
-  lloyd_batched     batched one-pass Lloyd: B independent problems stacked
-               as (B, N, F) / (B, K, F) run through one kernel launch, the
-               problem axis outermost in the grid (``supports_batch=True``;
-               every output gains a leading B axis).
+  lloyd_batched     batched one-pass Lloyd: B independent problems, a
+               (B, N, F) stack or a BatchPlan of packed rows, against
+               (B, K, F) centroids in one kernel launch over the problems'
+               row tiles (``supports_batch=True``; every output gains a
+               leading B axis).
   lloyd_batched_xla XLA analogue of the batched kernel (batched
                contractions; non-TPU fast path).
   lloyd_pruned one-pass Lloyd with tile-granular triangle-inequality
@@ -413,20 +414,29 @@ def assign_lloyd_pruned_xla(x: jax.Array, c: jax.Array, *, bounds=None):
 
 def assign_lloyd_batched(x, c: jax.Array, params=None):
     # Batched one-pass Lloyd: B independent problems through one kernel
-    # launch, the problem axis mapped to the outermost grid dimension
-    # (smallk epilogue per problem — batched problems have small K by
-    # construction). Extended 5-tuple contract with a leading B axis.
+    # launch over their row tiles (smallk epilogue per tile — batched
+    # problems have small K by construction). Extended 5-tuple contract
+    # with a leading B axis; a BatchPlan's problems may differ in row
+    # count, (assign, min_dist) then (B, n_max), zero past each problem's
+    # rows.
     am, md, sums, counts = ops.fused_lloyd_batched(x, c, params)
     return am, md, _zero(), sums, counts
 
 
 @jax.jit
-def assign_lloyd_batched_xla(x: jax.Array, c: jax.Array):
+def assign_lloyd_batched_xla(x, c: jax.Array):
     # XLA analogue of the batched one-pass kernel (non-TPU fast path): the
     # per-problem distance GEMM, argmin and one-hot update run as batched
     # contractions over the stacked (B, N, F) / (B, K, F) operands — XLA
     # loops the problem axis outside each GEMM, so per-problem numerics
     # match the B=1 call bit-for-bit while one dispatch covers all B.
+    # A BatchPlan is spread to (B, n_max, F); ragged problems get zero
+    # rows past their ends, masked out of the update: the CPU path only,
+    # where the stack is small.
+    valid = None
+    if isinstance(x, ops.BatchPlan):
+        valid = None if x.stacked else x.valid()
+        x = x.x
     k = c.shape[1]
     xf = x.astype(jnp.float32)
     cf = c.astype(jnp.float32)
@@ -438,6 +448,9 @@ def assign_lloyd_batched_xla(x: jax.Array, c: jax.Array):
     am = jnp.argmin(d, axis=2).astype(jnp.int32)                 # (B, N)
     md = jnp.min(d, axis=2)
     onehot = jax.nn.one_hot(am, k, dtype=x.dtype)                # (B, N, K)
+    if valid is not None:
+        onehot = onehot * valid[:, :, None].astype(x.dtype)
+        am, md = jnp.where(valid, am, 0), jnp.where(valid, md, 0.0)
     sums = jax.lax.dot_general(
         onehot, x, (((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)                      # (B, K, F)
@@ -510,8 +523,8 @@ register_backend(AssignmentBackend(
     "lloyd_batched", assign_lloyd_batched, takes_params=True,
     fuses_update=True, supports_batch=True,
     doc="batched one-pass Lloyd Pallas kernel: B independent problems per "
-        "launch, problem axis outermost in the grid (smallk epilogue per "
-        "problem)"))
+        "launch, a grid over their row tiles and a tile map naming each "
+        "tile's problem (smallk epilogue per tile)"))
 register_backend(AssignmentBackend(
     "lloyd_batched_xla", assign_lloyd_batched_xla, fuses_update=True,
     supports_batch=True,

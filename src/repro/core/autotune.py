@@ -65,7 +65,7 @@ VMEM_BUDGET = _hw.VMEM_BUDGET     # bytes usable per core
 # dual-checksum scratch and the expected-checksum output blocks of the
 # protected update epilogue; its model charges the checksum FLOPs/traffic.
 # "batched" is the many-problem one-pass kernel: B problems per launch,
-# problem axis outermost in the grid, padded K always a single centroid
+# a grid over their row tiles, padded K always a single centroid
 # tile (so block_k is not a search axis and winners are additionally keyed
 # by the B bucket — a B=4 launch and a B=1024 launch amortize dispatch and
 # pipeline ramp-up very differently at the same per-problem shape).

@@ -266,19 +266,18 @@ class DistributedKMeans:
                                    n_steps)
         daxes = self._daxes
 
-        def local_chunk(x, c, am, inertia, done, keys, it0):
-            plan = ops.plan_data_batched(est._cast(x), params) \
-                if backend.takes_params else est._cast(x)
+        def local_chunk(x, c, am, inertia, done):
+            plan = ops.plan_data_batched(est._cast(x), params)
             det0 = jnp.zeros((), jnp.int32)
             (c, am, inertia, done, det), live = chunk(
-                plan, c, am, inertia, done, det0, keys, it0)
+                plan, c, am, inertia, done, det0)
             return c, am, inertia, done, jax.lax.psum(det, daxes), live
 
         row = _axes_spec(self._paxes)
         return jax.jit(jax.shard_map(
             local_chunk, mesh=self.mesh,
             in_specs=(P(row, None, None), P(row, None, None), P(row, None),
-                      P(row), P(row), P(row, None), P()),
+                      P(row), P(row)),
             out_specs=(P(row, None, None), P(row, None), P(row), P(row),
                        P(), P(None, row)),
             check_vma=False))
@@ -289,11 +288,10 @@ class DistributedKMeans:
                       on_iteration: Optional[Callable] = None):
         est = self.est
         bsz, n, f = xs.shape
-        keys = est._problem_keys(bsz)     # problem b seeds from its global
-        centroids = jnp.asarray(centroids, jnp.float32)     # index, so the
-        am = jnp.zeros((bsz, n), jnp.int32)   # sharded fit matches the
-        inertia = jnp.full((bsz,), jnp.inf, jnp.float32)   # single-device
-        done = jnp.zeros((bsz,), jnp.bool_)                # one exactly
+        centroids = jnp.asarray(centroids, jnp.float32)
+        am = jnp.zeros((bsz, n), jnp.int32)
+        inertia = jnp.full((bsz,), jnp.inf, jnp.float32)
+        done = jnp.zeros((bsz,), jnp.bool_)
         iters = np.zeros((bsz,), np.int64)
         total_det = 0
         it0 = start_iteration
@@ -307,7 +305,7 @@ class DistributedKMeans:
                 self._steps[key] = self._build_step_problems(
                     bsz // self._pp, n, f, n_steps)
             centroids, am, inertia, done, det, live = self._steps[key](
-                xs, centroids, am, inertia, done, keys, jnp.int32(it0))
+                xs, centroids, am, inertia, done)
             done_h, live_h, det_h = _host_read((done, live, det))
             iters += live_h.sum(axis=0).astype(np.int64)
             total_det += int(det_h)

@@ -37,14 +37,18 @@ at 2-byte dtypes) while every accumulator and output stays f32.
 
 Batched many-problem variant (:func:`lloyd_step_batched`): production
 traffic is rarely one big clustering problem — it is thousands of
-independent small ones (per-user embeddings, per-shard codebooks) whose
-individual kernel launches waste the MXU. The batched template threads a
-leading problem dimension ``B`` through the grid as its *outermost*
-dimension ``(B, M/bm, F/bf)``: each problem carries its own centroid tile
-and per-problem accumulator, and — because batched problems have small K by
-construction (padded K is a single centroid tile) — every grid step reuses
-the ``smallk`` epilogue, min/argmin written directly and the one-hot update
-emitted in the same step. One launch amortizes B dispatches.
+independent small ones (per-user embeddings, per-shard codebooks, the keys
+of each attention head of a prefill batch) whose individual kernel
+launches waste the MXU. The B problems' rows are packed back to back, each
+padded to whole row tiles, and the grid runs over row tiles ``(T, F/bf)``.
+A tile map in SMEM (scalar prefetch) names each tile's problem, which
+selects its centroid block, and its valid rows, which mask the update
+epilogue. Batched problems have small K by construction (padded K is a
+single centroid tile), so every grid step is the ``smallk`` epilogue,
+min/argmin written directly and the one-hot update emitted in the same
+step. One launch amortizes B dispatches. Problems of one row count (a
+stack) launch as ``lloyd_step_batched``, problems of different row counts
+as :func:`lloyd_step_ragged`: one kernel under two names.
 """
 from __future__ import annotations
 
@@ -167,20 +171,29 @@ def _emit_update(meta_ref, argmin_ref, sums_ref, counts_ref, xbuf_ref,
     x for normal f32) and the product takes one bf16 MXU pass per slice.
     ``Precision.HIGHEST`` would take six, three of them against the
     one-hot's bf16 mid and lo parts, which are zero."""
-    kp = counts_ref.shape[-1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0) + m_idx * bm
     valid = (rows < meta_ref[0]).astype(jnp.float32)           # (bm, 1)
+    sums, counts = _onehot_update(valid, argmin_ref[...],
+                                  counts_ref.shape[-1], xbuf_ref[...])
+    counts_ref[0] = counts                                     # (1, kp)
+    sums_ref[...] = sums[None]                                 # (1, kp, fp)
+
+
+def _onehot_update(valid: jax.Array, am: jax.Array, kp: int, x: jax.Array
+                   ) -> tuple[jax.Array, jax.Array]:
+    """The one-hot product of :func:`_emit_update` on values: ``valid``
+    (bm, 1) f32 0/1 row mask, ``am`` (bm, 1) final argmin, ``x`` (bm, fp)
+    stashed rows. Returns (sums (kp, fp), counts (1, kp)), both f32."""
     clusters = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
-    onehot = (argmin_ref[...] == clusters).astype(jnp.float32) * valid
-    counts_ref[0] = jnp.sum(onehot, axis=0, keepdims=True)     # (1, kp)
-    x = xbuf_ref[...]
+    onehot = (am == clusters).astype(jnp.float32) * valid
+    counts = jnp.sum(onehot, axis=0, keepdims=True)            # (1, kp)
     if x.dtype != jnp.float32:
         sums = mxu_dot(onehot.astype(x.dtype), x, (0, 0))
     else:
         onehot = onehot.astype(jnp.bfloat16)
         hi, mid, lo = (mxu_dot(onehot, s, (0, 0)) for s in _bf16_slices(x))
         sums = (hi + mid) + lo
-    sums_ref[...] = sums[None]                                 # (1, kp, fp)
+    return sums, counts
 
 
 def _bf16_slices(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -231,121 +244,177 @@ def _kernel_smallk(meta_ref, x_ref, c_ref, cn_ref,
                      m_idx, bm)
 
 
-def _kernel_batched(meta_ref, x_ref, c_ref, cn_ref,
-                    mind_ref, argmin_ref, sums_ref, counts_ref,
+def _kernel_batched(tile_prob_ref, tile_rows_ref, tile_slot_ref, x_ref,
+                    c_ref, cn_ref, mind_ref, argmin_ref, sums_ref, counts_ref,
                     acc_ref, xbuf_ref, sem_ref):
-    """One problem's (bm, kp) tile of the batched grid (B, M/bm, F/bf).
+    """One row tile of the batched grid (T, F/bf): the ``smallk``
+    single-sweep epilogue on a tile of packed rows, its problem read from
+    the tile map.
 
-    The problem index is the outermost grid dimension: every block spec
-    selects problem ``b``'s slab, so the kernel body is the ``smallk``
-    single-sweep epilogue on that problem's own centroid tile and
-    accumulator — blocks just carry a leading length-1 problem axis.
+    Every problem's rows are padded to a whole number of row tiles, so a
+    tile lies in one problem; the centroid and norm blocks follow
+    ``tile_prob[t]``, and rows at or past ``tile_rows[t]`` (the problem's
+    tail) are masked out of the sums and counts. The tile's partials go to
+    row ``tile_slot[t]``, so that each run of problems with one tile count
+    leaves them tile-major, as its tree sum reads them.
 
-    meta_ref  : (1,)              SMEM — [true_n] (shared: stacked problems
-                                  are padded together)
-    x_ref     : (1, bm, bf)       problem b's sample tile
-    c_ref     : (1, kp, bf)       problem b's (single) centroid tile
-    cn_ref    : (1, 1, kp)        problem b's centroid squared norms
-    mind_ref  : (1, bm, 1)        min distance (output, single visit)
-    argmin_ref: (1, bm, 1)        argmin       (output, single visit)
-    sums_ref  : (1, 1, kp, fp)    per-row-tile partial cluster sums
-    counts_ref: (1, 1, 1, kp)     per-row-tile partial cluster counts
-    acc_ref   : (bm, kp)          per-problem VMEM scratch accumulator
-    xbuf_ref  : (bm, fp)          VMEM stash of the row tile's chunks
-    sem_ref   : (2,)              DMA semaphores for the async stash
+    tile_prob_ref: (T,)         SMEM (scalar prefetch) — problem of tile t
+    tile_rows_ref: (T,)         SMEM (scalar prefetch) — valid rows of t
+    tile_slot_ref: (T,)         SMEM (scalar prefetch) — partials row of t
+    x_ref        : (bm, bf)     the tile's packed rows
+    c_ref        : (1, kp, bf)  its problem's (single) centroid tile
+    cn_ref       : (1, 1, kp)   its problem's centroid squared norms
+    mind_ref     : (1, 1, bm)   min distance, one lane-dense row per tile
+    argmin_ref   : (1, 1, bm)   argmin, the same layout
+    sums_ref     : (1, kp, fp)  the tile's partial cluster sums (its slot)
+    counts_ref   : (1, 1, kp)   the tile's partial cluster counts (ditto)
+    acc_ref      : (bm, kp)     VMEM scratch accumulator
+    xbuf_ref     : (bm, fp)     VMEM stash of the row tile's chunks
+    sem_ref      : (2,)         DMA semaphores for the async stash
+
+    The min and argmin leave as rows, not (bm, 1) columns: a column block
+    is stored one row per 128-lane line in HBM, 128 times its size.
     """
-    m_idx = pl.program_id(1)
-    f_idx = pl.program_id(2)
-    nf = pl.num_programs(2)
+    t_idx = pl.program_id(0)
+    f_idx = pl.program_id(1)
+    nf = pl.num_programs(1)
     bm = acc_ref.shape[0]
-    bf = x_ref.shape[2]
+    bf = x_ref.shape[1]
 
     @pl.when(f_idx == 0)
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Single centroid-tile sweep per problem: every feature step is a
-    # first visit, so stash unconditionally (smallk rule) — async, so the
-    # copy overlaps this step's MXU product.
-    _stash_dma_start(x_ref.at[0], xbuf_ref, sem_ref, f_idx, bf)
+    # Single centroid-tile sweep: every feature step is a first visit, so
+    # stash unconditionally (smallk rule) — async, so the copy overlaps
+    # this step's MXU product.
+    _stash_dma_start(x_ref, xbuf_ref, sem_ref, f_idx, bf)
 
-    acc_ref[...] += mxu_dot(x_ref[0], c_ref[0], (1, 1))
+    acc_ref[...] += mxu_dot(x_ref[...], c_ref[0], (1, 1))
 
     @pl.when(f_idx == nf - 1)
     def _epilogue():
         local_min, local_arg = tile_min_argmin(acc_ref[...], cn_ref[0], 0)
-        mind_ref[0] = local_min      # single visit: direct write
-        argmin_ref[0] = local_arg
-        _stash_dma_wait_last(x_ref.at[0], xbuf_ref, sem_ref, nf, bf)
-        _emit_update(meta_ref, argmin_ref.at[0], sums_ref.at[0],
-                     counts_ref.at[0], xbuf_ref, m_idx, bm)
+        mind_ref[0] = jnp.transpose(local_min)
+        argmin_ref[0] = jnp.transpose(local_arg)
+        _stash_dma_wait_last(x_ref, xbuf_ref, sem_ref, nf, bf)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+        valid = (rows < tile_rows_ref[t_idx]).astype(jnp.float32)
+        sums, counts = _onehot_update(valid, local_arg,
+                                      counts_ref.shape[-1], xbuf_ref[...])
+        counts_ref[0] = counts
+        sums_ref[...] = sums[None]
+
+
+def _batched_call(tile_prob, tile_rows, tile_slot, x, c, cn, block_m,
+                  block_f, interpret):
+    """The pallas_call of :func:`_kernel_batched`, shared by its two
+    entries (not jitted itself: each entry's trace keeps its own name)."""
+    m, f = x.shape
+    k = c.shape[1]
+    assert m % block_m == 0 and f % block_f == 0 and k % 128 == 0, (
+        f"unpadded shapes {(m, k, f)} vs blocks ({block_m}, {k}, {block_f})")
+    num_t = m // block_m
+    assert all(a.shape == (num_t,) for a in (tile_prob, tile_rows,
+                                            tile_slot)), (
+        f"tile map of {tile_prob.shape} for {num_t} row tiles")
+
+    out_shape = [
+        jax.ShapeDtypeStruct((num_t, 1, block_m), jnp.float32),
+        jax.ShapeDtypeStruct((num_t, 1, block_m), jnp.int32),
+        jax.ShapeDtypeStruct((num_t, k, f), jnp.float32),
+        # unit axis before K: see ``lloyd_step``
+        jax.ShapeDtypeStruct((num_t, 1, k), jnp.float32),
+    ]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(num_t, f // block_f),
+        in_specs=[
+            pl.BlockSpec((block_m, block_f), lambda t, j, *_: (t, j)),
+            pl.BlockSpec((1, k, block_f),
+                         lambda t, j, tp, *_: (tp[t], 0, j)),
+            pl.BlockSpec((1, 1, k), lambda t, j, tp, *_: (tp[t], 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, block_m), lambda t, j, *_: (t, 0, 0)),
+            pl.BlockSpec((1, 1, block_m), lambda t, j, *_: (t, 0, 0)),
+            pl.BlockSpec((1, k, f), lambda t, j, tp, tr, ts: (ts[t], 0, 0)),
+            pl.BlockSpec((1, 1, k), lambda t, j, tp, tr, ts: (ts[t], 0, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_m, k), jnp.float32),
+            pltpu.VMEM((block_m, f), x.dtype),   # stash in the input dtype
+            pltpu.SemaphoreType.DMA((STASH_SLOTS,)),
+        ],
+    )
+    kernel = pl.pallas_call(
+        _kernel_batched,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=compiler_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )
+    mind, am, sums, counts = kernel(tile_prob, tile_rows, tile_slot, x, c,
+                                    cn)
+    return mind[:, 0], am[:, 0], sums, counts[:, 0]
 
 
 @functools.partial(
     jax.jit, static_argnames=("block_m", "block_f", "interpret"))
 def lloyd_step_batched(
+    tile_prob: jax.Array,
+    tile_rows: jax.Array,
+    tile_slot: jax.Array,
     x: jax.Array,
     c: jax.Array,
     cn: jax.Array,
-    meta: jax.Array,
     *,
     block_m: int = 256,
     block_f: int = 512,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Raw batched one-pass kernel entry: B independent problems, one launch.
+    """Raw batched one-pass kernel entry: B independent problems, one
+    launch, their rows packed back to back in whole row tiles.
 
-    x (B, N, F) stacked samples, c (B, K, F) per-problem centroids (f32/
-    bf16/fp16), cn (B, 1, K) f32 per-problem centroid sq-norms with +inf in
-    padded slots, meta (1,) int32 = [true_n]. Shapes must be pre-padded to
-    the block grid; padded K must be a single centroid tile (the smallk
-    condition — batched problems have small K by construction), so K itself
-    is the centroid tile and there is no ``block_k`` knob. Returns
-    (min_d (B, N, 1), argmin (B, N, 1), sums (B, N/bm, K, F),
-    counts (B, N/bm, K)); sum the partial blocks over axis 1 for each
-    problem's (K, F) / (K,) totals.
+    x (T*bm, F) packed rows, each problem padded to whole row tiles;
+    tile_prob (T,) int32 the problem of each row tile, tile_rows (T,)
+    int32 its valid rows, tile_slot (T,) int32 the row of the partials
+    it writes; c (B, K, F) per-problem centroids (f32/bf16/
+    fp16) and cn (B, 1, K) f32 their squared norms (+inf in padded
+    slots). Shapes must be pre-padded to the block grid; padded K must be
+    a single centroid tile (the smallk condition — batched problems have
+    small K by construction), so K itself is the centroid tile and there
+    is no ``block_k`` knob. Returns (min_d (T, bm), argmin (T, bm),
+    sums (T, K, F), counts (T, K)), the last two per-tile partials in
+    ``tile_slot`` order, to be summed over each problem's tiles.
+
+    This entry launches problems of one row count (a (B, N, F) stack);
+    :func:`lloyd_step_ragged` is the same kernel under its own name for
+    problems of different row counts.
     """
-    bsz, m, f = x.shape
-    k = c.shape[1]
-    assert m % block_m == 0 and f % block_f == 0 and k % 128 == 0, (
-        f"unpadded shapes {(bsz, m, k, f)} vs blocks "
-        f"({block_m}, {k}, {block_f})")
-    num_m = m // block_m
+    return _batched_call(tile_prob, tile_rows, tile_slot, x, c, cn, block_m,
+                         block_f, interpret)
 
-    out_shape = [
-        jax.ShapeDtypeStruct((bsz, m, 1), jnp.float32),
-        jax.ShapeDtypeStruct((bsz, m, 1), jnp.int32),
-        jax.ShapeDtypeStruct((bsz, num_m, k, f), jnp.float32),
-        # unit axis before K: see ``lloyd_step``
-        jax.ShapeDtypeStruct((bsz, num_m, 1, k), jnp.float32),
-    ]
-    scratch = [
-        pltpu.VMEM((block_m, k), jnp.float32),
-        pltpu.VMEM((block_m, f), x.dtype),   # stash in the input dtype
-        pltpu.SemaphoreType.DMA((STASH_SLOTS,)),
-    ]
-    kernel = pl.pallas_call(
-        _kernel_batched,
-        grid=(bsz, num_m, f // block_f),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_m, block_f), lambda b, i, t: (b, i, t)),
-            pl.BlockSpec((1, k, block_f), lambda b, i, t: (b, 0, t)),
-            pl.BlockSpec((1, 1, k), lambda b, i, t: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_m, 1), lambda b, i, t: (b, i, 0)),
-            pl.BlockSpec((1, block_m, 1), lambda b, i, t: (b, i, 0)),
-            pl.BlockSpec((1, 1, k, f), lambda b, i, t: (b, i, 0, 0)),
-            pl.BlockSpec((1, 1, 1, k), lambda b, i, t: (b, i, 0, 0)),
-        ],
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret,
-    )
-    mind, am, sums, counts = kernel(meta, x, c, cn)
-    return mind, am, sums, counts[:, :, 0]
+
+@functools.partial(
+    jax.jit, static_argnames=("block_m", "block_f", "interpret"))
+def lloyd_step_ragged(
+    tile_prob: jax.Array,
+    tile_rows: jax.Array,
+    tile_slot: jax.Array,
+    x: jax.Array,
+    c: jax.Array,
+    cn: jax.Array,
+    *,
+    block_m: int = 256,
+    block_f: int = 512,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """:func:`lloyd_step_batched` for problems of different row counts:
+    the same kernel and arguments, launched under this name so a trace
+    tells ragged launches from stacked ones."""
+    return _batched_call(tile_prob, tile_rows, tile_slot, x, c, cn, block_m,
+                         block_f, interpret)
 
 
 @functools.partial(

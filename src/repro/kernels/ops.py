@@ -9,10 +9,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 from typing import Any, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.extend import core as jex_core
 
 from repro import obs
@@ -97,10 +99,10 @@ def lloyd_ft_vmem_bytes(params: KernelParams, k: int, f: int,
 
 def lloyd_batched_vmem_bytes(params: KernelParams, k: int, f: int,
                              dtype: Any = jnp.float32) -> int:
-    """Working-set estimate for the batched one-pass kernel: one problem's
-    tiles are resident at a time (the problem axis is the outermost grid
-    dimension), so the footprint is the smallk one-pass working set with
-    padded K as the single centroid tile — ``block_k`` is not a knob."""
+    """Working-set estimate for the batched one-pass kernel: one row
+    tile, and its problem's centroid tile, are resident at a time, so the
+    footprint is the smallk one-pass working set with padded K as the
+    single centroid tile — ``block_k`` is not a knob."""
     b = _itemsize(dtype)
     kp = _round_up(k, 128)
     fp = _round_up(f, params.block_f)
@@ -278,50 +280,256 @@ def plan_data_int8(x: jax.Array, params: Optional[KernelParams] = None, *,
 
 @dataclasses.dataclass(frozen=True)
 class BatchPlan:
-    """Per-fit data plan for B stacked problems: the (B, N, F) block padded
-    to the kernel grid and its per-problem row squared norms, computed
+    """Per-fit data plan for B problems: their rows packed back to back
+    and padded to the kernel grid, and their row squared norms, computed
     exactly once and reused across every batched Lloyd iteration.
 
-    x      : (b, n, f)   the original stacked samples
-    xp     : (b, np, fp) X padded to the block grid (== x when params is
-             None)
-    xn     : (b, n)      per-problem row squared norms, f32
-    b, n, f: true (unpadded) dimensions
-    params : the KernelParams the padding was laid out for (None = no
-             Pallas backend in play; xp is x unpadded)
+    Problem ``b``'s rows sit in ``xp`` from ``offsets[b]``, padded with
+    zero rows to a whole number of ``block``-row tiles, so every row tile
+    lies in one problem. The tile map says which (``tile_prob``), how
+    many of its rows are real (``tile_rows``) and where its partial sums
+    go (``tile_slot``: each run of problems with one tile count keeps
+    them tile-major, so its tree sum halves contiguous blocks). A
+    (B, N, F) stack is the case of equal lengths: ``xp`` is then its
+    padded block, reshaped. X is held once: the padded copy, or, without
+    tiles (the XLA analogue's plan), the caller's rows themselves.
+
+    xp        : (mp, fp)  packed rows, each problem padded to whole tiles
+    xn        : (mp,)     row squared norms, f32 (0 in padded rows)
+    tile_prob : (T,)      int32, the problem of each row tile
+    tile_rows : (T,)      int32, the valid rows of each row tile
+    tile_slot : (T,)      int32, the partials row of each row tile
+    lengths   : the B problems' row counts (static)
+    block     : rows per tile, the padding unit (1 = rows unpadded)
+    f         : true feature count
+    params    : the KernelParams the padding was laid out for (None = no
+                Pallas backend in play: ``block`` is 1, features unpadded,
+                no tile map)
     """
 
-    x: jax.Array
     xp: jax.Array
     xn: jax.Array
-    b: int
-    n: int
+    tile_prob: jax.Array
+    tile_rows: jax.Array
+    tile_slot: jax.Array
+    lengths: tuple[int, ...]
+    block: int
     f: int
     params: Optional[KernelParams]
+
+    @property
+    def b(self) -> int:
+        return len(self.lengths)
+
+    @property
+    def n_max(self) -> int:
+        return max(self.lengths)
+
+    @property
+    def stacked(self) -> bool:
+        """Every problem has the same row count."""
+        return len(set(self.lengths)) == 1
+
+    @property
+    def tiles(self) -> tuple[int, ...]:
+        """Row tiles of each problem."""
+        return tuple(-(-n // self.block) for n in self.lengths)
+
+    @property
+    def offsets(self) -> tuple[int, ...]:
+        """First row of each problem in ``xp``."""
+        ends = np.cumsum([t * self.block for t in self.tiles])
+        return tuple(int(e) for e in np.concatenate([[0], ends[:-1]]))
+
+    @property
+    def rows_valid(self) -> int:
+        return int(sum(self.lengths))
+
+    @property
+    def rows_padded(self) -> int:
+        return self.xp.shape[0] - self.rows_valid
+
+    @property
+    def x(self) -> jax.Array:
+        """(B, n_max, F) the problems' rows, 0 past each problem's end: a
+        stack's own (B, N, F) block."""
+        return self.per_problem(self.xp)[..., :self.f]
+
+    def valid(self) -> jax.Array:
+        """(B, n_max) bool: which slots of a per-problem row are rows."""
+        n = jnp.asarray(self.lengths, jnp.int32)
+        return jnp.arange(self.n_max, dtype=jnp.int32)[None, :] < n[:, None]
+
+    def per_problem(self, v: jax.Array) -> jax.Array:
+        """(mp, ...) values in the packed layout -> (B, n_max, ...) per
+        problem, 0 past each problem's rows. A stack is a reshape; ragged
+        problems gather whole row tiles (a problem starts on a tile),
+        never single values: a gather of scalars is slow on the TPU."""
+        num_t, rest = v.shape[0] // self.block, v.shape[1:]
+        span = max(self.tiles)
+        if self.stacked:
+            return v.reshape((self.b, span * self.block) + rest)[
+                :, :self.n_max]
+        first = jnp.asarray(self.offsets, jnp.int32) // self.block
+        idx = jnp.minimum(first[:, None] + jnp.arange(span)[None, :],
+                          num_t - 1)
+        out = v.reshape((num_t, self.block) + rest)[idx].reshape(
+            (self.b, span * self.block) + rest)[:, :self.n_max]
+        valid = self.valid()
+        valid = valid.reshape(valid.shape + (1,) * len(rest))
+        return jnp.where(valid, out, jnp.zeros((), v.dtype))
+
+    def packed(self, v: jax.Array) -> jax.Array:
+        """(B, n_max) per-problem values -> (sum(lengths),) packed, in the
+        caller's row order: one program per ``lengths``."""
+        return _packed(v, lengths=self.lengths)
 
 
 jax.tree_util.register_pytree_node(
     BatchPlan,
-    lambda p: ((p.x, p.xp, p.xn), (p.b, p.n, p.f, p.params)),
-    lambda aux, kids: BatchPlan(kids[0], kids[1], kids[2], *aux))
+    lambda p: ((p.xp, p.xn, p.tile_prob, p.tile_rows, p.tile_slot),
+               (p.lengths, p.block, p.f, p.params)),
+    lambda aux, kids: BatchPlan(*kids, *aux))
 
 
-def plan_data_batched(x: jax.Array,
-                      params: Optional[KernelParams] = None) -> BatchPlan:
-    """Build the per-fit :class:`BatchPlan` (pad + row norms, once).
+@functools.partial(jax.jit, static_argnames=("lengths",))
+def _packed(v: jax.Array, *, lengths: tuple[int, ...]) -> jax.Array:
+    return jnp.concatenate([v[b, :n] for b, n in enumerate(lengths)])
 
-    Padding happens on the whole (B, N, F) block in one op — the stacked
-    layout means every problem shares N and F, so one pad covers all B
-    problems (a per-problem loop of pads is exactly the dispatch overhead
-    the batched path exists to remove)."""
+
+@functools.partial(jax.jit, static_argnames=("rows", "fp"))
+def _pad_stack(x: jax.Array, *, rows: int, fp: int) -> jax.Array:
+    """(B, N, F) -> (B * rows, fp): each problem zero-padded to ``rows``
+    rows and its features to ``fp``, flattened in one program (an eager
+    reshape would copy the padded block)."""
     b, n, f = x.shape
-    xn = jnp.sum(x.astype(jnp.float32) ** 2, axis=2)
-    if params is None:
-        return BatchPlan(x=x, xp=x, xn=xn, b=b, n=n, f=f, params=None)
-    np_ = _round_up(n, params.block_m)
-    fp = _round_up(f, params.block_f)
-    xp = jnp.pad(x, ((0, 0), (0, np_ - n), (0, fp - f)))
-    return BatchPlan(x=x, xp=xp, xn=xn, b=b, n=n, f=f, params=params)
+    return jnp.pad(x, ((0, 0), (0, rows - n), (0, fp - f))).reshape(
+        b * rows, fp)
+
+
+@functools.partial(jax.jit, static_argnames=("f",))
+def _row_norms(xp: jax.Array, *, f: int) -> jax.Array:
+    """Squared norms, in f32, of the first ``f`` features of each row:
+    one fused reduction, so no squares of X are held."""
+    return jnp.sum(xp[:, :f].astype(jnp.float32) ** 2, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("lengths", "block", "fp"))
+def _pack_rows(x: jax.Array, *, lengths: tuple[int, ...], block: int,
+               fp: int) -> jax.Array:
+    """Packed (sum N, F) rows -> each problem padded to whole tiles of
+    ``block`` rows and features to ``fp``, zero in the padding: one
+    contiguous copy per problem into a zeroed block. Each problem's
+    source offset passes through an optimization barrier with the block
+    written so far, so its slice is read only after the previous copy:
+    left free, XLA slices many problems ahead into temporaries (0.92 GB
+    at the KV-key cell's shapes on a v5e)."""
+    tiles = [-(-n // block) for n in lengths]
+    xp = jnp.zeros((sum(tiles) * block, fp), x.dtype)
+    start = dst = 0
+    for n, t in zip(lengths, tiles):
+        xp, s = jax.lax.optimization_barrier((xp, jnp.int32(start)))
+        rows = jnp.pad(jax.lax.dynamic_slice_in_dim(x, s, n),
+                       ((0, 0), (0, fp - x.shape[1])))
+        xp = jax.lax.dynamic_update_slice(xp, rows, (dst, 0))
+        start, dst = start + n, dst + t * block
+    return xp
+
+
+# Row tiles of a ragged launch hold at least this many rows per padded
+# cluster slot (up to MAX_RAGGED_BLOCK): each tile writes one (K, F)
+# partial, so the partials stay under 1/16 of X's bytes, at the price of
+# up to B * (block_m - 1) padded rows.
+RAGGED_ROWS_PER_SLOT = 16
+MAX_RAGGED_BLOCK = 4096
+
+
+def ragged_params(params: KernelParams, n_max: int, k: int, f: int,
+                  dtype: Any = jnp.float32) -> KernelParams:
+    """The tiles of a ragged launch: ``params`` with ``block_m`` raised to
+    ``RAGGED_ROWS_PER_SLOT`` rows per padded cluster slot, then clamped to
+    the longest problem like any launch."""
+    bm = max(params.block_m, min(RAGGED_ROWS_PER_SLOT * _round_up(k, 128),
+                                 MAX_RAGGED_BLOCK))
+    return clamp_params(n_max, k, f, dataclasses.replace(params, block_m=bm),
+                        dtype=dtype)
+
+
+def ragged_lengths(x: jax.Array, lengths) -> tuple[int, ...]:
+    """Check ``lengths`` against packed rows ``x`` (sum N, F) and return
+    them as a tuple of ints."""
+    arr = np.asarray(lengths)
+    if arr.ndim != 1 or arr.size == 0 or not np.issubdtype(arr.dtype,
+                                                           np.integer):
+        raise ValueError(f"lengths must be a non-empty 1-D integer array, "
+                         f"got {lengths!r}")
+    if x.ndim != 2:
+        raise ValueError(f"ragged problems are packed (sum N, F) rows, got "
+                         f"shape {x.shape}")
+    out = tuple(int(n) for n in arr)
+    if min(out) < 1:
+        raise ValueError(f"every problem needs at least one row, got "
+                         f"lengths {out}")
+    if sum(out) != x.shape[0]:
+        raise ValueError(f"lengths sum to {sum(out)} rows but x has "
+                         f"{x.shape[0]}")
+    return out
+
+
+def plan_data_batched(x: jax.Array, params: Optional[KernelParams] = None,
+                      lengths=None) -> BatchPlan:
+    """Build the per-fit :class:`BatchPlan` (pack + row norms, once).
+
+    ``x`` is a (B, N, F) stack, or, with ``lengths`` (B,), the rows of B
+    problems of any row counts packed back to back, (sum N, F); packed
+    rows of one length are that stack, reshaped. With ``params`` every
+    problem is padded to whole ``block_m`` row tiles and features to
+    ``block_f``: a stack by one pad of the whole block (a per-problem loop
+    of pads is the dispatch overhead the batched path exists to remove),
+    its row norms taken as :func:`plan_data` takes them, so each problem's
+    arithmetic is the single-problem path's; ragged rows by one copy per
+    problem (at most B * (block_m - 1) padded rows), their norms in one
+    fused reduction. Without ``params``, rows are kept as they are."""
+    if lengths is not None:
+        lengths = ragged_lengths(x, lengths)
+        if len(set(lengths)) == 1:
+            x = x.reshape(len(lengths), lengths[0], x.shape[1])
+    block = params.block_m if params is not None else 1
+    f = x.shape[-1]
+    fp = _round_up(f, params.block_f) if params is not None else f
+    if x.ndim == 3:
+        b, n, _ = x.shape
+        lengths, np_ = (n,) * b, _round_up(n, block)
+        xn = jnp.sum(x.astype(jnp.float32) ** 2, axis=2)
+        xn = jnp.pad(xn, ((0, 0), (0, np_ - n))).reshape(-1)
+        xp = _pad_stack(x, rows=np_, fp=fp)
+    else:
+        xp = x if params is None else _pack_rows(x, lengths=lengths,
+                                                 block=block, fp=fp)
+        xn = _row_norms(xp, f=f)
+    tiles = [-(-n // block) for n in lengths] if params is not None else []
+    tile_prob = np.repeat(np.arange(len(tiles), dtype=np.int32), tiles)
+    tile_rows = np.concatenate([np.zeros((0,), np.int32)] + [
+        np.minimum(block, n - block * np.arange(t)) for n, t in
+        zip(lengths, tiles)]).astype(np.int32)
+    return BatchPlan(xp=xp, xn=xn, tile_prob=jnp.asarray(tile_prob),
+                     tile_rows=jnp.asarray(tile_rows),
+                     tile_slot=jnp.asarray(_tile_slots(tiles)),
+                     lengths=lengths, block=block, f=f, params=params)
+
+
+def _tile_slots(tiles: list[int]) -> np.ndarray:
+    """The partials row of each row tile: a run of g problems with n tiles
+    each, tiles (b, j) in problem order, writes its partials as an
+    (n, g) block, tile j of problem b at row j * g + b."""
+    slots, start = [], 0
+    for n, group in itertools.groupby(tiles):
+        g = len(list(group))
+        j = np.arange(g * n)
+        slots.append(start + (j % n) * g + j // n)
+        start += g * n
+    return np.concatenate([np.zeros((0,), np.int64)] + slots).astype(
+        np.int32)
 
 
 def _pad_centroids_batched(c: jax.Array, k: int, kp: int,
@@ -508,15 +716,21 @@ def fused_assign_int8(
     return am[:m, 0], mind[:m, 0]
 
 
-def _tree_sum(a: jax.Array) -> jax.Array:
+def _halve(a: jax.Array) -> jax.Array:
     """Balanced pairwise reduction over axis 0 (log2 depth, better fp
     behaviour than a linear fold for many partial blocks)."""
+    while a.shape[0] > 1:
+        half = a.shape[0] // 2
+        rest = a[2 * half:]
+        a = jnp.concatenate([a[:half] + a[half:2 * half], rest], axis=0)
+    return a[0]
+
+
+def _tree_sum(a: jax.Array) -> jax.Array:
+    """:func:`_halve` in the ``partials`` scope: one problem's partial
+    blocks."""
     with obs.scope("partials"):
-        while a.shape[0] > 1:
-            half = a.shape[0] // 2
-            rest = a[2 * half:]
-            a = jnp.concatenate([a[:half] + a[half:2 * half], rest], axis=0)
-        return a[0]
+        return _halve(a)
 
 
 def fused_lloyd(
@@ -732,7 +946,7 @@ def _resolve_padded_batched(x: Any, c: jax.Array,
         plan = plan_data_batched(x, params)
     c = c.astype(plan.xp.dtype)
     kp = _round_up(k, 128)
-    cp, cn = _pad_centroids_batched(c, k, kp, plan.xp.shape[2])
+    cp, cn = _pad_centroids_batched(c, k, kp, plan.xp.shape[1])
     return plan, cp, cn, params
 
 
@@ -745,29 +959,49 @@ def fused_lloyd_batched(
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One-pass Lloyd step for B independent problems in a single launch.
 
-    ``x`` may be a raw (B, N, F) stack or a prebuilt :class:`BatchPlan`;
-    ``c`` is the (B, K, F) per-problem centroid stack. f32, bf16 and fp16
-    inputs all lower (f32 accumulators and outputs). The problem axis maps
-    to the outermost grid dimension of the kernel, so one launch replaces B
-    dispatches; per-problem arithmetic is identical to a loop of
-    single-problem :func:`fused_lloyd` calls at the same tiles (same
-    epilogue, same tree-reduction order). Returns (assign (B, N) int32,
-    true squared distance (B, N) f32, sums (B, K, F) f32,
-    counts (B, K) f32).
+    ``x`` may be a raw (B, N, F) stack or a prebuilt :class:`BatchPlan`,
+    whose problems may differ in row count; ``c`` is the (B, K, F)
+    per-problem centroid stack. f32, bf16 and fp16 inputs all lower (f32
+    accumulators and outputs). The kernel's grid runs over the problems'
+    row tiles, so one launch replaces B dispatches; per-problem arithmetic
+    is identical to a loop of single-problem :func:`fused_lloyd` calls at
+    the same tiles (same epilogue, same tree-reduction order). Problems of
+    one row count launch ``lloyd_step_batched``, others
+    ``lloyd_step_ragged`` (the same kernel). Returns (assign (B, n_max)
+    int32, true squared distance (B, n_max) f32, both 0 past each
+    problem's rows; sums (B, K, F) f32, counts (B, K) f32).
     """
     plan, cp, cn, params = _resolve_padded_batched(x, c, params)
     if interpret is None:
         interpret = not on_tpu()
-    k, n = c.shape[1], plan.n
-    meta = jnp.array([n], jnp.int32)
-    mind, am, sums, counts = _ll.lloyd_step_batched(
-        plan.xp, cp, cn, meta, block_m=params.block_m,
-        block_f=params.block_f, interpret=interpret)
-    # same balanced pairwise order as the single-problem reduction, per
-    # problem: collapse the row-tile partials (axis 1) for all B at once
-    sums = _tree_sum(jnp.moveaxis(sums, 1, 0))[:, :k, :plan.f]
-    counts = _tree_sum(jnp.moveaxis(counts, 1, 0))[:, :k]
-    return am[:, :n, 0], mind[:, :n, 0] + plan.xn, sums, counts
+    k = c.shape[1]
+    step = _ll.lloyd_step_batched if plan.stacked else _ll.lloyd_step_ragged
+    mind, am, sums, counts = step(
+        plan.tile_prob, plan.tile_rows, plan.tile_slot, plan.xp, cp, cn,
+        block_m=params.block_m, block_f=params.block_f, interpret=interpret)
+    # each problem's row-tile partials, in the single-problem pairs
+    sums = _segment_tree_sum(sums, plan.tiles)[:, :k, :plan.f]
+    counts = _segment_tree_sum(counts, plan.tiles)[:, :k]
+    return (plan.per_problem(am.reshape(-1)),
+            plan.per_problem(mind.reshape(-1) + plan.xn), sums, counts)
+
+
+def _segment_tree_sum(a: jax.Array, counts: tuple[int, ...]) -> jax.Array:
+    """:func:`_tree_sum` of each problem's partial blocks, problem ``b``
+    having ``counts[b]`` of them: (sum(counts), ...) -> (len(counts),
+    ...). The blocks come in ``BatchPlan.tile_slot`` order: a run of g
+    problems with n blocks each is an (n, g, ...) block, halved along its
+    leading axis by contiguous slices, which XLA fuses into the sums with
+    no copy (compile for a v5e: a problem-major run is transposed or
+    copied out first)."""
+    with obs.scope("partials"):
+        out, start = [], 0
+        for n, group in itertools.groupby(counts):
+            g = len(list(group))
+            out.append(_halve(a[start:start + g * n].reshape(
+                (n, g) + a.shape[1:])))
+            start += g * n
+        return out[0] if len(out) == 1 else jnp.concatenate(out)
 
 
 def _verify_update_partials(plan: Any, am: jax.Array, sums_p: jax.Array,
@@ -1085,15 +1319,16 @@ def kernel_plan(kind: str, m: int, k: int, f: int,
     fn: Any
     args: tuple[Any, ...]
     if kind == "batched":
-        np_ = _round_up(m, p.block_m)
+        tiles = batch * (_round_up(m, p.block_m) // p.block_m)
         kp = _round_up(k, 128)
-        xs = jax.ShapeDtypeStruct((batch, np_, fp), dt)
+        tile_map = jax.ShapeDtypeStruct((tiles,), jnp.int32)
+        xs = jax.ShapeDtypeStruct((tiles * p.block_m, fp), dt)
         cs = jax.ShapeDtypeStruct((batch, kp, fp), dt)
         cn = jax.ShapeDtypeStruct((batch, 1, kp), jnp.float32)
         var = "smallk"   # the batched template is the smallk epilogue
         fn = functools.partial(_ll.lloyd_step_batched, block_m=p.block_m,
                                block_f=p.block_f, interpret=False)
-        args = (xs, cs, cn, meta)
+        args = (tile_map, tile_map, tile_map, xs, cs, cn)
     elif kind == "init":
         # fused k-means++ round: (B, Np/bn) grid, full-F blocks; K and
         # block_k/block_f are not axes of this kernel

@@ -108,13 +108,11 @@ def test_batched_chunk_hlo_carries_the_step_scopes(blobs, backend, one_pass):
     xb = jnp.stack([x[:300], x[300:]])
     bkm = BatchedKMeans(4, max_iter=2, backend=backend)
     params = bkm._resolve_params(2, 300, x.shape[1])
-    plan = ops.plan_data_batched(xb, params) \
-        if bkm._backend.takes_params else xb
+    plan = ops.plan_data_batched(xb, params)
     text = bkm._chunk_fn(params, 2).lower(
         plan, xb[:, :4], jnp.zeros((2, 300), jnp.int32),
         jnp.zeros((2,), jnp.float32), jnp.zeros((2,), bool),
-        jnp.zeros((), jnp.int32), bkm._problem_keys(2),
-        jnp.int32(0)).as_text(debug_info=True)
+        jnp.zeros((), jnp.int32)).as_text(debug_info=True)
     want = STEP_SCOPES | ({"kmeans.partials"} if one_pass else set())
     assert _scopes(text) == want
 

@@ -6,7 +6,8 @@ rules — block shapes whose last two dims are neither (8, 128)-aligned nor
 equal to the array's, vector ops without a lowering, working sets over the
 scoped-VMEM limit. Each test here compiles one ``ops`` wrapper with
 ``interpret=False`` at the chip smoke's widths (SIFT1M: N=1,048,576,
-F=128; the batched stack B=16, N=65,536, K=256) with the tiles the
+F=128; the batched stack B=16, N=65,536, K=256; the ragged KV-key batch,
+256 problems of 5,087 to 105,545 rows, K=256) with the tiles the
 autotuner picks, and asserts the kernel reached the compiled program
 (``tpu_custom_call``). A compile that passes is not a chip run:
 ``chip_smoke.py`` is.
@@ -26,6 +27,9 @@ from repro.kernels.kmeanspp_init import init_kmeanspp_fused
 
 N, F = 1_048_576, 128
 B, BN, BK = 16, 65_536, 256
+# the ragged cell: 8 prompts' keys x 8 full-attention layers x 4 KV heads
+PROMPTS = (5087, 7845, 12098, 18658, 28774, 44376, 68438, 105545)
+RAGGED = tuple(n for n in PROMPTS for _ in range(32))
 
 
 @pytest.fixture(scope="module")
@@ -138,7 +142,30 @@ def test_fused_lloyd_batched(one_chip):
     txt = _compiled_text(
         lambda x, c: ops.fused_lloyd_batched(x, c, p, interpret=False),
         _sds(one_chip, (B, BN, F)), _sds(one_chip, (B, BK, F)))
-    assert "tpu_custom_call" in txt
+    assert "lloyd_step_batched" in txt and "tpu_custom_call" in txt
+
+
+def test_fused_lloyd_ragged(one_chip):
+    """The ragged step at the KV-key cell's shapes: B = 256 problems,
+    sum N = 9,306,272 rows, K = 256, F = 128, the pack included."""
+    _, p = _tuned("batched", max(RAGGED), BK, F, batch=len(RAGGED))
+    p = ops.ragged_params(p, max(RAGGED), BK, F)
+    txt = _compiled_text(
+        lambda x, c: ops.fused_lloyd_batched(
+            ops.plan_data_batched(x, p, RAGGED), c, interpret=False),
+        _sds(one_chip, (sum(RAGGED), F)),
+        _sds(one_chip, (len(RAGGED), BK, F)))
+    assert "lloyd_step_ragged" in txt and "tpu_custom_call" in txt
+
+
+def test_ragged_pack_needs_no_temporaries(one_chip):
+    """The pack at the KV-key cell's shapes writes X's padded copy and
+    nothing else: no problem's rows are sliced ahead into temporaries."""
+    m = sum(RAGGED)
+    mem = ops._pack_rows.lower(
+        _sds(one_chip, (m, F)), lengths=RAGGED, block=4096,
+        fp=F).compile().memory_analysis()
+    assert mem.temp_size_in_bytes <= max(RAGGED) * F * 4
 
 
 def test_kmeanspp_round(one_chip):
