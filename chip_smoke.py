@@ -142,8 +142,8 @@ def compiled_step(backend, params, use_dmr, x, c):
         out = backend(x, c, params=params)
         if backend.fuses_update:
             return out[0], out[1], out[3], out[4]
-        sums, counts = protected_sums(x, out[0], c.shape[-2], use_dmr=use_dmr)
-        return out[0], out[1], sums, counts
+        upd = protected_sums(x, out[0], c.shape[-2], use_dmr=use_dmr)
+        return out[0], out[1], upd.sums, upd.counts
 
     compiled = jax.jit(step).lower(x, c).compile()
     return "tpu_custom_call" in compiled.as_text(), compiled(x, c)

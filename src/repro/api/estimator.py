@@ -133,6 +133,11 @@ class KMeans:
         fits on a bounds-carrying backend (``supports_bounds``), empty
         otherwise. Iteration zero is always 0.0 (the seed pass computes
         every tile).
+    update_windows_ : float or None
+        The two-pass update of the last ``fit`` step: 128-cluster windows
+        its sorted-window kernel ran per row tile of label-sorted rows
+        (1.0 when every tile fit one window), 0.0 where the dense one-hot
+        product ran (``ops.update_path``), None for a one-pass backend.
 
     See Also
     --------
@@ -234,6 +239,7 @@ class KMeans:
         self.n_iter_: int = 0
         self.detected_errors_: int = 0
         self.prune_history_: list = []
+        self.update_windows_: Optional[float] = None
 
     # ------------------------------------------------------------------
     # internals
@@ -313,18 +319,20 @@ class KMeans:
                       centroids: jax.Array) -> tuple:
         """One centroid update from a backend result: one-pass backends
         already carry (sums, counts); two-pass backends pay the second
-        pass over X (optionally DMR-protected)."""
-        from repro.core.kmeans import centroid_update, means_from_sums
+        pass over X (optionally DMR-protected), which also reports its
+        windows per row tile (``update_windows_``; None for one-pass)."""
+        from repro.core.kmeans import means_from_sums, protected_sums
+        windows = None
         if self._backend.fuses_update:
             # bounds-carrying backends extend the 5-tuple by
             # (new_bounds, prune_frac); the update only needs the head
             am, md, det, sums, counts = out[:5]
-            new_c = means_from_sums(sums, counts, centroids)
         else:
             am, md, det = out
-            new_c, counts = centroid_update(x, am, self.n_clusters, centroids,
-                                            use_dmr=self._use_dmr)
-        return am, md, det, new_c, counts
+            sums, counts, windows = protected_sums(
+                x, am, self.n_clusters, use_dmr=self._use_dmr)
+        new_c = means_from_sums(sums, counts, centroids)
+        return am, md, det, new_c, counts, windows
 
     def _lloyd_step_fn(self, params: Optional[ops.KernelParams]
                        ) -> Callable[..., Any]:
@@ -338,11 +346,11 @@ class KMeans:
                 x = self._cast(x)
                 out = backend(x, self._cast(centroids), params=params,
                               inj=inj)
-                am, md, det, new_c, counts = self._apply_update(
+                am, md, det, new_c, counts, windows = self._apply_update(
                     out, x, centroids)
                 inertia = jnp.sum(md)
                 shift = jnp.sqrt(jnp.sum((new_c - centroids) ** 2))
-                return new_c, am, counts, md, inertia, shift, det
+                return new_c, am, counts, md, inertia, shift, det, windows
 
             static = () if backend.takes_injection else ("inj",)
             self._step_cache[key] = jax.jit(step, static_argnames=static)
@@ -370,7 +378,8 @@ class KMeans:
                     am, md, det, sums, bcnt = out[:5]
                 else:
                     am, md, det = out
-                    sums, bcnt = protected_sums(x, am, k, use_dmr=use_dmr)
+                    sums, bcnt, _ = protected_sums(x, am, k,
+                                                   use_dmr=use_dmr)
                 new_counts = counts + bcnt
                 eta = (bcnt / jnp.maximum(new_counts, 1.0))[:, None]
                 bmean = sums / jnp.maximum(bcnt, 1.0)[:, None]
@@ -430,7 +439,7 @@ class KMeans:
                                           params=params if takes_params
                                           else None, bounds=bounds)
                         with obs.scope("update"):
-                            am_b, md, det_i, new_c, counts = \
+                            am_b, md, det_i, new_c, counts, _ = \
                                 self._apply_update(out, plan.x, centroids)
                         new_bounds, pfrac = out[5], out[6]
                         inertia_i = jnp.sum(md)
@@ -470,7 +479,7 @@ class KMeans:
                   det0: jax.Array, inertia0: jax.Array, key: jax.Array,
                   it0: Any, inj_stack: Any) -> tuple:
             def body(carry: tuple, xs: tuple) -> tuple:
-                centroids, am, inertia, done, det = carry
+                centroids, am, inertia, done, det, win = carry
                 inj, t = xs
 
                 @obs.scope("step")
@@ -481,30 +490,33 @@ class KMeans:
                                       params=params if takes_params else None,
                                       inj=inj if takes_inj else None)
                     with obs.scope("update"):
-                        am_b, md, det_i, new_c, counts = self._apply_update(
-                            out, plan.x, centroids)
+                        am_b, md, det_i, new_c, counts, win_i = \
+                            self._apply_update(out, plan.x, centroids)
                     inertia_i = jnp.sum(md)
                     shift = jnp.sqrt(jnp.sum((new_c - centroids) ** 2))
                     with obs.scope("reseed"):
                         new_c = reseed_empty(jax.random.fold_in(key, it0 + t),
                                              plan.x, new_c, counts, md)
                     return (new_c, am_b, inertia_i, shift,
-                            det + det_i.astype(jnp.int32))
+                            det + det_i.astype(jnp.int32), win_i)
 
                 def frozen(_: None) -> tuple:
-                    return centroids, am, inertia, jnp.float32(0.0), det
+                    return centroids, am, inertia, jnp.float32(0.0), det, win
 
-                new_c, am_n, inertia_n, shift, det_n = jax.lax.cond(
+                new_c, am_n, inertia_n, shift, det_n, win_n = jax.lax.cond(
                     done, frozen, live, None)
                 active = jnp.logical_not(done)
                 done_n = jnp.logical_or(done, shift < tol)
-                return ((new_c, am_n, inertia_n, done_n, det_n),
+                return ((new_c, am_n, inertia_n, done_n, det_n, win_n),
                         (new_c, inertia_n, shift, active))
 
-            init = (centroids, am0, inertia0, jnp.bool_(False), det0)
-            (centroids, am, inertia, done, det), hist = jax.lax.scan(
+            # the update's window count rides the carry only where a
+            # two-pass update reports one (a None carries no value)
+            win0 = None if backend.fuses_update else jnp.float32(0.0)
+            init = (centroids, am0, inertia0, jnp.bool_(False), det0, win0)
+            (centroids, am, inertia, done, det, win), hist = jax.lax.scan(
                 body, init, (inj_stack, jnp.arange(n_steps)), length=n_steps)
-            return centroids, am, inertia, det, done, hist
+            return centroids, am, inertia, det, done, hist, win
 
         fn = jax.jit(chunk)
         self._step_cache[cache_key] = fn
@@ -604,6 +616,7 @@ class KMeans:
         det = jnp.zeros((), jnp.int32)
         inertia = jnp.float32(jnp.inf)
         inertia_host = float("inf")
+        windows = None
         it0 = 0
         while it0 < self.max_iter:
             n_steps = min(self.sync_every, self.max_iter - it0)
@@ -626,6 +639,8 @@ class KMeans:
             centroids, am, inertia, det, done_d, hist = out[:6]
             if supports_bounds:
                 bounds = out[6]
+            else:
+                windows = out[6]
             # the chunk boundary: the only device->host sync of the window.
             # The (n_steps, K, F) centroid history crosses only when a
             # callback will actually read it.
@@ -655,7 +670,9 @@ class KMeans:
         self.cluster_centers_ = centroids
         self.n_iter_ = max(1, it0)
         with obs.span("sync"):
-            self.detected_errors_ = int(_host_read(det))
+            det_h, win_h = _host_read((det, windows))
+        self.detected_errors_ = int(det_h)
+        self.update_windows_ = None if win_h is None else float(win_h)
         self._counts = None
         self.labels_ = am
         self.inertia_ = inertia_host
@@ -672,6 +689,7 @@ class KMeans:
 
         total_det = jnp.zeros((), jnp.int32)
         inertia = jnp.asarray(jnp.inf)
+        windows = None
         it = 0
         for it in range(self.max_iter):
             idx = rng.choice(x.shape[0], min(self.batch_size, x.shape[0]),
@@ -683,7 +701,7 @@ class KMeans:
             inj = self._draw_injection(inj_rng, batch.shape[0],
                                        batch.shape[1], params) \
                 if takes_inj else None
-            centroids, am_b, counts, md, inertia, shift, det = step(
+            centroids, am_b, counts, md, inertia, shift, det, windows = step(
                 batch, centroids, inj=inj)
             total_det = total_det + det
             # one funnel read per iteration covers both host consumers
@@ -696,7 +714,9 @@ class KMeans:
 
         self.cluster_centers_ = centroids
         self.n_iter_ = it + 1
-        self.detected_errors_ = int(_host_read(total_det))
+        det_h, win_h = _host_read((total_det, windows))
+        self.detected_errors_ = int(det_h)
+        self.update_windows_ = None if win_h is None else float(win_h)
         self._counts = None
         am, dist, det = self._predict_full(x)
         self.detected_errors_ += int(_host_read(det))

@@ -133,6 +133,8 @@ class Verdict(NamedTuple):
     row: jax.Array        # int32 scalar (0 if not detected)
     col: jax.Array        # int32 scalar
     delta: jax.Array      # the error magnitude to subtract
+    value: jax.Array      # the element rebuilt from its row checksum, for
+    #                       a corruption to Inf or NaN that no delta undoes
 
 
 def verify(d: jax.Array, expected: ChecksumState, threshold) -> Verdict:
@@ -147,9 +149,10 @@ def verify(d: jax.Array, expected: ChecksumState, threshold) -> Verdict:
     res_col2 = obs.col2 - expected.col2
     res_row2 = obs.row2 - expected.row2
 
-    # Detection: any column / row residual above threshold.
-    col_bad = jnp.abs(res_col1) > threshold
-    row_bad = jnp.abs(res_row1) > threshold
+    # Detection: any column / row residual above threshold, or not finite
+    # (an exponent flip can turn an element into Inf or NaN).
+    col_bad = jnp.logical_not(jnp.abs(res_col1) <= threshold)
+    row_bad = jnp.logical_not(jnp.abs(res_row1) <= threshold)
     detected = jnp.logical_or(jnp.any(col_bad), jnp.any(row_bad))
 
     # Location. Primary: the arg-max residual column gives j and delta;
@@ -157,18 +160,28 @@ def verify(d: jax.Array, expected: ChecksumState, threshold) -> Verdict:
     # (paper's location encoding). Cross-check with the row residuals.
     j = jnp.argmax(jnp.abs(res_col1)).astype(jnp.int32)
     delta_col = res_col1[j]
-    i_from_ratio = jnp.round(res_col2[j] / jnp.where(delta_col == 0, 1.0, delta_col)) - 1
+    ratio_i = res_col2[j] / jnp.where(delta_col == 0, 1.0, delta_col)
     # Fall back to the row-residual argmax when column residual is degenerate
-    # (e.g. error in a row whose column hit threshold issues).
+    # (e.g. error in a row whose column hit threshold issues), or when the
+    # ratio is not finite: an element corrupted to Inf or NaN, or one so
+    # large that its weighted checksum overflows. The argmaxes then point
+    # at the element (argmax takes a NaN as the largest).
     i_direct = jnp.argmax(jnp.abs(res_row1)).astype(jnp.int32)
-    use_ratio = jnp.abs(delta_col) > threshold
-    i = jnp.where(use_ratio, i_from_ratio.astype(jnp.int32), i_direct)
+    use_ratio = jnp.logical_and(jnp.abs(delta_col) > threshold,
+                                jnp.isfinite(ratio_i))
+    i = jnp.where(use_ratio, (jnp.round(ratio_i) - 1).astype(jnp.int32),
+                  i_direct)
     i = jnp.clip(i, 0, d.shape[0] - 1)
     delta_row = res_row1[i]
     delta = jnp.where(jnp.abs(delta_col) > jnp.abs(delta_row), delta_col, delta_row)
     # If the column residual was degenerate, recover j from the row ratio.
-    j_from_ratio = jnp.round(res_row2[i] / jnp.where(delta_row == 0, 1.0, delta_row)) - 1
-    j = jnp.where(use_ratio, j, jnp.clip(j_from_ratio.astype(jnp.int32), 0, d.shape[1] - 1))
+    ratio_j = res_row2[i] / jnp.where(delta_row == 0, 1.0, delta_row)
+    j_from_ratio = jnp.clip((jnp.round(ratio_j) - 1).astype(jnp.int32), 0,
+                            d.shape[1] - 1)
+    j = jnp.where(jnp.logical_or(use_ratio, jnp.logical_not(
+        jnp.isfinite(ratio_j))), j, j_from_ratio)
+    rest = jnp.sum(jnp.where(jnp.arange(d.shape[1]) == j, 0.0, d[i]))
+    value = expected.row1[i] - rest
 
     zero = jnp.zeros((), jnp.int32)
     return Verdict(
@@ -176,10 +189,14 @@ def verify(d: jax.Array, expected: ChecksumState, threshold) -> Verdict:
         row=jnp.where(detected, i, zero),
         col=jnp.where(detected, j, zero),
         delta=jnp.where(detected, delta, jnp.zeros((), d.dtype)),
+        value=value.astype(d.dtype),
     )
 
 
 def correct(d: jax.Array, verdict: Verdict) -> jax.Array:
-    """Subtract the located delta (no-op when nothing was detected)."""
-    fixed = d.at[verdict.row, verdict.col].add(-verdict.delta)
+    """Subtract the located delta (no-op when nothing was detected); an
+    element corrupted to Inf or NaN is rebuilt from its row checksum."""
+    at = d.at[verdict.row, verdict.col]
+    fixed = jnp.where(jnp.isfinite(verdict.delta), at.add(-verdict.delta),
+                      at.set(verdict.value))
     return jnp.where(verdict.detected, fixed, d)

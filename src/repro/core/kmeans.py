@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from repro.core import dmr as dmr_mod
 from repro.core.fault import FaultConfig
-from repro.kernels import ref
+from repro.kernels import ops
 
 
 class KMeansState(NamedTuple):
@@ -82,24 +82,24 @@ def init_kmeanspp(key: jax.Array, x: jax.Array, k: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def protected_sums(x: jax.Array, assign: jax.Array, k: int, *,
-                   use_dmr: bool = True):
-    """Per-cluster (sums, counts), optionally under DMR.
+                   use_dmr: bool = True) -> ops.ClusterSums:
+    """Per-cluster (sums, counts, windows) of :func:`ops.cluster_sums`,
+    optionally under DMR.
 
     DMR duplicates the arithmetic over once-loaded data (<1 % overhead in
     the paper); a mismatch triggers one recompute (fail-continue fix)."""
     def _sums(x, assign):
-        return ref.centroid_update(x, assign, k)
+        return ops.cluster_sums(x, assign, k)
 
     if not use_dmr:
         return _sums(x, assign)
-    (sums, counts), bad = dmr_mod.dmr(_sums, x, assign)
+    result, bad = dmr_mod.dmr(_sums, x, assign)
 
     def recompute(_):
         return _sums(jax.lax.optimization_barrier(x),
                      jax.lax.optimization_barrier(assign))
 
-    return jax.lax.cond(bad, recompute, lambda _: (sums, counts),
-                        operand=None)
+    return jax.lax.cond(bad, recompute, lambda _: result, operand=None)
 
 
 def means_from_sums(sums: jax.Array, counts: jax.Array,
@@ -114,7 +114,7 @@ def means_from_sums(sums: jax.Array, counts: jax.Array,
 def centroid_update(x: jax.Array, assign: jax.Array, k: int,
                     prev: jax.Array, *, use_dmr: bool = True):
     """Means of assigned points; empty clusters keep their previous centroid."""
-    sums, counts = protected_sums(x, assign, k, use_dmr=use_dmr)
+    sums, counts, _ = protected_sums(x, assign, k, use_dmr=use_dmr)
     return means_from_sums(sums, counts, prev), counts
 
 

@@ -215,7 +215,7 @@ class DistributedKMeans:
                 am, md, det, sums, cnt = out
             else:
                 am, md, det = out
-                sums, cnt = protected_sums(x, am, k, use_dmr=use_dmr)
+                sums, cnt, _ = protected_sums(x, am, k, use_dmr=use_dmr)
             sums, cnt, bad, res_out = reduce_update(
                 sums, cnt, intra=intra, cross=cross, compress=compress,
                 residual=res[0] if compress else None,

@@ -2,7 +2,7 @@
 
 ``distance_argmin`` performs the distance GEMM and the min/argmin epilogue,
 but the centroid *update* still re-reads X from HBM in a second pass
-(``ref.centroid_update``). This kernel folds that second pass into the
+(``ops.cluster_sums``). This kernel folds that second pass into the
 assignment kernel's epilogue: while the feature tiles of X stream through
 VMEM for the GEMM, they are stashed in a VMEM row-tile buffer; once the
 argmin for a row tile is final (last centroid tile, last feature step), a
@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._mosaic import compiler_params, mxu_dot
+from repro.kernels._mosaic import compiler_params, mxu_dot, onehot_dot
 from repro.kernels.distance_argmin import MIN_INIT, fold_min, tile_min_argmin
 
 # SMEM metadata layout: [true_m] — rows >= true_m are padding and must not
@@ -165,12 +165,9 @@ def _emit_update(meta_ref, argmin_ref, sums_ref, counts_ref, xbuf_ref,
                  m_idx, bm):
     """Shared one-hot update epilogue: final argmin -> per-cluster partial
     sums/counts for this row tile. The one-hot matrix is exact (0/1) in the
-    stash dtype, so a 2-byte stash loses nothing; accumulation is f32.
-
-    An f32 stash is split exactly into three bf16 slices (hi + mid + lo ==
-    x for normal f32) and the product takes one bf16 MXU pass per slice.
-    ``Precision.HIGHEST`` would take six, three of them against the
-    one-hot's bf16 mid and lo parts, which are zero."""
+    stash dtype, so a 2-byte stash loses nothing; accumulation is f32. An
+    f32 stash takes the product in three exact bf16 passes
+    (:func:`~repro.kernels._mosaic.onehot_dot`)."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0) + m_idx * bm
     valid = (rows < meta_ref[0]).astype(jnp.float32)           # (bm, 1)
     sums, counts = _onehot_update(valid, argmin_ref[...],
@@ -187,26 +184,7 @@ def _onehot_update(valid: jax.Array, am: jax.Array, kp: int, x: jax.Array
     clusters = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
     onehot = (am == clusters).astype(jnp.float32) * valid
     counts = jnp.sum(onehot, axis=0, keepdims=True)            # (1, kp)
-    if x.dtype != jnp.float32:
-        sums = mxu_dot(onehot.astype(x.dtype), x, (0, 0))
-    else:
-        onehot = onehot.astype(jnp.bfloat16)
-        hi, mid, lo = (mxu_dot(onehot, s, (0, 0)) for s in _bf16_slices(x))
-        sums = (hi + mid) + lo
-    return sums, counts
-
-
-def _bf16_slices(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Split f32 ``x`` into bf16 ``(hi, mid, lo)`` with hi + mid + lo == x.
-
-    Each slice holds the next 8 significant bits of what the slices before
-    it left over, and each residual is exact in f32, so the three slices
-    carry all 24 bits of a normal f32."""
-    hi = x.astype(jnp.bfloat16)
-    rest = x - hi.astype(jnp.float32)
-    mid = rest.astype(jnp.bfloat16)
-    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, mid, lo
+    return onehot_dot(onehot, x, (0, 0)), counts
 
 
 def _kernel_smallk(meta_ref, x_ref, c_ref, cn_ref,

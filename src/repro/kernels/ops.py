@@ -10,15 +10,16 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
-from typing import Any, Iterator, Optional
+from typing import Any, Iterator, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.extend import core as jex_core
 
-from repro import obs
+from repro import hw, obs
 from repro.dist.compression import quantize_rows as _quantize_rows
+from repro.kernels import cluster_sums as _cs
 from repro.kernels import distance_argmin as _da
 from repro.kernels import distance_argmin_ft as _daft
 from repro.kernels import distance_argmin_int8 as _dai
@@ -763,6 +764,130 @@ def fused_lloyd(
     sums = _tree_sum(sums)[:k, :plan.f]
     counts = _tree_sum(counts)[:k]
     return am[:m, 0], mind[:m, 0] + plan.xn, sums, counts
+
+
+# ---------------------------------------------------------------------------
+# Two-pass centroid update
+# ---------------------------------------------------------------------------
+
+class ClusterSums(NamedTuple):
+    """Per-cluster sums and counts of one update pass."""
+
+    sums: jax.Array      # (K, F) f32
+    counts: jax.Array    # (K,) f32, exact integers
+    windows: jax.Array   # f32 scalar: 128-cluster windows the sorted kernel
+    #                      ran per row tile (1.0: every tile fit one); 0.0
+    #                      on the dense path
+
+
+# Row tile of the sorted update: at the IVF4096 cell's Zipf(1) cluster
+# sizes a 1024-row tile of label-sorted rows spans at most 26 labels (44
+# if the sizes fall in label order; a numpy model of the sizes), so one
+# window covers every tile.
+SORTED_BLOCK_M = 1024
+# Smallest K at which the sorted update (sort, gather, window kernel) beats
+# the dense one-hot product, whose cost grows with K, for each X dtype the
+# sorted kernel takes. TPU v5e, M = 1 M, F = 128 (chip probes, PERF.md §6):
+# f32: dense 3.47, 4.48, 6.70, 12.12, 22.27 ms at K = 256, 512, 1024, 2048,
+# 4096; sorted 13.3 to 13.9 ms at every K; they cross near K = 2400. At
+# M = 65,536 they tie at K = 1024 (1.42 against 1.48 ms) and the sorted
+# path wins at 4096 (1.76 against 2.28), so the rule holds for smaller M
+# too. bf16 (the dense product in one MXU pass, not three): dense 11.54,
+# 13.82, 16.41, 21.82 ms at K = 4096, 5120, 6144, 8192; sorted 14.14 to
+# 14.46; they cross near K = 5300. Other dtypes take the dense product:
+# Mosaic has no float16 row load for the kernel, and the estimators hand
+# int8 fits an f32 X.
+SORTED_MIN_K = {jnp.dtype(jnp.float32): 2560, jnp.dtype(jnp.bfloat16): 5376}
+
+
+def cluster_sums_vmem_bytes(k: int, f: int, dtype: Any = jnp.float32) -> int:
+    """Working-set estimate of the sorted-window kernel: double-buffered
+    row and label tiles, the resident (double-buffered) f32 sums block, the
+    bf16 slices of a row tile and one window's one-hot and products."""
+    block_m = SORTED_BLOCK_M
+    fp = _round_up(f, 128)
+    rows = 2 * block_m * fp * _itemsize(dtype) + 2 * 8 * block_m * 4
+    out = 2 * _cs.out_rows(k) * fp * 4
+    work = (3 * block_m * fp * 2 + 2 * _cs.WINDOW * block_m * 4
+            + 3 * _cs.WINDOW * fp * 4)
+    return rows + out + work
+
+
+def update_path(m: int, k: int, f: int, dtype: Any = jnp.float32) -> str:
+    """The update the TPU runs for M rows of width F into K clusters:
+    ``"sorted"`` for an X of a dtype in :data:`SORTED_MIN_K` from its
+    threshold on, while the sums block fits VMEM; ``"dense"`` (the one-hot
+    product) otherwise. The dense product costs 2·M·K·F flops; the sorted
+    path a sort of M labels, a gather of M rows and 2·M·128·F flops,
+    whatever K is."""
+    min_k = SORTED_MIN_K.get(jnp.dtype(dtype))
+    if (min_k is not None and k >= min_k and m >= SORTED_BLOCK_M
+            and cluster_sums_vmem_bytes(k, f, dtype) <= hw.VMEM_BUDGET):
+        return "sorted"
+    return "dense"
+
+
+def cluster_sums(x: jax.Array, am: jax.Array, k: int) -> ClusterSums:
+    """Per-cluster sums and counts of ``x`` (M, F) under labels ``am``
+    (M,) int32 in [0, K): the two-pass update. On the TPU the path follows
+    :func:`update_path`; elsewhere the dense product runs (Pallas would
+    only interpret)."""
+    m, f = x.shape
+    if on_tpu() and update_path(m, k, f, x.dtype) == "sorted":
+        return _sorted_sums(x, am, k, interpret=False)
+    return _dense_sums(x, am, k)
+
+
+def _dense_sums(x: jax.Array, am: jax.Array, k: int) -> ClusterSums:
+    """The one-hot product ``onehot(am)^T x`` at full precision; counts
+    are the one-hot's column sums, in f32 for every input dtype."""
+    onehot = jax.nn.one_hot(am, k, dtype=x.dtype)            # (M, K)
+    sums = jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+    counts = jnp.sum(onehot.astype(jnp.float32), axis=0)
+    return ClusterSums(sums, counts, jnp.float32(0.0))
+
+
+def _sort_labels(am: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """``am`` sorted stably, with the row order that sorts it. One
+    operand, the packed key ``label·2^b + row``, where it fits 32 bits;
+    else a two-operand sort."""
+    m = am.shape[0]
+    bits = max(1, (m - 1).bit_length())
+    rows = jnp.arange(m, dtype=jnp.int32)
+    if k << bits > 2 ** 32:
+        labels, order = jax.lax.sort((am, rows), num_keys=1, is_stable=True)
+        return labels, order
+    key = jnp.sort((am.astype(jnp.uint32) << bits) | rows.astype(jnp.uint32))
+    return ((key >> bits).astype(jnp.int32),
+            (key & ((1 << bits) - 1)).astype(jnp.int32))
+
+
+def _sorted_sums(x: jax.Array, am: jax.Array, k: int, *,
+                 interpret: bool = False) -> ClusterSums:
+    """The sorted-window update: sort the labels, count each cluster from
+    the sorted labels, gather X's rows in label order and sum each row
+    tile against its label windows (``kernels/cluster_sums.py``)."""
+    m = x.shape[0]
+    bm = min(SORTED_BLOCK_M, _round_up(m, 128))
+    mp = _round_up(m, bm)
+    with obs.scope("sort"):
+        labels, order = _sort_labels(am, k)
+        edges = jnp.searchsorted(labels, jnp.arange(k + 1, dtype=jnp.int32))
+        counts = jnp.diff(edges).astype(jnp.float32)
+        last = labels[jnp.minimum(jnp.arange(bm, mp + 1, bm), m) - 1]
+        start = labels[::bm] // 8 * 8
+        nwin = (last - start) // _cs.WINDOW + 1
+        labels = jnp.pad(labels, (0, mp - m),
+                         constant_values=_cs.PAD_LABEL)[None]
+    with obs.scope("gather"):
+        xs = x.at[jnp.pad(order, (0, mp - m))].get(
+            mode="promise_in_bounds")
+    sums = _cs.cluster_sums(start, nwin, labels, xs, k=k, block_m=bm,
+                            interpret=interpret)
+    windows = jnp.sum(nwin).astype(jnp.float32) / nwin.shape[0]
+    return ClusterSums(sums[:k], counts, windows)
 
 
 # Relative + absolute fp-safety slack on the tile skip test. The bounds

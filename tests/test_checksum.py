@@ -52,6 +52,26 @@ class TestLocateAndCorrect:
         fixed = checksum.correct(bad, v)
         np.testing.assert_allclose(fixed, d, rtol=1e-4, atol=1e-3)
 
+    @pytest.mark.parametrize("i,j,value", [
+        (17, 3, float("nan")), (0, 31, float("inf")),
+        (63, 0, -float("inf")), (5, 9, 3.0e38)])
+    def test_non_finite_or_overflowing_error_corrected(self, i, j, value):
+        """An exponent flip can make an element Inf, NaN, or so large that
+        its weighted checksum overflows: it is still detected, located by
+        the residual argmaxes and rebuilt (or its delta subtracted)."""
+        x, y = _rand((64, 128), 5), _rand((128, 32), 6)
+        d = x @ y
+        exp = checksum.expected_checksums(x, y)
+        bad = d.at[i, j].set(value)
+        thr = checksum.default_threshold(128) * float(jnp.max(jnp.abs(d)))
+        v = checksum.verify(bad, exp, thr)
+        assert bool(v.detected)
+        assert int(v.row) == i and int(v.col) == j
+        fixed = checksum.correct(bad, v)
+        assert bool(jnp.all(jnp.isfinite(fixed)))
+        atol = 1e-2 + (abs(value) * 2e-5 if np.isfinite(value) else 0.0)
+        np.testing.assert_allclose(fixed, d, rtol=1e-3, atol=atol)
+
     @settings(max_examples=40, deadline=None)
     @given(st.integers(0, 31), st.integers(0, 15),
            st.sampled_from([1e3, -1e3, 1e6, -1e-1 * 1e4]))

@@ -5,6 +5,7 @@ import subprocess
 import sys
 
 import jax.numpy as jnp
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -183,3 +184,28 @@ class TestChipEntryPoints:
         assert out.returncode != 0
         assert "needs a TPU" in out.stderr
         assert '"ok"' not in out.stdout
+
+    @pytest.mark.parametrize("use_dmr", [False, True])
+    @pytest.mark.parametrize("name", ["fused", "lloyd"])
+    def test_chip_smoke_step_agrees_with_ref(self, name, use_dmr):
+        """The smoke's compiled step, for a two-pass and a one-pass
+        backend, run on the CPU at a small shape: it returns what its
+        reference check reads, and that check passes."""
+        import importlib.util
+
+        import jax
+        from repro.api.registry import get_backend
+        from repro.kernels.ops import KernelParams
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+        smoke = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(smoke)
+        x = jax.random.normal(jax.random.key(0), (512, 16))
+        c = x[:8]
+        _, (am, md, sums, counts) = smoke.compiled_step(
+            get_backend(name), KernelParams(128, 128, 128), use_dmr, x, c)
+        agree = smoke.ref_agreement(x, c, am, md, sums, counts)
+        assert agree["label_mismatches_off_tie"] == 0
+        assert agree["counts_equal"]
+        assert agree["sums_max_rel_err"] <= smoke.SUM_RTOL
+        assert agree["inertia_err_over_scale"] <= smoke.INERTIA_RTOL

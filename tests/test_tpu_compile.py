@@ -22,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.api.cache import AutotuneCache
+from repro.kernels import cluster_sums as cs_kernel
 from repro.kernels import ops
 from repro.kernels.kmeanspp_init import init_kmeanspp_fused
 
@@ -166,6 +167,65 @@ def test_ragged_pack_needs_no_temporaries(one_chip):
         _sds(one_chip, (m, F)), lengths=RAGGED, block=4096,
         fp=F).compile().memory_analysis()
     assert mem.temp_size_in_bytes <= max(RAGGED) * F * 4
+
+
+def test_cluster_sums_ivf4096(one_chip):
+    """The sorted-window update kernel at the IVF4096 fit's shape: 1 M
+    label-sorted rows of 128 in 1024-row tiles, K = 4096, its (4224, 128)
+    sums block resident in VMEM."""
+    m, k, bm = 1_000_000, 4096, ops.SORTED_BLOCK_M
+    assert ops.update_path(m, k, F) == "sorted"
+    t = -(-m // bm)
+    txt = _compiled_text(
+        lambda s, n, lab, x: cs_kernel.cluster_sums(s, n, lab, x, k=k,
+                                                    block_m=bm),
+        _sds(one_chip, (t,), jnp.int32), _sds(one_chip, (t,), jnp.int32),
+        _sds(one_chip, (1, t * bm), jnp.int32),
+        _sds(one_chip, (t * bm, F)))
+    assert "cluster_sums" in txt and "tpu_custom_call" in txt
+
+
+def test_cluster_sums_bf16(one_chip):
+    """The kernel on a bf16 X, from the bf16 threshold on."""
+    m, k, bm = 1_000_000, 6144, ops.SORTED_BLOCK_M
+    assert ops.update_path(m, k, F, jnp.bfloat16) == "sorted"
+    t = -(-m // bm)
+    txt = _compiled_text(
+        lambda s, n, lab, x: cs_kernel.cluster_sums(s, n, lab, x, k=k,
+                                                    block_m=bm),
+        _sds(one_chip, (t,), jnp.int32), _sds(one_chip, (t,), jnp.int32),
+        _sds(one_chip, (1, t * bm), jnp.int32),
+        _sds(one_chip, (t * bm, F), jnp.bfloat16))
+    assert "cluster_sums" in txt and "tpu_custom_call" in txt
+
+
+def test_distributed_row_step_sorted_update(topo, monkeypatch):
+    """``DistributedKMeans`` row mode on the four-chip mesh at the
+    IVF4096 fit's K: each shard of 1 M rows runs the sorted-window update
+    (sort, gather, ``cluster_sums``) inside the sharded step."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.api import KMeans
+    from repro.core.fault import no_step_injection
+    from repro.dist.kmeans_dist import DistributedKMeans
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    m_local, k = N, 4096
+    assert ops.update_path(m_local, k, F) == "sorted"
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    est = KMeans(k, tol=0.0, init="random", autotune=AutotuneCache(None))
+    dk = DistributedKMeans(est, mesh)
+    backend = dk._shard_backend()
+    assert not backend.fuses_update
+    inj = no_step_injection(backend.kernel_kind)
+
+    def sds(shape, spec, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    txt = dk._build_step(m_local, F).lower(
+        sds((len(topo.devices) * m_local, F), P("data", None)),
+        sds((k, F), P(None, None)), sds(inj.shape, P(None), inj.dtype),
+        sds((1, k, F), P(None, None, None))).compile().as_text()
+    assert "cluster_sums" in txt and "tpu_custom_call" in txt
 
 
 def test_kmeanspp_round(one_chip):
