@@ -201,7 +201,7 @@ def check_backend_contracts(
     slots = dict(descriptor_slots) if descriptor_slots is not None \
         else _default_descriptor_slots()
     out: list[Violation] = []
-    src = "src/repro/core/assignment.py"
+    src = "src/repro/api/registry.py"
     m, k, f = shape
     for name in sorted(backends):
         b = backends[name]

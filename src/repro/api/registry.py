@@ -17,6 +17,7 @@ instead of failing silently inside a kernel.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import jax
@@ -174,7 +175,7 @@ class AssignmentBackend:
 
 
 # The registry itself is the one sanctioned module-level mutable: an
-# append-only name->backend table populated at import time, not a cache.
+# append-only name->backend table, filled with the built-ins on first use.
 _REGISTRY: dict[str, AssignmentBackend] = {}  # analysis: allow=module-state
 
 
@@ -200,10 +201,90 @@ def list_backends() -> dict[str, AssignmentBackend]:
     return dict(_REGISTRY)
 
 
+@functools.cache
 def _ensure_builtin_backends() -> None:
-    # The built-in ladder registers itself on import; importing here (not at
-    # module top) keeps registry.py import-cycle-free.
-    from repro.core import assignment as _assignment  # noqa: F401
+    """Fill the registry with the built-in backends, once, on first use:
+    the functions of ``repro.core.assignment`` with their capability
+    flags, imported here, at call time, so that ``repro.core`` never
+    imports ``repro.api``. A name registered before then keeps the
+    caller's backend."""
+    from repro.core import assignment as a
+    from repro.kernels import ops
+    for backend in (
+        AssignmentBackend(
+            "gemm_fused", a.assign_gemm_fused,
+            doc="paper V2/V3 analogue: XLA fuses the GEMM epilogue (cuML "
+                "baseline)"),
+        AssignmentBackend(
+            "fused", a.assign_fused, takes_params=True,
+            doc="paper V4/V5: Pallas fused kernel (MXU + in-VMEM argmin)"),
+        AssignmentBackend(
+            "fused_ft", a.assign_fused_ft, supports_ft=True,
+            takes_params=True, takes_injection=True,
+            doc="paper §IV: fused kernel + dual-checksum online ABFT "
+                "correction"),
+        AssignmentBackend(
+            "abft_offline", a.assign_abft_offline, supports_ft=True,
+            doc="Wu-et-al-style baseline: checksummed GEMM, offline "
+                "verification"),
+        AssignmentBackend(
+            "int8", a.assign_int8, takes_params=True, supports_int8=True,
+            doc="int8 distance template: per-row quantized X/C, i8xi8->i32 "
+                "MXU tiles, f32 scale-corrected epilogue with exact norm "
+                "terms (bit-exact argmin on quantization-safe data)"),
+        AssignmentBackend(
+            "int8_xla", a.assign_int8_xla, supports_int8=True,
+            doc="XLA analogue of the int8 template: same quantization and "
+                "epilogue, f32-carrier GEMM over the quantized integers "
+                "(non-TPU fast path)"),
+        AssignmentBackend(
+            "lloyd", a.assign_lloyd, takes_params=True, fuses_update=True,
+            doc="one-pass Lloyd Pallas kernel: fused assignment + "
+                "in-epilogue centroid accumulation (X read once per "
+                "iteration)"),
+        AssignmentBackend(
+            "lloyd_xla", a.assign_lloyd_xla, fuses_update=True,
+            doc="XLA analogue of the one-pass kernel (non-TPU fast path)"),
+        AssignmentBackend(
+            "lloyd_ft", a.assign_lloyd_ft, supports_ft=True,
+            takes_params=True, takes_injection=True, fuses_update=True,
+            doc="one-pass FT Lloyd Pallas kernel: fused dual-checksum ABFT "
+                "on the distance GEMM + checksum-protected update epilogue "
+                "(paper Fig. 6 composed with the fused-update iteration)"),
+        AssignmentBackend(
+            "lloyd_ft_xla", a.assign_lloyd_ft_xla, supports_ft=True,
+            fuses_update=True,
+            doc="XLA analogue of the one-pass FT backend (checksummed cross "
+                "product + verified one-hot update; non-TPU fast path)"),
+        AssignmentBackend(
+            "lloyd_batched", a.assign_lloyd_batched, takes_params=True,
+            fuses_update=True, supports_batch=True,
+            doc="batched one-pass Lloyd Pallas kernel: B independent "
+                "problems per launch, a grid over their row tiles and a "
+                "tile map naming each tile's problem (smallk epilogue per "
+                "tile)"),
+        AssignmentBackend(
+            "lloyd_batched_xla", a.assign_lloyd_batched_xla,
+            fuses_update=True, supports_batch=True,
+            doc="XLA analogue of the batched one-pass kernel (batched "
+                "contractions over the problem stack; non-TPU fast path)"),
+        AssignmentBackend(
+            "lloyd_pruned", a.assign_lloyd_pruned, takes_params=True,
+            fuses_update=True, supports_bounds=True,
+            bounds_init=ops.init_bounds,
+            doc="pruned one-pass Lloyd Pallas kernel: Hamerly bounds skip "
+                "whole centroid tiles that provably lose (bit-identical to "
+                "lloyd; extended 7-tuple with bounds state + prune "
+                "fraction)"),
+        AssignmentBackend(
+            "lloyd_pruned_xla", a.assign_lloyd_pruned_xla,
+            fuses_update=True, supports_bounds=True,
+            bounds_init=a.init_bounds_xla,
+            doc="XLA analogue of the pruned one-pass backend (row-chunk x "
+                "16-centroid-group cells under lax.cond; non-TPU fast "
+                "path)"),
+    ):
+        _REGISTRY.setdefault(backend.name, backend)
 
 
 # ---------------------------------------------------------------------------
@@ -278,13 +359,7 @@ def main(argv: Optional[list[str]] = None) -> int:
     """
     import argparse
 
-    # ``python -m repro.api.registry`` executes this module as __main__ —
-    # a *second* module instance with its own empty _REGISTRY, while the
-    # builtin backends register into the canonical ``repro.api.registry``.
-    # Always render through the canonical instance.
-    from repro.api import registry as _canonical
     from repro.analysis import report
-    render = _canonical.render_markdown
 
     ap = argparse.ArgumentParser(
         prog="python -m repro.api.registry",
@@ -299,7 +374,7 @@ def main(argv: Optional[list[str]] = None) -> int:
                          "annotations)")
     args = ap.parse_args(argv)
     if args.check:
-        rendered = render()
+        rendered = render_markdown()
         try:
             with open(args.check, encoding="utf-8") as fh:
                 committed: Optional[str] = fh.read()
@@ -314,7 +389,7 @@ def main(argv: Optional[list[str]] = None) -> int:
             return report.emit([stale], fmt=args.format)
         print(f"{args.check} is up to date")
         return report.EXIT_OK
-    out = render()
+    out = render_markdown()
     if args.markdown in (None, "-"):
         print(out, end="")
     else:

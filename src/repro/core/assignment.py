@@ -3,11 +3,6 @@
 Each implementation maps (x (M, F), c (K, F)) ->
 (assign (M,) int32, true squared distance (M,), detected errors):
 
-  naive        the paper's "basic implementation": per-sample loop over all
-               centroids, elementwise distances (no GEMM). O(M K F) scalar
-               work and O(M K F) intermediate traffic.
-  gemm         paper V1: distance via GEMM, *materialized* D (M, K) in HBM,
-               separate argmin pass (two kernels, extra round trip).
   gemm_fused   paper V2/V3 analogue on XLA: one jit so XLA fuses the GEMM
                epilogue with the reduction (cuML-analogue baseline).
   fused        paper V4/V5: the Pallas fused kernel (MXU + in-VMEM argmin).
@@ -52,11 +47,10 @@ Each implementation maps (x (M, F), c (K, F)) ->
                Bit-identical to ``lloyd`` by construction.
   lloyd_pruned_xla XLA analogue at finer granularity (row chunks x
                16-centroid groups, ``lax.cond`` per cell so skipped groups
-               cost nothing off-TPU) — the non-TPU fast path and the
-               pruned benchmark rung.
+               cost nothing off-TPU) — the non-TPU fast path.
 
-Every implementation is published through the ``repro.api`` backend
-registry as an :class:`~repro.api.registry.AssignmentBackend` declaring its
+This module only defines the functions. ``repro.api.registry`` names each
+one as an :class:`~repro.api.registry.AssignmentBackend` declaring its
 capabilities (``supports_ft`` / ``takes_params`` / ``takes_injection``);
 drivers obtain one via ``repro.api.get_backend(name)`` or let a
 ``FaultPolicy`` resolve it, and call it with the uniform
@@ -76,27 +70,6 @@ from repro.kernels import ops, ref
 
 def _zero():
     return jnp.zeros((), jnp.int32)
-
-
-@jax.jit
-def assign_naive(x: jax.Array, c: jax.Array):
-    # One "thread" per sample; centroids broadcast — no GEMM, pure VPU.
-    # Batched over samples in chunks to bound the (M, K, F) intermediate.
-    def per_sample(xi):
-        d = jnp.sum((xi[None, :] - c) ** 2, axis=1)
-        return jnp.argmin(d).astype(jnp.int32), jnp.min(d)
-    am, md = jax.lax.map(per_sample, x, batch_size=1024)
-    return am, md, _zero()
-
-
-@jax.jit
-def assign_gemm(x: jax.Array, c: jax.Array):
-    # Materialize D, then reduce in a second pass. optimization_barrier
-    # models the paper's separate-kernel round trip (prevents XLA from
-    # fusing the argmin into the GEMM loop).
-    d = ref.distance_matrix(x, c)
-    d = jax.lax.optimization_barrier(d)
-    return jnp.argmin(d, axis=1).astype(jnp.int32), jnp.min(d, axis=1), _zero()
 
 
 @jax.jit
@@ -189,8 +162,7 @@ def assign_lloyd_ft(x, c: jax.Array, params=None,
 @jax.jit
 def assign_lloyd_xla(x: jax.Array, c: jax.Array):
     # XLA analogue of the one-pass kernel: assignment and the one-hot
-    # update GEMM in a single fused graph (the non-TPU fast path; also the
-    # benchmark ladder's one-pass rung).
+    # update GEMM in a single fused graph (the non-TPU fast path).
     d = ref.distance_matrix(x, c)
     am = jnp.argmin(d, axis=1).astype(jnp.int32)
     md = jnp.min(d, axis=1)
@@ -466,78 +438,3 @@ def assign_abft_offline(x: jax.Array, c: jax.Array):
     return (jnp.argmin(d, axis=1).astype(jnp.int32), jnp.min(d, axis=1),
             detected.astype(jnp.int32))
 
-
-# ---------------------------------------------------------------------------
-# Registry publication: the ladder as capability-declaring backends.
-# ---------------------------------------------------------------------------
-
-from repro.api.registry import AssignmentBackend, register_backend
-
-register_backend(AssignmentBackend(
-    "naive", assign_naive,
-    doc="paper's basic implementation: per-sample scalar loop, no GEMM"))
-register_backend(AssignmentBackend(
-    "gemm", assign_gemm,
-    doc="paper V1: GEMM + materialized D + separate argmin pass"))
-register_backend(AssignmentBackend(
-    "gemm_fused", assign_gemm_fused,
-    doc="paper V2/V3 analogue: XLA fuses the GEMM epilogue (cuML baseline)"))
-register_backend(AssignmentBackend(
-    "fused", assign_fused, takes_params=True,
-    doc="paper V4/V5: Pallas fused kernel (MXU + in-VMEM argmin)"))
-register_backend(AssignmentBackend(
-    "fused_ft", assign_fused_ft, supports_ft=True, takes_params=True,
-    takes_injection=True,
-    doc="paper §IV: fused kernel + dual-checksum online ABFT correction"))
-register_backend(AssignmentBackend(
-    "abft_offline", assign_abft_offline, supports_ft=True,
-    doc="Wu-et-al-style baseline: checksummed GEMM, offline verification"))
-register_backend(AssignmentBackend(
-    "int8", assign_int8, takes_params=True, supports_int8=True,
-    doc="int8 distance template: per-row quantized X/C, i8xi8->i32 MXU "
-        "tiles, f32 scale-corrected epilogue with exact norm terms "
-        "(bit-exact argmin on quantization-safe data)"))
-register_backend(AssignmentBackend(
-    "int8_xla", assign_int8_xla, supports_int8=True,
-    doc="XLA analogue of the int8 template: same quantization and "
-        "epilogue, f32-carrier GEMM over the quantized integers (non-TPU "
-        "fast path)"))
-register_backend(AssignmentBackend(
-    "lloyd", assign_lloyd, takes_params=True, fuses_update=True,
-    doc="one-pass Lloyd Pallas kernel: fused assignment + in-epilogue "
-        "centroid accumulation (X read once per iteration)"))
-register_backend(AssignmentBackend(
-    "lloyd_xla", assign_lloyd_xla, fuses_update=True,
-    doc="XLA analogue of the one-pass kernel (non-TPU fast path)"))
-register_backend(AssignmentBackend(
-    "lloyd_ft", assign_lloyd_ft, supports_ft=True, takes_params=True,
-    takes_injection=True, fuses_update=True,
-    doc="one-pass FT Lloyd Pallas kernel: fused dual-checksum ABFT on the "
-        "distance GEMM + checksum-protected update epilogue (paper Fig. 6 "
-        "composed with the fused-update iteration)"))
-register_backend(AssignmentBackend(
-    "lloyd_ft_xla", assign_lloyd_ft_xla, supports_ft=True, fuses_update=True,
-    doc="XLA analogue of the one-pass FT backend (checksummed cross "
-        "product + verified one-hot update; non-TPU fast path)"))
-register_backend(AssignmentBackend(
-    "lloyd_batched", assign_lloyd_batched, takes_params=True,
-    fuses_update=True, supports_batch=True,
-    doc="batched one-pass Lloyd Pallas kernel: B independent problems per "
-        "launch, a grid over their row tiles and a tile map naming each "
-        "tile's problem (smallk epilogue per tile)"))
-register_backend(AssignmentBackend(
-    "lloyd_batched_xla", assign_lloyd_batched_xla, fuses_update=True,
-    supports_batch=True,
-    doc="XLA analogue of the batched one-pass kernel (batched contractions "
-        "over the problem stack; non-TPU fast path)"))
-register_backend(AssignmentBackend(
-    "lloyd_pruned", assign_lloyd_pruned, takes_params=True,
-    fuses_update=True, supports_bounds=True, bounds_init=ops.init_bounds,
-    doc="pruned one-pass Lloyd Pallas kernel: Hamerly bounds skip whole "
-        "centroid tiles that provably lose (bit-identical to lloyd; "
-        "extended 7-tuple with bounds state + prune fraction)"))
-register_backend(AssignmentBackend(
-    "lloyd_pruned_xla", assign_lloyd_pruned_xla, fuses_update=True,
-    supports_bounds=True, bounds_init=init_bounds_xla,
-    doc="XLA analogue of the pruned one-pass backend (row-chunk x "
-        "16-centroid-group cells under lax.cond; non-TPU fast path)"))

@@ -22,8 +22,7 @@ as of the template-family refactor it searches three axes, not one:
                                "model": analytical HBM-traffic/MXU-occupancy
                                model (used when the target TPU is absent —
                                this container), "measure": wall-time of the
-                               real kernel (used on device; also drives the
-                               CPU benchmark figures via the jnp fallback).
+                               real kernel (used on device).
   4. ``AutotuneCache``       — per-shape winners, persisted as JSON: the
                                kernel-selection table the runtime consults.
                                Lives in ``repro.api.cache`` as an injectable
@@ -40,7 +39,6 @@ from __future__ import annotations
 import functools
 import itertools
 import time
-import warnings
 from typing import Iterable, Optional
 
 import jax
@@ -373,8 +371,7 @@ def measure_score(m: int, k: int, f: int, p: KernelParams, *, iters: int = 3,
     backend is present; the Pallas interpreter is only an *explicit*
     fallback for kernel-path smoke timing off-device (it measures the
     interpreter, not the kernel — a number that must never be presented as
-    hardware performance, which is why ``benchmarks/check_regression``
-    refuses interpret-mode rungs as guards).
+    hardware performance).
 
     Inputs are seeded-random (all-ones invited constant folding), the
     candidate pipeline is compiled exactly once up front (naively repeating
@@ -480,8 +477,7 @@ def _clustered_data(m: int, k: int, f: int, dtype) -> tuple:
     mode: cluster-contiguous rows assigned round-robin-free (rows of
     cluster j are the contiguous slice j*m/k..(j+1)*m/k) and centroids in
     cluster order, so row tiles and centroid tiles align — the regime tile
-    pruning is built for. ``benchmarks/common.clustered_blobs`` is the
-    user-facing twin (src must not import from benchmarks/)."""
+    pruning is built for."""
     kx, kc = jax.random.split(jax.random.PRNGKey(7))
     centers = jax.random.normal(kc, (k, f), jnp.float32) * 8.0
     labels = (jnp.arange(m) * k) // m
@@ -589,30 +585,3 @@ def select_params(m: int, k: int, f: int, *, mode: str = "model",
                          f"set exceeds VMEM{hint}")
     return best
 
-
-# ---------------------------------------------------------------------------
-# Winner table: owned by repro.api.cache.AutotuneCache (an injectable object,
-# passed per-estimator). The deprecated helpers below delegate to the
-# process-default cache for callers not yet migrated.
-# ---------------------------------------------------------------------------
-
-
-def build_table(shapes: Iterable[tuple[int, int, int]], *, mode: str = "model",
-                dtype=jnp.float32, path: Optional[str] = None) -> dict:
-    """Deprecated: use ``AutotuneCache(path).build(shapes, mode=...)``."""
-    warnings.warn("autotune.build_table is deprecated; use "
-                  "repro.api.AutotuneCache(path).build(...)",
-                  DeprecationWarning, stacklevel=2)
-    from repro.api.cache import AutotuneCache, default_cache
-    cache = AutotuneCache(path) if path else default_cache()
-    return cache.build(shapes, mode=mode, dtype=dtype)
-
-
-def lookup_params(m: int, k: int, f: int) -> KernelParams:
-    """Deprecated: use ``repro.api.AutotuneCache.lookup`` (injectable) or
-    ``repro.api.default_cache()`` for the process-wide table."""
-    warnings.warn("autotune.lookup_params is deprecated; use "
-                  "repro.api.default_cache().lookup(m, k, f)",
-                  DeprecationWarning, stacklevel=2)
-    from repro.api.cache import default_cache
-    return default_cache().lookup(m, k, f)[1]

@@ -20,9 +20,8 @@ itself lands in the returned ``detected`` total, and on the compressed
 hop the expectations are taken on the dequantized values so quantization
 error is never mistaken for corruption.
 
-Accepts either a ``repro.api.KMeans`` estimator (preferred), a
-``repro.api.BatchedKMeans`` (problem-axis sharding — see below), or a
-legacy ``KMeansConfig``.
+Accepts a ``repro.api.KMeans`` estimator or a ``repro.api.BatchedKMeans``
+(problem-axis sharding — see below).
 
 Problem-axis mode: handing ``DistributedKMeans`` a
 :class:`~repro.batch.BatchedKMeans` switches the sharded dimension from
@@ -98,12 +97,13 @@ def restore_estimator(checkpointer):
 class DistributedKMeans:
     def __init__(self, config, mesh, *, reduce: Optional[ReducePlan] = None):
         from repro.api import BatchedKMeans, KMeans as ApiKMeans
+        if not isinstance(config, (ApiKMeans, BatchedKMeans)):
+            raise TypeError(
+                f"DistributedKMeans takes a repro.api.KMeans or "
+                f"repro.api.BatchedKMeans estimator, got "
+                f"{type(config).__name__}")
         self.problem_axis = isinstance(config, BatchedKMeans)
-        if isinstance(config, (ApiKMeans, BatchedKMeans)):
-            self.est = config
-        else:   # legacy KMeansConfig
-            from repro.core.kmeans import _make_estimator
-            self.est = _make_estimator(config, None)
+        self.est = config
         self.reduce = reduce if reduce is not None else ReducePlan()
         self._bind_mesh(mesh)
 

@@ -45,10 +45,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels._mosaic import compiler_params, mxu_dot
 
 # Initial value of the running minimum: +float32 max, so the first observed
-# distance always wins the compare. (Historically misnamed NEG_LIMIT; kept
-# as a deprecated alias below.)
+# distance always wins the compare.
 MIN_INIT = float(jnp.finfo(jnp.float32).max)
-NEG_LIMIT = MIN_INIT  # deprecated alias — use MIN_INIT
 
 
 def tile_min_argmin(acc, cn, base_col):

@@ -21,8 +21,7 @@ into the tile loop:
     detect->locate->correct path inside one kernel launch.
 
 Checksum arithmetic is O((bm + bk) * bf) per tile against the tile's
-O(bm * bk * bf) MACs — e.g. ~1.2 % extra FLOPs at (256, 128) tiles; the
-measured overhead is benchmarked in benchmarks/bench_ft_overhead.py.
+O(bm * bk * bf) MACs — e.g. ~1.2 % extra FLOPs at (256, 128) tiles.
 
 X and C tiles may be f32, bf16 or fp16 (the dtype axis of the §III-B
 template family); the main product accumulates in f32 and the checksums are

@@ -1,7 +1,7 @@
 """Where an entry point keeps JAX's persistent compilation cache.
 
 Importing the library sets no cache; entry points (``chip_smoke.py``,
-``benchmarks/run.py``) call :func:`use_compile_cache` once, before their
+``chipbench/run.py``) call :func:`use_compile_cache` once, before their
 first compile.
 """
 from __future__ import annotations

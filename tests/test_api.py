@@ -1,5 +1,9 @@
 """repro.api surface: estimator round-trips, streaming partial_fit,
-FaultPolicy matrix, backend-registry capabilities, injectable autotune."""
+FaultPolicy matrix, backend-registry capabilities, injectable autotune,
+and the one-way layering beneath it."""
+import ast
+import pathlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -155,8 +159,7 @@ class TestFaultPolicyMatrix:
 class TestRegistry:
     def test_builtin_ladder_registered_with_capabilities(self):
         backends = list_backends()
-        for name in ("naive", "gemm", "gemm_fused", "fused", "fused_ft",
-                     "abft_offline"):
+        for name in ("gemm_fused", "fused", "fused_ft", "abft_offline"):
             assert name in backends
         assert backends["fused_ft"].supports_ft
         assert backends["fused_ft"].takes_injection
@@ -196,6 +199,36 @@ class TestRegistry:
             list_backends()   # registry snapshot still sane
             from repro.api.registry import _REGISTRY
             _REGISTRY.pop("test_custom", None)
+
+
+class TestLayers:
+    def test_core_never_imports_api(self):
+        """The layers point one way, api -> core -> kernels: no import
+        under repro.core names repro.api, at module level or inside a
+        function, and the distributed driver takes only the api
+        estimators."""
+        import repro.core
+        core = pathlib.Path(repro.core.__file__).parent
+        offenders = []
+        for path in sorted(core.rglob("*.py")):
+            pkg = ["repro", "core", *path.relative_to(core).parent.parts]
+            for node in ast.walk(ast.parse(path.read_text())):
+                if isinstance(node, ast.Import):
+                    names = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom):
+                    base = pkg[:len(pkg) + 1 - node.level] if node.level else []
+                    mod = ".".join(base + ([node.module] if node.module else []))
+                    names = [mod] + [f"{mod}.{a.name}" for a in node.names]
+                else:
+                    continue
+                if any(n == "repro.api" or n.startswith("repro.api.")
+                       for n in names):
+                    offenders.append(f"{path.name}:{node.lineno}")
+        assert offenders == []
+
+        from repro.dist.kmeans_dist import DistributedKMeans
+        with pytest.raises(TypeError, match="repro.api.BatchedKMeans"):
+            DistributedKMeans({"k": 8, "max_iters": 25}, None)
 
 
 class TestAutotuneInjection:

@@ -68,23 +68,23 @@ class TestDistributedKMeans:
         out = run_with_devices(f"""
         import jax, jax.numpy as jnp
         from repro.dist.kmeans_dist import DistributedKMeans
-        from repro.core.kmeans import KMeansConfig, KMeans
+        from repro.api import FaultPolicy, KMeans
         from repro.data.blobs import make_blobs
         from repro.ft.checkpoint import Checkpointer
 
         mesh = jax.make_mesh((4, 2), ("data", "model"))
         x, _ = make_blobs(4096, 32, 8, seed=3)
-        cfg = KMeansConfig(k=8, max_iters=25, assignment="fused_ft", seed=0)
-        dk = DistributedKMeans(cfg, mesh)
-        c0 = KMeans(cfg).init_centroids(x)
+        est = KMeans(8, max_iter=25, fault=FaultPolicy.correct(),
+                     backend="fused_ft", random_state=0)
+        dk = DistributedKMeans(est, mesh)
+        c0 = est.init_centroids(x)
         ck = Checkpointer(r'{tmp_path}', async_write=True)
         c, am, inertia, iters, det = dk.fit(
             dk.shard_data(x), c0, checkpointer=ck, checkpoint_interval=2)
         ck.wait()
-        ref = KMeans(KMeansConfig(k=8, max_iters=25,
-                                  assignment="gemm_fused", seed=0)).fit(
-            x, centroids=c0)
-        rel = abs(float(inertia) - float(ref.inertia)) / float(ref.inertia)
+        ref = KMeans(8, max_iter=25, backend="gemm_fused",
+                     random_state=0).fit(x, centroids=c0)
+        rel = abs(float(inertia) - ref.inertia_) / ref.inertia_
         print("REL", rel)
         print("STEPS", ck.available_steps())
         st = ck.restore()
@@ -99,16 +99,16 @@ class TestDistributedKMeans:
         out = run_with_devices(f"""
         import jax, jax.numpy as jnp
         from repro.dist.kmeans_dist import DistributedKMeans
-        from repro.core.kmeans import KMeansConfig, KMeans
+        from repro.api import KMeans
         from repro.data.blobs import make_blobs
         from repro.ft.checkpoint import Checkpointer
 
         mesh = jax.make_mesh((8, 1), ("data", "model"))
         x, _ = make_blobs(2048, 16, 4, seed=9)
-        cfg = KMeansConfig(k=4, max_iters=12, tol=0.0,
-                           assignment="gemm_fused", seed=0)
-        dk = DistributedKMeans(cfg, mesh)
-        c0 = KMeans(cfg).init_centroids(x)
+        est = KMeans(4, max_iter=12, tol=0.0, backend="gemm_fused",
+                     random_state=0)
+        dk = DistributedKMeans(est, mesh)
+        c0 = est.init_centroids(x)
         xs = dk.shard_data(x)
         ck = Checkpointer(r'{tmp_path}', async_write=False)
         # run 1: "crashes" after 6 iterations (simulated by max_iters)

@@ -1,12 +1,12 @@
 """K-means system behaviour: convergence, strategy equivalence, FT modes,
-baselines, DMR, empty clusters."""
+DMR, empty clusters."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import get_backend
-from repro.core import (KMeans, KMeansConfig, FaultConfig, baselines, dmr)
+from repro.api import FaultPolicy, InjectionCampaign, KMeans, get_backend
+from repro.core import dmr
 from repro.core.kmeans import reseed_empty
 from repro.data.blobs import make_blobs
 from repro.kernels import ref
@@ -30,8 +30,7 @@ def _purity(assign, labels, k):
 
 
 class TestStrategiesAgree:
-    @pytest.mark.parametrize("strategy", ["naive", "gemm", "gemm_fused",
-                                          "abft_offline"])
+    @pytest.mark.parametrize("strategy", ["gemm_fused", "abft_offline"])
     def test_assignment_matches_reference(self, strategy, blobs):
         x, _ = blobs
         c = x[:16]
@@ -51,16 +50,16 @@ class TestStrategiesAgree:
 class TestLloydConvergence:
     def test_converges_and_recovers_clusters(self, blobs):
         x, labels = blobs
-        res = KMeans(KMeansConfig(k=8, max_iters=50, tol=1e-5,
-                                  assignment="gemm_fused", seed=0)).fit(x)
-        assert res.iterations < 50
-        assert _purity(res.assign, labels, 8) > 0.95
+        km = KMeans(8, max_iter=50, tol=1e-5, backend="gemm_fused",
+                    random_state=0).fit(x)
+        assert km.n_iter_ < 50
+        assert _purity(km.labels_, labels, 8) > 0.95
 
     def test_inertia_monotonically_nonincreasing(self, blobs):
         x, _ = blobs
         history = []
-        KMeans(KMeansConfig(k=8, max_iters=20, tol=0.0,
-                            assignment="gemm_fused", seed=0)).fit(
+        KMeans(8, max_iter=20, tol=0.0, backend="gemm_fused",
+               random_state=0).fit(
             x, on_iteration=lambda it, c, inertia, shift:
                 history.append(inertia))
         diffs = np.diff(history)
@@ -68,42 +67,31 @@ class TestLloydConvergence:
 
     def test_minibatch_mode(self, blobs):
         x, labels = blobs
-        res = KMeans(KMeansConfig(k=8, max_iters=30, minibatch=1024,
-                                  assignment="gemm_fused", seed=0)).fit(x)
-        assert _purity(res.assign, labels, 8) > 0.85
+        km = KMeans(8, max_iter=30, batch_size=1024, backend="gemm_fused",
+                    random_state=0).fit(x)
+        assert _purity(km.labels_, labels, 8) > 0.85
 
     def test_kmeanspp_beats_random_init(self, blobs):
         x, _ = blobs
-        r_pp = KMeans(KMeansConfig(k=8, max_iters=30, init="kmeans++",
-                                   assignment="gemm_fused", seed=2)).fit(x)
-        r_rand = KMeans(KMeansConfig(k=8, max_iters=30, init="random",
-                                     assignment="gemm_fused", seed=2)).fit(x)
-        assert float(r_pp.inertia) <= float(r_rand.inertia) * 1.5
+        r_pp = KMeans(8, max_iter=30, init="kmeans++", backend="gemm_fused",
+                      random_state=2).fit(x)
+        r_rand = KMeans(8, max_iter=30, init="random", backend="gemm_fused",
+                        random_state=2).fit(x)
+        assert r_pp.inertia_ <= r_rand.inertia_ * 1.5
 
 
 class TestFaultTolerance:
     def test_ft_kmeans_with_continuous_injection(self, blobs):
         """Paper's claim: correctness maintained under injections."""
         x, labels = blobs
-        cfg = KMeansConfig(k=8, max_iters=30, assignment="fused_ft", seed=0)
-        clean = KMeans(cfg).fit(x)
-        fault = KMeans(cfg).fit(x, fault=FaultConfig(rate=1.0))
-        assert int(fault.detected_errors) > 0
-        assert abs(float(fault.inertia) - float(clean.inertia)) \
-            <= abs(float(clean.inertia)) * 1e-3
-
-    def test_checkpoint_restart_baseline(self, blobs):
-        x, _ = blobs
-        # tol=0 -> fixed 25 iterations, so the 0.3/iter fault rate fires whp
-        cfg = KMeansConfig(k=8, max_iters=25, tol=0.0,
-                           assignment="gemm_fused", seed=0)
-        km = baselines.CheckpointRestartKMeans(cfg)
-        res, stats = km.fit(x, fault=FaultConfig(rate=0.3, seed=5))
-        assert stats["rollbacks"] >= 1          # errors happened
-        assert stats["wasted_iterations"] >= stats["rollbacks"]
-        clean, _ = baselines.CheckpointRestartKMeans(cfg).fit(x)
-        assert abs(float(res.inertia) - float(clean.inertia)) \
-            <= abs(float(clean.inertia)) * 0.02
+        clean = KMeans(8, max_iter=30, fault=FaultPolicy.correct(),
+                       backend="fused_ft", random_state=0).fit(x)
+        fault = KMeans(8, max_iter=30, fault=FaultPolicy.correct(
+            injection=InjectionCampaign(rate=1.0)), backend="fused_ft",
+            random_state=0).fit(x)
+        assert fault.detected_errors_ > 0
+        assert abs(fault.inertia_ - clean.inertia_) \
+            <= abs(clean.inertia_) * 1e-3
 
     def test_dmr_detects_mismatch(self):
         calls = [0]
@@ -130,14 +118,13 @@ class TestEdgeCases:
 
     def test_k_greater_than_unique_points_does_not_crash(self):
         x = jnp.ones((16, 4))
-        res = KMeans(KMeansConfig(k=8, max_iters=3,
-                                  assignment="gemm_fused", seed=0,
-                                  init="random")).fit(x)
-        assert res.centroids.shape == (8, 4)
+        km = KMeans(8, max_iter=3, backend="gemm_fused", random_state=0,
+                    init="random").fit(x)
+        assert km.cluster_centers_.shape == (8, 4)
 
     def test_single_feature_dim(self):
         x = jnp.asarray(np.random.default_rng(1).normal(size=(128, 1)),
                         jnp.float32)
-        res = KMeans(KMeansConfig(k=4, max_iters=10,
-                                  assignment="gemm_fused", seed=0)).fit(x)
-        assert res.centroids.shape == (4, 1)
+        km = KMeans(4, max_iter=10, backend="gemm_fused",
+                    random_state=0).fit(x)
+        assert km.cluster_centers_.shape == (4, 1)

@@ -271,9 +271,3 @@ class TestTrafficModel:
         assert one["x_read"] == 4096 * 128 * 4 * (512 // 128)
         with pytest.raises(ValueError):
             iteration_traffic(m, k, f, p, pipeline="lloyd")  # kind != pipeline
-
-    def test_bench_model_rows_expose_the_table(self):
-        from benchmarks.bench_stepwise import _traffic_rows
-        rows, traffic = _traffic_rows(16_384, 128, 128)
-        assert any(r.startswith("model_onepass_hbm") for r in rows)
-        assert traffic["one_pass"]["total"] < traffic["two_pass"]["total"]

@@ -282,8 +282,7 @@ class TestSelectionAndRegistry:
 
 class TestClusteredBlobsGenerator:
     def test_rows_are_cluster_contiguous_and_separated(self):
-        from benchmarks.common import clustered_blobs
-        x, centers = clustered_blobs(512, 16, 32, seed=0)
+        x, centers = autotune._clustered_data(512, 32, 16, jnp.float32)
         assert x.shape == (512, 16) and centers.shape == (32, 16)
         d = jnp.sum((x[:, None, :] - centers[None, :, :]) ** 2, axis=-1)
         labels = jnp.argmin(d, axis=1)
@@ -291,6 +290,6 @@ class TestClusteredBlobsGenerator:
         # and cluster-contiguous: labels are sorted
         assert jnp.array_equal(labels, jnp.sort(labels))
         assert int(labels[0]) == 0 and int(labels[-1]) == 31
-        # seeded: same seed, same data
-        x2, c2 = clustered_blobs(512, 16, 32, seed=0)
+        # seeded: same call, same data
+        x2, c2 = autotune._clustered_data(512, 32, 16, jnp.float32)
         assert jnp.array_equal(x, x2) and jnp.array_equal(centers, c2)

@@ -1,6 +1,6 @@
 """Seeding determinism + fused k-means++ parity contracts.
 
-What this suite pins (and why the bench rungs may trust the numbers):
+What this suite pins:
 
 * ``init_kmeanspp`` / ``init_random`` are pure functions of (key, x, k):
   the same seed gives bit-identical centroids across direct, re-jitted
