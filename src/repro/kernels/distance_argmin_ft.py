@@ -12,10 +12,11 @@ into the tile loop:
     e1 = ones, e2 = [1..b] (location encoding), at tile-local indices;
   * at the verification interval (the last feature step of each (m, k)
     tile — the paper's ``k % 256 == 0`` boundary maps to the tile
-    boundary on TPU), the observed checksums of the accumulator are
-    compared; a residual above threshold *locates* the corrupted element
-    via the e2/e1 ratio and the kernel corrects it in place, then runs the
-    fused min/argmin epilogue on the *corrected* tile;
+    boundary on TPU), the observed e1 checksums of the accumulator are
+    compared on every tile; only a tile whose residual exceeds the
+    threshold goes on to the e2 residuals, which *locate* the corrupted
+    element via the e2/e1 ratio, and the kernel corrects it in place. The
+    fused min/argmin epilogue then runs on the *corrected* tile;
   * an optional injection descriptor adds a delta into the accumulator
     mid-stream (a simulated SEU in the MXU output), exercising the whole
     detect->locate->correct path inside one kernel launch.
@@ -132,9 +133,11 @@ def inject_distance(acc_ref, inj_ref, m_idx, c_idx, f_idx):
         acc_ref[...] += jnp.where(mask, delta, 0.0)
 
 
-def verify_and_correct(acc, col1, col2, row1, row2, factor):
-    """Verification interval of one (bm, bk) accumulator tile: detect ->
-    locate -> correct. Returns (the corrected tile, detected (1, 1) bool).
+def detect(acc, col1, row1, factor):
+    """Detection at the verification interval of one (bm, bk) accumulator
+    tile: the e1 column and row residuals against the dtype-aware
+    threshold. Returns (res_col1 (1, bk), res_row1 (bm, 1), thr (1, 1),
+    flag (1, 1) int32, 1 where a residual exceeds the threshold).
 
     ``factor`` is the dtype-aware threshold factor (a trace-time constant:
     the grid is static). The magnitude scale comes from the *expected*
@@ -142,26 +145,35 @@ def verify_and_correct(acc, col1, col2, row1, row2, factor):
     from the possibly-corrupted accumulator: a corrupted-side scale lets a
     large delta inflate its own threshold past itself whenever the factor
     exceeds 1 (bf16 at wide tiles), self-masking exactly the errors worth
-    catching.
+    catching. The flag is int32 because Mosaic branches on an int32 (1, 1)
+    vector's element, not a bool one's.
     """
-    bm, bk = acc.shape
-    w_m = (_iota((bm, 1), 0) + 1).astype(jnp.float32)
-    w_k = (_iota((1, bk), 1) + 1).astype(jnp.float32)
     res_col1 = jnp.sum(acc, axis=0, keepdims=True) - col1           # (1, bk)
-    res_col2 = jnp.sum(w_m * acc, axis=0, keepdims=True) - col2
     res_row1 = jnp.sum(acc, axis=1, keepdims=True) - row1           # (bm, 1)
-    res_row2 = jnp.sum(w_k * acc, axis=1, keepdims=True) - row2
-
     scale = jnp.maximum(
         jnp.maximum(jnp.max(jnp.abs(col1), keepdims=True),
                     jnp.max(jnp.abs(row1), keepdims=True)), 1.0)    # (1, 1)
     thr = jnp.float32(factor) * scale
-    abs_col1, abs_row1 = jnp.abs(res_col1), jnp.abs(res_row1)
-    detected = jnp.logical_or(jnp.max(abs_col1, keepdims=True) > thr,
-                              jnp.max(abs_row1, keepdims=True) > thr)
+    detected = jnp.logical_or(jnp.max(jnp.abs(res_col1), keepdims=True) > thr,
+                              jnp.max(jnp.abs(res_row1), keepdims=True) > thr)
+    return res_col1, res_row1, thr, detected.astype(jnp.int32)
 
-    # Locate: argmax |column residual| gives j and delta; e2/e1 ratio of
-    # the row residuals gives i (and vice versa as fallback).
+
+def locate_and_correct(acc, res_col1, res_row1, col2, row2, thr):
+    """Location and correction of the one corrupted element of a tile that
+    ``detect`` flagged. Returns the corrected tile.
+
+    Argmax |column residual| gives j and delta; the e2/e1 ratio of the
+    residuals gives i (and vice versa as fallback). The e2 residuals serve
+    location alone, so they are computed here, on a flagged tile only.
+    """
+    bm, bk = acc.shape
+    w_m = (_iota((bm, 1), 0) + 1).astype(jnp.float32)
+    w_k = (_iota((1, bk), 1) + 1).astype(jnp.float32)
+    res_col2 = jnp.sum(w_m * acc, axis=0, keepdims=True) - col2     # (1, bk)
+    res_row2 = jnp.sum(w_k * acc, axis=1, keepdims=True) - row2     # (bm, 1)
+    abs_col1, abs_row1 = jnp.abs(res_col1), jnp.abs(res_row1)
+
     j = _first_argmax(abs_col1, 1)
     delta_col = _pick(res_col1, 1, j)
     i_direct = _first_argmax(abs_row1, 0)
@@ -179,12 +191,38 @@ def verify_and_correct(acc, col1, col2, row1, row2, factor):
 
     # Mosaic broadcasts a (1, 1) vector along one tile axis at a time, so
     # the correction is built as a (bm, 1) column, then spread over lanes.
-    # Subtracting 0.0 leaves every other element (and an undetected tile)
-    # bit-unchanged.
-    on_row = jnp.logical_and(_iota((bm, 1), 0) == i, detected)       # (bm, 1)
+    # Subtracting 0.0 leaves every other element bit-unchanged.
+    on_row = _iota((bm, 1), 0) == i                                  # (bm, 1)
     on_col = _iota((1, bk), 1) == j                                  # (1, bk)
     corr = jnp.where(on_col, jnp.where(on_row, delta, 0.0), 0.0)     # (bm, bk)
-    return acc - corr, detected
+    return acc - corr
+
+
+def verify_and_reduce(acc_ref, col1_ref, col2_ref, row1_ref, row2_ref,
+                      cn_ref, mind_ref, argmin_ref, det_ref, base_col,
+                      factor):
+    """Verification interval of one (bm, bk) accumulator tile, shared by
+    both FT kernels: detect on every tile, locate and correct in place
+    only on a flagged one (at most one tile in a detection interval holds
+    an error, so the common path skips the e2 residuals and the
+    correction), then the min/argmin epilogue on the corrected tile,
+    folded into the running minimum. Adds the flag to ``det_ref``.
+
+    Each step reads the tile from ``acc_ref`` afresh: a tile loaded once
+    and held across the branch does not fit the vector registers, and its
+    spills cost more bundles than the reloads (compile for a v5e)."""
+    res_col1, res_row1, thr, flag = detect(
+        acc_ref[...], col1_ref[...], row1_ref[...], factor)
+    det_ref[0] += flag
+
+    @pl.when(flag[0, 0] > 0)
+    def _locate_and_correct():
+        acc_ref[...] = locate_and_correct(acc_ref[...], res_col1, res_row1,
+                                          col2_ref[...], row2_ref[...], thr)
+
+    local_min, local_arg = tile_min_argmin(acc_ref[...], cn_ref[...],
+                                           base_col)
+    fold_min(mind_ref, argmin_ref, local_min, local_arg)
 
 
 def _kernel(inj_ref, x_ref, c_ref, cn_ref,
@@ -220,18 +258,12 @@ def _kernel(inj_ref, x_ref, c_ref, cn_ref,
     accumulate_checksums(x, c, col1_ref, col2_ref, row1_ref, row2_ref)
     inject_distance(acc_ref, inj_ref, m_idx, c_idx, f_idx)
 
-    # --- verification interval: detect -> locate -> correct -> reduce -------
+    # --- verification interval: detect (-> locate -> correct) -> reduce -----
     @pl.when(f_idx == nf - 1)
     def _verify_and_reduce():
-        acc, detected = verify_and_correct(
-            acc_ref[...], col1_ref[...], col2_ref[...], row1_ref[...],
-            row2_ref[...], threshold_factor(nf * bf, x_ref.dtype))
-        acc_ref[...] = acc
-        det_ref[0] += detected.astype(jnp.int32)
-
-        # --- fused epilogue on the corrected tile ---------------------------
-        local_min, local_arg = tile_min_argmin(acc, cn_ref[...], c_idx * bk)
-        fold_min(mind_ref, argmin_ref, local_min, local_arg)
+        verify_and_reduce(acc_ref, col1_ref, col2_ref, row1_ref, row2_ref,
+                          cn_ref, mind_ref, argmin_ref, det_ref, c_idx * bk,
+                          threshold_factor(nf * bf, x_ref.dtype))
 
 
 def no_injection() -> jax.Array:

@@ -11,10 +11,11 @@ layers ride the same streamed tiles:
   * **distance GEMM** (compute-bound): the e1/e2 column/row checksums of
     D = X C^T accumulate from the VMEM-resident tiles exactly as in
     ``distance_argmin_ft``; at the verification interval (last feature
-    step of each (m, k) tile) a residual above the dtype-aware threshold
-    locates the corrupted accumulator element via the e2/e1 ratio and the
-    kernel corrects it in place — the min/argmin epilogue and the update
-    epilogue both run on the *corrected* accumulator;
+    step of each (m, k) tile) an e1 residual above the dtype-aware
+    threshold sends that tile, and only that one, on to location via the
+    e2/e1 ratio and correction in place (``verify_and_reduce``) — the
+    min/argmin epilogue and the update epilogue both run on the
+    *corrected* accumulator;
   * **update epilogue** (the one-hot MXU product): alongside each row
     tile's partial per-cluster sums/counts the kernel emits their
     *expected* e1/e2 column checksums, computed from the argmin/valid
@@ -55,12 +56,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels._mosaic import compiler_params, mxu_dot
-from repro.kernels.distance_argmin import MIN_INIT, fold_min, tile_min_argmin
+from repro.kernels.distance_argmin import MIN_INIT
 from repro.kernels.distance_argmin_ft import (_f32_from_bits,
                                               accumulate_checksums,
                                               inject_distance,
                                               threshold_factor,
-                                              verify_and_correct)
+                                              verify_and_reduce)
 from repro.kernels.lloyd_step import (STASH_SLOTS, _emit_update,
                                       _stash_dma_start, _stash_dma_wait_last)
 
@@ -168,18 +169,12 @@ def _kernel(meta_ref, inj_ref, x_ref, c_ref, cn_ref,
     accumulate_checksums(x, c, col1_ref, col2_ref, row1_ref, row2_ref)
     inject_distance(acc_ref, inj_ref, m_idx, c_idx, f_idx)
 
-    # --- verification interval: detect -> locate -> correct -> reduce -------
+    # --- verification interval: detect (-> locate -> correct) -> reduce -----
     @pl.when(f_idx == nf - 1)
     def _verify_and_reduce():
-        acc, detected = verify_and_correct(
-            acc_ref[...], col1_ref[...], col2_ref[...], row1_ref[...],
-            row2_ref[...], threshold_factor(nf * bf, x_ref.dtype))
-        acc_ref[...] = acc
-        det_ref[0] += detected.astype(jnp.int32)
-
-        # --- fused min/argmin epilogue on the corrected tile ----------------
-        local_min, local_arg = tile_min_argmin(acc, cn_ref[...], c_idx * bk)
-        fold_min(mind_ref, argmin_ref, local_min, local_arg)
+        verify_and_reduce(acc_ref, col1_ref, col2_ref, row1_ref, row2_ref,
+                          cn_ref, mind_ref, argmin_ref, det_ref, c_idx * bk,
+                          threshold_factor(nf * bf, x_ref.dtype))
 
     # --- protected update epilogue: argmin for this row tile is final -------
     @pl.when(jnp.logical_and(c_idx == nk - 1, f_idx == nf - 1))

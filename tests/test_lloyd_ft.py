@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.api import (AutotuneCache, BackendCapabilityError, FaultPolicy,
                        InjectionCampaign, KMeans, get_backend, list_backends)
@@ -19,7 +20,9 @@ from repro.core.autotune import feasible, model_score, select_params
 from repro.core.fault import (draw_step_injection, no_step_injection,
                               planned_injections)
 from repro.data.blobs import make_blobs
+from repro.kernels import distance_argmin_ft as daft
 from repro.kernels import ops, ref
+from repro.kernels.distance_argmin import MIN_INIT
 from repro.kernels.lloyd_step_ft import INJ_LEN, make_injection, no_injection
 from repro.kernels.ops import KernelParams
 
@@ -150,6 +153,115 @@ class TestInjectionCorrection:
         assert int(both[0]) == 1 and int(both[7]) == 1
         only_u = make_injection(update=(1, 4, 5, 6.0))
         assert int(only_u[0]) == 0 and int(only_u[7]) == 1
+
+
+def _run_ft(kernel, x, c, params, inj=None):
+    """Both FT kernels' outputs by name: the one-pass Lloyd step, and the
+    FT assignment alone (no sums or counts)."""
+    if kernel == "lloyd_ft":
+        am, md, sums, cnt, det = ops.fused_lloyd_ft(x, c, params, inj=inj,
+                                                    interpret=True)
+        return dict(am=am, md=md, sums=sums, cnt=cnt, det=det)
+    am, md, det = ops.fused_assign_ft(x, c, params, inj=inj, interpret=True)
+    return dict(am=am, md=md, det=det)
+
+
+class TestLazyLocate:
+    """Detection runs on every distance tile; location and correction only
+    on a flagged one. On integer data every product, checksum and residual
+    is exact in f32, so a corrected tile equals the clean tile bit for bit
+    and every output of an injected run must equal the clean run's, the
+    minimum distance included: a location or correction off by one element
+    or one bit shows."""
+
+    PARAMS = KernelParams(block_m=256, block_k=128, block_f=128)
+    M, K, F = 512, 384, 128            # grid (2, 3, 1): one feature step
+    ARGMIN_ROW = 77
+
+    @pytest.fixture(scope="class")
+    def data(self):
+        kx, kc = jax.random.split(jax.random.PRNGKey(21))
+        x = jax.random.randint(kx, (self.M, self.F), -3, 4).astype(jnp.float32)
+        c = jax.random.randint(kc, (self.K, self.F), -3, 4).astype(jnp.float32)
+        return x, c
+
+    @pytest.fixture(scope="class", params=["lloyd_ft", "assign_ft"])
+    def clean(self, request, data):
+        out = _run_ft(request.param, *data, self.PARAMS)
+        assert int(out["det"]) == 0
+        return request.param, out
+
+    def _descriptor(self, kernel, m_tile, c_tile, row, col, delta):
+        if kernel == "lloyd_ft":
+            return make_injection(distance=(m_tile, c_tile, 0, row, col,
+                                             delta))
+        return daft.make_injection(m_tile, c_tile, 0, row, col, delta)
+
+    @pytest.mark.parametrize("where", [
+        (0, 0, 0, 0),                  # first row and column of a tile
+        (1, 0, 255, 127),              # last row and column
+        (0, 2, 0, 127),                # c_idx > 0, first row, last column
+        (1, 1, 255, 0),                # c_idx > 0, last row, first column
+        "argmin",                      # the element that is its row's min
+    ])
+    @pytest.mark.parametrize("delta", [2048.0, -2048.0])
+    def test_seu_corrected_bit_equal_to_clean(self, data, clean, where,
+                                              delta):
+        kernel, want = clean
+        if where == "argmin":
+            row = self.ARGMIN_ROW
+            col = int(want["am"][row])
+            where = (row // self.PARAMS.block_m, col // self.PARAMS.block_k,
+                     row % self.PARAMS.block_m, col % self.PARAMS.block_k)
+        inj = self._descriptor(kernel, *where, delta)
+        got = _run_ft(kernel, *data, self.PARAMS, inj=inj)
+        assert int(got["det"]) == 1
+        for name in want:
+            if name != "det":
+                np.testing.assert_array_equal(np.asarray(got[name]),
+                                              np.asarray(want[name]), name)
+
+    def test_clean_tile_with_negative_zeros_passes_bit_unchanged(self):
+        """A tile that detects nothing skips location and correction: the
+        accumulator holds its -0.0 entries bit for bit (an added +0.0
+        correction would turn them into +0.0), and the min/argmin comes
+        from the tile as it was."""
+        bm, bk = 64, 128
+        kx, kc = jax.random.split(jax.random.PRNGKey(22))
+        acc = jax.random.randint(kx, (bm, bk), -40, 41).astype(jnp.float32)
+        acc = jnp.where(acc == 0, -0.0, acc)
+        assert int(jnp.sum(jnp.signbit(acc) & (acc == 0))) > 0
+        cn = jax.random.randint(kc, (1, bk), 0, 200).astype(jnp.float32)
+        w_m = jnp.arange(1, bm + 1, dtype=jnp.float32)[:, None]
+        w_k = jnp.arange(1, bk + 1, dtype=jnp.float32)[None, :]
+        sums = (jnp.sum(acc, 0, keepdims=True),
+                jnp.sum(w_m * acc, 0, keepdims=True),
+                jnp.sum(acc, 1, keepdims=True),
+                jnp.sum(w_k * acc, 1, keepdims=True))
+
+        def kernel(acc_in, col1, col2, row1, row2, cn_ref,
+                   acc_ref, mind_ref, argmin_ref, det_ref):
+            acc_ref[...] = acc_in[...]
+            mind_ref[...] = jnp.full_like(mind_ref, MIN_INIT)
+            argmin_ref[...] = jnp.zeros_like(argmin_ref)
+            det_ref[...] = jnp.zeros_like(det_ref)
+            daft.verify_and_reduce(acc_ref, col1, col2, row1, row2, cn_ref,
+                                   mind_ref, argmin_ref, det_ref, 0,
+                                   checksum.threshold_factor(self.F))
+
+        out, mind, am, det = pl.pallas_call(
+            kernel, interpret=True,
+            out_shape=[jax.ShapeDtypeStruct((bm, bk), jnp.float32),
+                       jax.ShapeDtypeStruct((bm, 1), jnp.float32),
+                       jax.ShapeDtypeStruct((bm, 1), jnp.int32),
+                       jax.ShapeDtypeStruct((1, 1, 1), jnp.int32)],
+        )(acc, *sums, cn)
+        assert int(det[0, 0, 0]) == 0
+        np.testing.assert_array_equal(np.asarray(out).view(np.int32),
+                                      np.asarray(acc).view(np.int32))
+        d = np.asarray(cn) - 2.0 * np.asarray(acc)
+        np.testing.assert_array_equal(np.asarray(mind)[:, 0], d.min(axis=1))
+        np.testing.assert_array_equal(np.asarray(am)[:, 0], d.argmin(axis=1))
 
 
 class TestDtypeThresholds:
