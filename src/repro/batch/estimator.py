@@ -161,13 +161,19 @@ class BatchedKMeans:
         Iterations per device-resident ``lax.scan`` chunk; the host
         observes convergence only at chunk boundaries.
     compute_dtype : {"float32", "bfloat16", "float16"}, default="float32"
-        Kernel compute dtype; casts happen at the kernel boundary and the
-        stored ``cluster_centers_`` stay f32 (same contract as
-        :class:`repro.api.KMeans`).
+        The arithmetic. Centroids are cast to it at the kernel boundary
+        and the stored ``cluster_centers_`` stay f32 (same contract as
+        :class:`repro.api.KMeans`). Rows are cast to it too, but rows
+        stored in bf16 under ``"float32"`` stay bf16: the distance product
+        takes the f32 centroids' three exact bf16 slices and the update
+        one exact pass, so the arithmetic is f32 and no f32 copy of the
+        rows is made (``ops.rows_dtype``).
     random_state : int, default=0
         Base seed. Problem ``b`` uses key ``PRNGKey(random_state + b)``
         for init (reseeding draws nothing), so a batched fit is
         bit-identical to B single-problem fits seeded ``random_state + b``.
+        Seeds of any size work (the key's high word holds the bits above
+        32).
 
     Attributes
     ----------
@@ -188,6 +194,11 @@ class BatchedKMeans:
         rows, the zero rows that pad them to whole row tiles (and a
         stacked block to the tile grid), and the row tiles. Counters, read
         only; the XLA analogue pads nothing and has no tiles (0, 0).
+    feature_lanes_, distance_passes_ : int
+        The same launches' padded feature width (F itself on the XLA
+        analogue) and bf16 MXU passes per distance product: 6 for f32
+        rows, 3 for bf16 rows at f32 arithmetic, 1 for 2-byte arithmetic
+        (``ops.distance_passes``).
 
     See Also
     --------
@@ -270,6 +281,8 @@ class BatchedKMeans:
         self.rows_valid_: int = 0
         self.rows_padded_: int = 0
         self.row_tiles_: int = 0
+        self.feature_lanes_: int = 0
+        self.distance_passes_: int = 0
 
     # ------------------------------------------------------------------
     # internals
@@ -303,14 +316,26 @@ class BatchedKMeans:
         return a if a.dtype == self.compute_dtype else \
             a.astype(self.compute_dtype)
 
+    def _cast_rows(self, x: jax.Array) -> jax.Array:
+        """Rows in the dtype the kernels take them in
+        (:func:`~repro.kernels.ops.rows_dtype`)."""
+        dtype = ops.rows_dtype(x.dtype, self.compute_dtype)
+        return x if x.dtype == dtype else x.astype(dtype)
+
     def _problem_keys(self, bsz: int) -> jax.Array:
         """Per-problem RNG keys: problem ``b`` seeds from
         ``random_state + b`` so its draws are independent of B (the
-        batched-vs-loop bit-equality hinges on this)."""
-        return jax.vmap(jax.random.PRNGKey)(
-            self.random_state + jnp.arange(bsz))
+        batched-vs-loop bit-equality hinges on this). The keys are
+        ``PRNGKey``'s words, built on the host: the seed's low 32 bits,
+        and above them its bits past 32 (0 below 2**32 and for negative
+        seeds, as an int32 seed gives), so a seed of any size works."""
+        seeds = [self.random_state + b for b in range(bsz)]
+        words = [((max(s, 0) >> 32) & 0xFFFFFFFF, s & 0xFFFFFFFF)
+                 for s in seeds]
+        words = np.asarray(words, np.uint32)  # analysis: allow=host-sync
+        return jnp.asarray(words)
 
-    def _resolve_params(self, bsz: int, n: int, f: int):
+    def _resolve_params(self, bsz: int, n: int, f: int, rows_dtype=None):
         if not self._backend.takes_params:
             return None
         if self.params is not None:
@@ -320,7 +345,7 @@ class BatchedKMeans:
                                         kind=self._backend.kernel_kind,
                                         dtype=self.compute_dtype, batch=bsz)
         return ops.clamp_params(n, self.n_clusters, f, p,
-                                dtype=self.compute_dtype)
+                                dtype=rows_dtype or self.compute_dtype)
 
     def init_centroids(self, x: jax.Array,
                        keys: Optional[jax.Array] = None, *,
@@ -425,17 +450,21 @@ class BatchedKMeans:
         ``params`` were given."""
         bsz, n, f = x.shape if lengths is None else (
             len(lengths), max(lengths), x.shape[1])
-        params = self._resolve_params(bsz, n, f)
+        x = self._cast_rows(x)
+        params = self._resolve_params(bsz, n, f, x.dtype)
         if (params is not None and self.params is None
                 and len(set(lengths or (n,))) > 1):
             params = ops.ragged_params(params, n, self.n_clusters, f,
-                                       self.compute_dtype)
+                                       x.dtype, self.compute_dtype)
         with obs.span("plan" if lengths is None else "pack"):
             # every problem padded to whole row tiles, once per fit
-            plan = ops.plan_data_batched(self._cast(x), params, lengths)
+            plan = ops.plan_data_batched(x, params, lengths)
         self.rows_valid_, self.rows_padded_ = (plan.rows_valid,
                                                plan.rows_padded)
         self.row_tiles_ = int(plan.tile_prob.shape[0])
+        self.feature_lanes_ = int(plan.xp.shape[1])
+        self.distance_passes_ = ops.distance_passes(plan.xp.dtype,
+                                                    self.compute_dtype)
         return plan, params
 
     def _fit_chunks(self, x: jax.Array, centroids: jax.Array,
@@ -480,16 +509,17 @@ class BatchedKMeans:
 
     def _assign(self, x: jax.Array):
         bsz, n, f = x.shape
-        params = self._resolve_params(bsz, n, f)
+        params = self._resolve_params(
+            bsz, n, f, ops.rows_dtype(x.dtype, self.compute_dtype))
         key = ("assign", params)
         if key not in self._step_cache:
             backend = self._backend
-            cast = self._cast
+            cast, cast_rows = self._cast, self._cast_rows
             if backend.takes_params:
-                fn = jax.jit(lambda x, c: backend(cast(x), cast(c),
+                fn = jax.jit(lambda x, c: backend(cast_rows(x), cast(c),
                                                   params=params)[:2])
             else:
-                fn = jax.jit(lambda x, c: backend(cast(x), cast(c))[:2])
+                fn = jax.jit(lambda x, c: backend(cast_rows(x), cast(c))[:2])
             self._step_cache[key] = fn
         return self._step_cache[key](x, self.cluster_centers_)
 

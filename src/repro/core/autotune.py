@@ -36,6 +36,7 @@ the pair makes the chosen template explicit to callers and to the cache.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import itertools
 import time
@@ -46,7 +47,8 @@ import jax.numpy as jnp
 
 from repro import hw as _hw
 from repro.kernels.ops import (PLAN_KINDS, VARIANTS, KernelParams, clamp_params,  # noqa: F401 — VARIANTS re-exported as selection vocabulary
-                               init_vmem_bytes, int8_vmem_bytes,
+                               feature_block, init_vmem_bytes,
+                               int8_vmem_bytes,
                                lloyd_batched_vmem_bytes,
                                lloyd_ft_vmem_bytes, lloyd_vmem_bytes,
                                pruned_vmem_bytes, sublane_align, _round_up)
@@ -143,10 +145,12 @@ def feasible(p: KernelParams, dtype=jnp.float32, *, kind: str = "assign",
         return False
     if kind == "batched":
         # one problem's tiles resident at a time; padded K is the single
-        # centroid tile by construction, so block_k never enters
+        # centroid tile by construction, so block_k never enters. The
+        # feature tile is the one the launch takes (F = 576: 640 lanes)
         if shape is None:
             return False
         _, k, f = shape
+        p = dataclasses.replace(p, block_f=feature_block(f, p.block_f))
         return lloyd_batched_vmem_bytes(p, k, f, dtype) <= VMEM_BUDGET
     if variant == "smallk":
         if kind == "lloyd_ft":

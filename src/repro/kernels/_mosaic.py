@@ -6,9 +6,10 @@ budget the autotuner checks each kernel's VMEM model against
 same number. Mosaic's default scoped limit is smaller than that budget.
 
 Every floating-point MXU product of the k-means kernels goes through
-:func:`mxu_dot`, which keeps f32 operands at f32 precision; the one-hot
-products of the centroid updates go through :func:`onehot_dot`, which is
-exact in three bf16 passes.
+:func:`mxu_dot`, which keeps f32 operands at f32 precision (bf16 rows
+against f32 centroids in three exact bf16 passes); the one-hot products of
+the centroid updates go through :func:`onehot_dot`, which is exact in
+three bf16 passes.
 """
 from __future__ import annotations
 
@@ -36,7 +37,17 @@ def mxu_dot(a: jax.Array, b: jax.Array, contract: tuple[int, int]
     an ABFT checksum disagree with the product it verifies, so every clean
     tile reads as corrupted. bf16 operands multiply exactly at either
     setting, and on the CPU (interpret mode) the precision changes
-    nothing."""
+    nothing.
+
+    A bf16 ``a`` against an f32 ``b`` (rows stored in bf16, centroids at
+    f32) takes ``b``'s three exact bf16 slices (:func:`bf16_slices`), one
+    bf16 pass each, summed ``(P_hi + P_mid) + P_lo`` in f32. Every product
+    is exact: these are the three nonzero terms that ``HIGHEST`` computes
+    on ``a`` upcast to f32 (its other three multiply ``a``'s zero mid and
+    lo parts), in half its passes."""
+    if a.dtype == jnp.bfloat16 and b.dtype == jnp.float32:
+        hi, mid, lo = (mxu_dot(a, s, contract) for s in bf16_slices(b))
+        return (hi + mid) + lo
     full = jnp.float32 in (a.dtype, b.dtype)
     return jax.lax.dot_general(
         a, b, (((contract[0],), (contract[1],)), ((), ())),
@@ -50,15 +61,12 @@ def onehot_dot(onehot: jax.Array, x: jax.Array, contract: tuple[int, int]
     the sum accumulated in f32.
 
     A 2-byte ``x`` takes one pass against the one-hot in its dtype. An f32
-    ``x`` is split exactly into three bf16 slices (:func:`bf16_slices`) and
-    takes one bf16 MXU pass per slice, summed ``(S_hi + S_mid) + S_lo``.
-    ``Precision.HIGHEST`` would take six, three of them against the
-    one-hot's bf16 mid and lo parts, which are zero."""
-    if x.dtype != jnp.float32:
-        return mxu_dot(onehot.astype(x.dtype), x, contract)
-    onehot = onehot.astype(jnp.bfloat16)
-    hi, mid, lo = (mxu_dot(onehot, s, contract) for s in bf16_slices(x))
-    return (hi + mid) + lo
+    ``x`` takes :func:`mxu_dot`'s three exact bf16 passes against the
+    bf16 one-hot, summed ``(S_hi + S_mid) + S_lo``. ``Precision.HIGHEST``
+    would take six, three of them against the one-hot's bf16 mid and lo
+    parts, which are zero."""
+    dtype = jnp.bfloat16 if x.dtype == jnp.float32 else x.dtype
+    return mxu_dot(onehot.astype(dtype), x, contract)
 
 
 def bf16_slices(x: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:

@@ -48,7 +48,12 @@ single centroid tile), so every grid step is the ``smallk`` epilogue,
 min/argmin written directly and the one-hot update emitted in the same
 step. One launch amortizes B dispatches. Problems of one row count (a
 stack) launch as ``lloyd_step_batched``, problems of different row counts
-as :func:`lloyd_step_ragged`: one kernel under two names.
+as :func:`lloyd_step_ragged`: one kernel under two names. Its rows may be
+bf16 under f32 centroids (rows stored in bf16, f32 arithmetic): the
+distance product then takes the centroids' three exact bf16 slices
+(``_mosaic.mxu_dot``, split per centroid tile in the kernel: splitting
+once per iteration in the wrapper measured the same on a v5e), the stash
+holds bf16 and the one-hot update is one exact pass.
 """
 from __future__ import annotations
 
@@ -240,7 +245,8 @@ def _kernel_batched(tile_prob_ref, tile_rows_ref, tile_slot_ref, x_ref,
     tile_rows_ref: (T,)         SMEM (scalar prefetch) — valid rows of t
     tile_slot_ref: (T,)         SMEM (scalar prefetch) — partials row of t
     x_ref        : (bm, bf)     the tile's packed rows
-    c_ref        : (1, kp, bf)  its problem's (single) centroid tile
+    c_ref        : (1, kp, bf)  its problem's (single) centroid tile, in
+                                the rows' dtype or f32 under bf16 rows
     cn_ref       : (1, 1, kp)   its problem's centroid squared norms
     mind_ref     : (1, 1, bm)   min distance, one lane-dense row per tile
     argmin_ref   : (1, 1, bm)   argmin, the same layout

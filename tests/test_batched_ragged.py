@@ -4,7 +4,9 @@ the ``lloyd_batched`` / ``lloyd_batched_xla`` backends.
 
 Each problem is judged on its own rows against ``kernels/ref.py``; equal
 lengths must equal the stacked fit bit for bit; padded rows must change
-nothing. Pallas kernels run interpret=True (kernel bodies in Python).
+nothing. Rows stored in bf16 at f32 arithmetic (``Bf16Rows`` cases) are
+judged on their rows upcast to f32. Pallas kernels run interpret=True
+(kernel bodies in Python).
 """
 import dataclasses
 
@@ -24,11 +26,39 @@ BACKENDS = ["lloyd_batched", "lloyd_batched_xla"]
 LENGTHS = [(5, 130, 77), (64, 128, 1, 200), (300,), (100, 120, 30, 60)]
 TILES = KernelParams(64, 128, 128)
 F, K = 12, 4
+F_WIDE = 300     # 3 lane groups: pads to 384, in 128-lane steps at TILES
 
 
-def _rows(lengths, seed=0):
-    return jnp.concatenate([make_blobs(n, F, K, seed=seed + i)[0]
+@dataclasses.dataclass(frozen=True)
+class Bf16Rows:
+    """A case of ragged rows stored in bf16, F_WIDE features, fit at f32
+    against centroids that bf16 does not hold (so all three bf16 slices
+    of each centroid are nonzero)."""
+    lengths: tuple
+
+    def __str__(self):
+        return f"bf16-F{F_WIDE}-{self.lengths}"
+
+
+WIDE = [Bf16Rows((5, 130, 77)), Bf16Rows((64, 128, 1, 200))]
+
+
+def _rows(lengths, seed=0, f=F):
+    return jnp.concatenate([make_blobs(n, f, K, seed=seed + i)[0]
                             for i, n in enumerate(lengths)])
+
+
+def _case(case, seed):
+    """(lengths, rows, centroids) of a case: f32 rows of F features and K
+    of their rows (seed + 1), or a Bf16Rows case's bf16 rows with f32
+    centroids off the bf16 grid."""
+    if not isinstance(case, Bf16Rows):
+        rows = _rows(case, seed)
+        return case, rows, _centroids(rows, case, seed + 1)
+    rows = _rows(case.lengths, seed, F_WIDE).astype(jnp.bfloat16)
+    c = _centroids(rows, case.lengths, seed + 1).astype(jnp.float32)
+    return case.lengths, rows, c + 0.3 * jax.random.normal(
+        jax.random.PRNGKey(seed), c.shape)
 
 
 def _split(rows, lengths):
@@ -58,18 +88,20 @@ def _step(backend, plan, c):
     return am, md, sums, counts
 
 
-@pytest.mark.parametrize("lengths", LENGTHS, ids=str)
+@pytest.mark.parametrize("lengths", LENGTHS + WIDE, ids=str)
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_step_matches_reference_per_problem(backend, lengths):
     """One ragged step: each problem's labels, distances, sums and counts
     are those of ``kernels/ref.py`` on that problem's rows alone; slots
-    past a problem's rows hold 0."""
-    rows = _rows(lengths)
-    c = _centroids(rows, lengths)
+    past a problem's rows hold 0. Tolerances are f32's for bf16 rows too:
+    every product of the three-pass distance and of the one-pass update
+    is exact, and only the order of f32 sums differs."""
+    lengths, rows, c = _case(lengths, 0)
     am, md, sums, counts = _step(backend, _plan(backend, rows, lengths), c)
     n_max = max(lengths)
     assert am.shape == md.shape == (len(lengths), n_max)
     for b, (xb, n) in enumerate(zip(_split(rows, lengths), lengths)):
+        xb = xb.astype(jnp.float32)
         md_r, am_r, sums_r, counts_r = ref.lloyd_step(xb, c[b])
         np.testing.assert_array_equal(np.asarray(am[b, :n]),
                                       np.asarray(am_r))
@@ -88,19 +120,21 @@ def test_step_matches_reference_per_problem(backend, lengths):
         assert not np.any(np.asarray(md[b, n:]))
 
 
-@pytest.mark.parametrize("lengths", LENGTHS, ids=str)
+@pytest.mark.parametrize("lengths", LENGTHS + WIDE, ids=str)
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_fit_matches_reference_per_problem(backend, lengths):
     """A ragged fit of one iteration: packed labels, inertia and new
-    centroids of every problem from its own rows."""
-    rows = _rows(lengths, seed=5)
-    c = _centroids(rows, lengths, seed=6)
+    centroids of every problem from its own rows. Inertia is a sum of f32
+    distances, which round on the scale of |x|^2 + |c|^2; centroids are
+    means of exact f32 sums, which round only in their order."""
+    lengths, rows, c = _case(lengths, 5)
     bkm = BatchedKMeans(K, max_iter=1, backend=backend, params=TILES,
                         random_state=2).fit(rows, lengths=lengths,
                                             centroids=c)
     assert bkm.labels_.shape == (sum(lengths),)
     labels = _split(bkm.labels_, lengths)
     for b, xb in enumerate(_split(rows, lengths)):
+        xb = xb.astype(jnp.float32)
         _, am_r, sums_r, counts_r = ref.lloyd_step(xb, c[b])
         np.testing.assert_array_equal(np.asarray(labels[b]),
                                       np.asarray(am_r))
@@ -269,3 +303,132 @@ def test_distributed_fit_takes_no_lengths():
     from repro.dist.kmeans_dist import DistributedKMeans
     with pytest.raises(TypeError, match="lengths"):
         DistributedKMeans.fit(object(), _rows((10,)), lengths=[10])
+
+
+@pytest.mark.parametrize("rows,compute,f,lanes,passes", [
+    ("bfloat16", "float32", F_WIDE, 384, 3),
+    ("float32", "float32", 128, 128, 6),
+    ("bfloat16", "bfloat16", F_WIDE, 384, 1)])
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_rows_keep_storage_dtype_and_counters(backend, rows, compute, f,
+                                              lanes, passes, monkeypatch):
+    """Rows stored in bf16 stay bf16 in the plan under f32 arithmetic (no
+    f32 copy is made); the counters give the padded feature width (F on
+    the XLA analogue) and the bf16 passes of each distance product."""
+    plans = []
+    orig = ops.plan_data_batched
+
+    def spy(x, params=None, lengths=None):
+        plans.append((x.dtype, orig(x, params, lengths)))
+        return plans[-1][1]
+    monkeypatch.setattr(ops, "plan_data_batched", spy)
+    lengths = (5, 130, 77)
+    x = _rows(lengths, 3, f).astype(rows)
+    # block_f 512 asks for more than 300 features need: one 384-lane step
+    bkm = BatchedKMeans(K, max_iter=1, backend=backend, compute_dtype=compute,
+                        params=KernelParams(64, 128, 512)).fit(
+        x, lengths=lengths)
+    (given, plan), = plans
+    assert given == plan.xp.dtype == jnp.dtype(rows)
+    assert bkm.cluster_centers_.dtype == jnp.float32
+    pallas = backend == "lloyd_batched"
+    assert bkm.feature_lanes_ == plan.xp.shape[1] == (lanes if pallas else f)
+    assert bkm.distance_passes_ == passes
+
+
+def test_feature_tiles_pad_to_lane_groups():
+    """Padded F is round_up(F, 128) and the feature tile divides it: 300
+    features pad to 384 (the halving rule gave 512), 576 to 640 in five
+    128-lane steps or one of 640; power-of-two widths are as before."""
+    def tile(f, block_f):
+        return ops.clamp_params(4096, 256, f, KernelParams(1024, 128,
+                                                           block_f)).block_f
+    assert tile(300, 512) == 384 and tile(300, 128) == 128
+    assert tile(576, 512) == 128 and tile(576, 1024) == 640
+    assert [tile(f, 512) for f in (8, 12, 128, 200, 1000)] == [
+        128, 128, 128, 256, 512]
+    plan = ops.plan_data_batched(_rows((5, 130), 0, F_WIDE),
+                                 ops.clamp_params(130, K, F_WIDE,
+                                                  ops.DEFAULT_PARAMS),
+                                 (5, 130))
+    assert plan.xp.shape[1] == 384
+    # the VMEM model counts bf16 rows at 2 bytes: their double-buffered
+    # tile and their stash, beside an f32 centroid tile
+    p = KernelParams(4096, 128, 640)
+    f32 = ops.lloyd_batched_vmem_bytes(p, 256, 576, jnp.float32)
+    mixed = ops.lloyd_batched_vmem_bytes(p, 256, 576, jnp.float32,
+                                         rows_dtype=jnp.bfloat16)
+    assert f32 - mixed == 2 * (2 * 4096 * 640 + 4096 * 640)
+    # a ragged launch takes the whole padded width in one step where it
+    # fits VMEM (128 lanes for the KV-key cell's F, as before)
+    q = KernelParams(1024, 128, 128)
+    assert ops.ragged_params(q, 202_141, 256, 576, jnp.bfloat16).block_f \
+        == 640
+    assert ops.ragged_params(q, 105_545, 256, 128).block_f == 128
+
+
+def test_three_slice_product_is_exact():
+    """bf16 rows times f32 centroids in three bf16 passes: the slices sum
+    to the centroids exactly, each partial product is exact in f32, and
+    the product lies within f32 summation rounding of the float64 one,
+    over 2**9 closer than one bf16 pass."""
+    from repro.kernels._mosaic import bf16_slices, mxu_dot
+    rng = np.random.default_rng(0)
+    a = jnp.asarray(rng.normal(size=(64, 300)) * 8.0, jnp.bfloat16)
+    b = jnp.asarray(rng.normal(size=(32, 300)) * np.exp2(
+        rng.integers(-6, 6, size=(32, 300))), jnp.float32)
+    a64, b64 = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    slices = [np.asarray(s, np.float64) for s in bf16_slices(b)]
+    np.testing.assert_array_equal(sum(slices), b64)
+    for s in slices:    # 8 x 8 significant bits: exact in f32
+        prod = a64[:, None, :] * s[None, :, :]
+        np.testing.assert_array_equal(prod.astype(np.float32), prod)
+    exact = a64 @ b64.T
+    bound = 300 * 2.0 ** -24 * (np.abs(a64) @ np.abs(b64).T)
+    err = np.abs(np.asarray(mxu_dot(a, b, (1, 1)), np.float64) - exact)
+    assert np.all(err <= bound)
+    one_pass = np.asarray(mxu_dot(a, b.astype(jnp.bfloat16), (1, 1)),
+                          np.float64)
+    assert np.max(err) * 2.0 ** 9 < np.max(np.abs(one_pass - exact))
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_bf16_control_rounds_centroids_and_misses(backend):
+    """``compute_dtype="bfloat16"`` on the same bf16 rows rounds the
+    centroids to bf16 for the distance product: its inertia misses the
+    f32 tolerance that the f32 arithmetic meets."""
+    lengths, rows, c = _case(WIDE[0], 5)
+    misses = {}
+    for compute in ("float32", "bfloat16"):
+        bkm = BatchedKMeans(K, max_iter=1, backend=backend, params=TILES,
+                            compute_dtype=compute).fit(
+            rows, lengths=lengths, centroids=c)
+        worst = 0.0
+        for b, xb in enumerate(_split(rows, lengths)):
+            xb = xb.astype(jnp.float32)
+            _, am_r, _, _ = ref.lloyd_step(xb, c[b])
+            inertia = float(jnp.sum((xb - c[b][am_r]) ** 2))
+            scale = float(jnp.sum(xb * xb) + jnp.sum(c[b][am_r] ** 2))
+            worst = max(worst, abs(bkm.inertia_[b] - inertia)
+                        / (1e-5 * inertia + 1e-6 * scale))
+        misses[compute] = worst
+    assert misses["float32"] < 1.0 < misses["bfloat16"]
+
+
+def test_wide_random_state():
+    """Per-problem keys: bit for bit the int32 ``PRNGKey(random_state +
+    b)`` below 2**31 (its wrap past 2**31 and negative seeds included);
+    wider seeds work, their bits past 32 in the key's high word."""
+    for seed in (0, 7, 2**31 - 3, -5):
+        want = jax.vmap(jax.random.PRNGKey)(seed + jnp.arange(6))
+        got = BatchedKMeans(K, random_state=seed)._problem_keys(6)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert got.dtype == want.dtype
+    wide = BatchedKMeans(K, random_state=2**33 + 7)
+    np.testing.assert_array_equal(np.asarray(wide._problem_keys(2)),
+                                  [[2, 7], [2, 8]])
+    lengths = (40, 90)
+    rows = _rows(lengths, seed=14)
+    wide.max_iter = 2
+    wide.fit(rows, lengths=lengths)
+    assert wide.cluster_centers_.shape == (2, K, F)

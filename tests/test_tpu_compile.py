@@ -236,3 +236,31 @@ def test_kmeanspp_round(one_chip):
                                             interpret=False),
         _sds(one_chip, (B, 2), jnp.uint32), _sds(one_chip, (B, BN, F)))
     assert "tpu_custom_call" in txt
+
+
+# the MLA latent cell: 4 prompts' [c_kv; k_pe] x 7 MLA layers, F = 576
+LATENT = tuple(n for n in (42495, 71468, 120194, 202141) for _ in range(7))
+LATENT_F = 576
+
+
+def test_fused_lloyd_ragged_bf16_rows(one_chip):
+    """The ragged step at the MLA latent cell's shapes: 28 problems, sum N
+    = 3,054,086 bf16 rows of 576 features against f32 centroids (three
+    bf16 passes), the pack included. Features pad to 640 lanes, and no
+    f32 copy of the rows is made: the program's temporaries hold the
+    640-lane bf16 pack (4.0 GB) and stay below the 7.04 GB that an f32
+    copy of the rows alone would take."""
+    _, p = _tuned("batched", max(LATENT), BK, LATENT_F, batch=len(LATENT))
+    p = ops.ragged_params(p, max(LATENT), BK, LATENT_F, jnp.bfloat16)
+    assert ops._round_up(LATENT_F, p.block_f) == 640
+    rows = sum(LATENT)
+    compiled = jax.jit(
+        lambda x, c: ops.fused_lloyd_batched(
+            ops.plan_data_batched(x, p, LATENT), c, interpret=False)).lower(
+        _sds(one_chip, (rows, LATENT_F), jnp.bfloat16),
+        _sds(one_chip, (len(LATENT), BK, LATENT_F))).compile()
+    txt = compiled.as_text()
+    assert "lloyd_step_ragged" in txt and "tpu_custom_call" in txt
+    pack = sum(-(-n // p.block_m) for n in LATENT) * p.block_m * 640 * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert pack <= temp < rows * LATENT_F * 4
